@@ -38,6 +38,9 @@ def main(argv=None) -> None:
                          "skipped as incomparable workloads)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     import benchmarks.common
     if args.smoke:
         benchmarks.common.SMOKE = True

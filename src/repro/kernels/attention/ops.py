@@ -1,7 +1,8 @@
 """Jit'd public wrapper around the flash-attention Pallas kernel.
 
-Handles layout (B, T, H, D) <-> (B, H, T, D), padding to block multiples,
-and the interpret fallback (CPU validation; real TPUs compile the kernel).
+Handles layout (B, T, H, D) <-> (B, H, T, D) and padding to block
+multiples.  Callers say whether the kernel runs compiled (TPU) or in
+interpret mode (CPU validation).
 """
 from __future__ import annotations
 
@@ -17,7 +18,7 @@ from repro.kernels.attention.kernel import flash_attention_tpu
                                    "interpret"))
 def flash_attention_op(q, k, v, *, causal: bool = True, window: int = 0,
                        q_block: int = 128, kv_block: int = 128,
-                       interpret: bool = True):
+                       interpret: bool):
     """q: (B, T, Hq, D); k/v: (B, S, Hkv, D) — framework layout."""
     B, T, Hq, D = q.shape
     S = k.shape[1]

@@ -1,8 +1,10 @@
 """Jit'd public wrappers for paged decode attention.
 
-``paged_decode_attention_op`` drives the Pallas kernel (interpret-mode on
-CPU, the correctness harness; compiled on TPU).  ``paged_decode_attention_jnp``
-is the blocked fallback: a `lax.switch` over page-aligned prefix widths —
+``paged_decode_attention_op`` drives the Pallas kernel; callers say
+whether it runs compiled (TPU) or in interpret mode (the CPU correctness
+harness), so the interpreter can never slip onto the chip by default.
+``paged_decode_attention_jnp`` is the blocked fallback: a `lax.switch`
+over page-aligned prefix widths —
 the branch for width W runs the *dense* reference math over k/v[:, :W],
 where W is the smallest page multiple covering max(attend_len).  Because
 masked tail keys feed exact zeros into every reduction, each branch is
@@ -13,10 +15,12 @@ falls back to the dense reference when the cache width doesn't page.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.common import auto_page_size
 from repro.kernels.decode_attention.kernel import paged_decode_attention_tpu
@@ -67,7 +71,7 @@ def paged_decode_attention_jnp(q, k_cache, v_cache, attend_len, *,
 
 @partial(jax.jit, static_argnames=("page_size", "interpret"))
 def paged_decode_attention_op(q, k_cache, v_cache, attend_len, *,
-                              page_size: int = 128, interpret: bool = True):
+                              page_size: int = 128, interpret: bool):
     """Pallas path in the framework layout: q (B, 1, Hq, D) -> same."""
     B, _, Hq, D = q.shape
     Hkv = k_cache.shape[2]
@@ -94,8 +98,12 @@ def paged_decode_attention(q, k_cache, v_cache, attend_len, *,
     if not page:
         return decode_attention_ref(q, k_cache, v_cache, attend_len)
     if backend == "tpu":
-        return paged_decode_attention_op(q, k_cache, v_cache, attend_len,
-                                         page_size=page, interpret=False)
+        op = partial(paged_decode_attention_op, page_size=page,
+                     interpret=False)
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            return op(q, k_cache, v_cache, attend_len)
+        return _per_shard(op, mesh, q, k_cache, v_cache, attend_len)
     if backend != "cpu":
         # the divisor-ladder bit-identity is an XLA *CPU* dot-panelization
         # property; an unverified backend (GPU) gets the dense reference
@@ -103,3 +111,24 @@ def paged_decode_attention(q, k_cache, v_cache, attend_len, *,
         return decode_attention_ref(q, k_cache, v_cache, attend_len)
     return paged_decode_attention_jnp(q, k_cache, v_cache, attend_len,
                                       page_size=page)
+
+
+def _per_shard(op, mesh, q, k_cache, v_cache, attend_len):
+    """Run the kernel on each device's shard of a meshed program (the
+    compiler cannot partition a Mosaic kernel itself).  Rows split over
+    the data axes and kv heads over "model", the layout
+    `launch.partition` gives the slot pool and the attention weights; an
+    axis that does not divide stays whole.  Query heads split with their
+    kv heads: a contiguous block of Hq/m query heads uses exactly the
+    block of Hkv/m kv heads on the same device."""
+    B, Hkv = k_cache.shape[0], k_cache.shape[2]
+    data = tuple(a for a in mesh.axis_names if a != "model")
+    dsize = math.prod(mesh.shape[a] for a in data)
+    msize = mesh.shape.get("model", 1)
+    rows = data if data and B % dsize == 0 else None
+    heads = "model" if msize > 1 and Hkv % msize == 0 else None
+    spec = P(rows, None, heads, None)
+    attend = jnp.broadcast_to(jnp.asarray(attend_len, jnp.int32), (B,))
+    return jax.shard_map(op, mesh=mesh, in_specs=(spec, spec, spec, P(rows)),
+                         out_specs=spec, check_vma=False)(
+        q, k_cache, v_cache, attend)

@@ -1,10 +1,11 @@
 """Jit'd wrappers for the fused offload pass.
 
-``fused_offload_op`` drives the Pallas kernel (interpret-mode on CPU, the
-correctness harness; compiled on TPU).  ``fused_offload_jnp`` is the jnp
-fallback: the same one-pass unrolled nearest-center scan, fused by XLA —
-unlike the seed two-pass path it never materializes the (..., C-k, L)
-distance tensor, so it is the substrate hot path on non-TPU backends.
+``fused_offload_op`` drives the Pallas kernel; callers say whether it runs
+compiled (TPU) or in interpret mode (the CPU correctness harness).
+``fused_offload_jnp`` is the jnp fallback: the same one-pass unrolled
+nearest-center scan, fused by XLA — unlike the seed two-pass path it
+never materializes the (..., C-k, L) distance tensor, so it is the
+substrate hot path on non-TPU backends.
 ``fused_offload`` picks per backend.
 """
 from __future__ import annotations
@@ -19,8 +20,7 @@ from repro.kernels.offload_fused.kernel import offload_fused_tpu
 
 
 @partial(jax.jit, static_argnames=("perm", "k", "interpret"))
-def fused_offload_op(x, centers, *, perm: tuple, k: int,
-                     interpret: bool = True):
+def fused_offload_op(x, centers, *, perm: tuple, k: int, interpret: bool):
     """x: (..., C) -> (local (..., k), remote, indices, dequantized)."""
     shape = x.shape
     C = shape[-1]

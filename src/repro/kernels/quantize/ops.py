@@ -10,7 +10,7 @@ from repro.kernels.quantize.kernel import quantize_tpu
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def quantize_op(x, centers, *, interpret: bool = True):
+def quantize_op(x, centers, *, interpret: bool):
     """x: any shape; centers: (L,).  Returns (indices, dequantized)."""
     shape = x.shape
     n = x.size

@@ -10,7 +10,7 @@ from repro.kernels.rmsnorm.kernel import rmsnorm_tpu
 
 
 @partial(jax.jit, static_argnames=("eps", "interpret"))
-def rmsnorm_op(x, scale, *, eps: float = 1e-6, interpret: bool = True):
+def rmsnorm_op(x, scale, *, eps: float = 1e-6, interpret: bool):
     """x: (..., d)."""
     shape = x.shape
     d = shape[-1]

@@ -10,7 +10,7 @@ from repro.kernels.topk_split.kernel import channel_permute_tpu
 
 
 @partial(jax.jit, static_argnames=("perm", "k", "interpret"))
-def split_op(x, *, perm: tuple, k: int, interpret: bool = True):
+def split_op(x, *, perm: tuple, k: int, interpret: bool):
     """x: (..., C) -> (local (..., k), remote (..., C-k))."""
     shape = x.shape
     C = shape[-1]

@@ -10,6 +10,7 @@ module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,7 +26,12 @@ def make_serving_mesh(*, data: int | None = None, model: int = 1):
     mesh, so the same code path serves laptops and pods."""
     if data is None:
         data = max(1, jax.device_count() // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    # Auto axes: the serving programs leave sharding propagation to the
+    # compiler from the placed params and pool (Explicit axes, the
+    # make_mesh default, would demand an out_sharding on every scatter
+    # into the sharded pool)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple[str, ...]:

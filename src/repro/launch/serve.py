@@ -53,41 +53,59 @@ def _dump_telemetry(tel, args) -> None:
               f"({len(tel.trace.spans)} spans)", file=sys.stderr)
 
 
-def _serve_queue(cfg, params, args, tel) -> int:
-    """Mixed-length request queue through the ServeEngine scheduler."""
+def queue_workload(cfg, params, *, n: int, lengths: tuple, tokens: int,
+                   mesh=None, prefix_cache: bool = False,
+                   serialized: bool = False, kv_tier_mb: float = 0.0,
+                   telemetry=None):
+    """The --queue workload: a ServeEngine over the continuous scheduler
+    (one length bucket per prompt length) and ``n`` requests of
+    ``tokens`` new tokens each, with prompt lengths drawn from
+    ``lengths`` by a seeded RNG.  With ``prefix_cache`` every prompt
+    opens with one shared page-aligned system prompt.  Returns (engine,
+    requests)."""
     import numpy as np
     from repro.serve.engine import Request, ServeEngine
     from repro.serve.scheduler import SchedulerConfig
 
-    mesh = None
-    if args.mesh:
-        from repro.launch.mesh import make_serving_mesh
-        dims = [int(x) for x in args.mesh.split(",")]
-        data, model = dims[0], (dims[1] if len(dims) > 1 else 1)
-        mesh = make_serving_mesh(data=data, model=model)
-    lengths = tuple(int(x) for x in args.lengths.split(","))
     rng = np.random.RandomState(0)
     shared = np.zeros((0,), np.int32)
-    if args.prefix_cache:
+    if prefix_cache:
         # shared-prefix workload: every prompt opens with the same
         # "system prompt" (page-aligned so it populates whole cache
         # pages) and diverges in its tail
         page = SchedulerConfig().page_size
         n_pages = max(1, max(lengths) // page)
         shared = rng.randint(0, cfg.vocab, n_pages * page)
-    max_len = len(shared) + max(lengths) + args.tokens + 8
+    max_len = len(shared) + max(lengths) + tokens + 8
     eng = ServeEngine(cfg, params, max_len=max_len, mesh=mesh,
-                      telemetry=tel,
+                      telemetry=telemetry,
                       scheduler=SchedulerConfig(
                           buckets=tuple(len(shared) + b for b in lengths),
-                          overlap=not args.serialized,
-                          prefix_cache=args.prefix_cache,
-                          kv_tier_mb=args.kv_tier_mb))
+                          overlap=not serialized,
+                          prefix_cache=prefix_cache,
+                          kv_tier_mb=kv_tier_mb))
     reqs = [Request(tokens=np.concatenate(
                         [shared, rng.randint(0, cfg.vocab,
                                              rng.choice(lengths))]),
-                    max_new_tokens=args.tokens)
-            for _ in range(args.queue)]
+                    max_new_tokens=tokens)
+            for _ in range(n)]
+    return eng, reqs
+
+
+def _serve_queue(cfg, params, args, tel) -> int:
+    """Mixed-length request queue through the ServeEngine scheduler."""
+    mesh = None
+    if args.mesh:
+        from repro.launch.mesh import make_serving_mesh
+        dims = [int(x) for x in args.mesh.split(",")]
+        data, model = dims[0], (dims[1] if len(dims) > 1 else 1)
+        mesh = make_serving_mesh(data=data, model=model)
+    eng, reqs = queue_workload(
+        cfg, params, n=args.queue,
+        lengths=tuple(int(x) for x in args.lengths.split(",")),
+        tokens=args.tokens, mesh=mesh, prefix_cache=args.prefix_cache,
+        serialized=args.serialized, kv_tier_mb=args.kv_tier_mb,
+        telemetry=tel)
     t0 = time.time()
     outs = eng.generate(reqs)
     dt = time.time() - t0
@@ -348,6 +366,9 @@ def main(argv=None) -> int:
                          "stderr); applies to every mode")
     args = ap.parse_args(argv)
     _validate_flags(ap, args)
+
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from repro.serve.telemetry import Telemetry
     tel = Telemetry(enabled=True)

@@ -25,6 +25,9 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=10)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     if args.dry_run:
         from repro.launch import dryrun
         rec = dryrun.run_one(args.arch, args.shape, multi_pod=args.multi_pod)

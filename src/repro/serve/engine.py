@@ -5,7 +5,7 @@ Two execution paths share one `generate` API for the Remote-NN role:
   * equal-length fast path — requests whose prompts share one length are
     grouped into a single prefill and decoded as one
     `jax.lax.while_loop` device program (sampling, EOS/done masking and
-    per-request length limits in-graph, cache donated on TPU), issuing
+    per-request length limits in-graph), issuing
     O(1) host transfers per call.  Bit-compatible with the PR-1 engine.
   * continuous batching — mixed-length queues route through
     `repro.serve.scheduler.ContinuousScheduler`: prompts are right-padded
@@ -141,13 +141,10 @@ class ServeEngine:
         self._key = jax.random.PRNGKey(seed)
         self._sched_cfg = scheduler or SchedulerConfig()
         self._sched: Optional[ContinuousScheduler] = None
-        # cache is donated where the backend supports it (TPU): the
-        # prefill cache buffers are reused in place by the loop instead
-        # of being copied per step
-        donate = (2,) if jax.default_backend() == "tpu" else ()
+        # the prefill cache is not donated: the loop returns no cache, so
+        # no output could take its buffers (donation would only warn)
         self._loop = jax.jit(partial(_decode_loop, cfg),
-                             static_argnames=("buf_len", "greedy"),
-                             donate_argnums=donate)
+                             static_argnames=("buf_len", "greedy"))
         # jit'd prefill (compiles once per prompt length): the op-by-op
         # eager prefill used to dominate the equal-length path's wall
         # clock, benching it below the scheduler on the same requests

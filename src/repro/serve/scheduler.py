@@ -329,8 +329,9 @@ class ContinuousScheduler:
             partial(bb.prefill_chunk, cfg),
             static_argnames=("attend_width",))
         self._inject = jax.jit(self._inject_impl)
-        donate = (1,) if jax.default_backend() == "tpu" else ()
-        self._chunk = jax.jit(self._chunk_impl, donate_argnums=donate)
+        # the chunk updates the pool in place on every backend: no pool
+        # leaf read before a dispatch may be read after it
+        self._chunk = jax.jit(self._chunk_impl, donate_argnums=(1,))
 
     # ------------------------------------------------------------- device --
 
@@ -1099,9 +1100,12 @@ class ContinuousScheduler:
         if not active.any():
             return None
         self._key, sub = jax.random.split(self._key)
-        self._pool, _ = self._chunk(self.params, self._pool,
-                                    jnp.asarray(active), sub,
-                                    jnp.int32(self.sched.chunk))
+        # a meshed chunk traces under its mesh, so the decode kernel can
+        # run per shard (`kernels.decode_attention.ops._per_shard`)
+        with (jax.set_mesh(self.mesh) if self.mesh is not None else _NULL):
+            self._pool, _ = self._chunk(self.params, self._pool,
+                                        jnp.asarray(active), sub,
+                                        jnp.int32(self.sched.chunk))
         return active
 
     def step(self) -> list[int]:

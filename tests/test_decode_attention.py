@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.common import auto_page_size
 from repro.kernels.decode_attention.ops import (
@@ -23,7 +24,6 @@ from repro.nn.attention import (
     decode_attention,
     reference_attention,
 )
-from tests._hypothesis_compat import given, settings, st
 
 KEY = jax.random.PRNGKey(0)
 

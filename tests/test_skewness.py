@@ -1,8 +1,8 @@
 """Skewness losses (Eq. 1/2) — unit + hypothesis property tests."""
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
-from _hypothesis_compat import hnp
+from hypothesis import given, settings, strategies as st
+from hypothesis.extra import numpy as hnp
 
 from repro.core.skewness import (
     achieved_skewness,

@@ -1,0 +1,79 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e chip.
+
+The TPU compiler checks what interpret mode never does (block shapes
+against the (8, 128) tiling, fast-memory limits), so these tests compile
+each kernel at the widths the served path runs: the paged decode kernel
+at qwen2-0.5b's head shapes and the fused AgileNN offload at the paper's
+96x96 size.  Nothing runs; no chip is needed.  The topology is described
+inside a fixture, so only the worker given this file loads the TPU
+compiler, and every worker collects the same tests.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.decode_attention.ops import paged_decode_attention_op
+from repro.kernels.offload_fused.ops import fused_offload_op
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """Compiles for a described chip are written to the persistent cache
+    but cannot be read back without one; keep the cache out of them."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_kernel_compiles_at_qwen2_0_5b_shapes(one_chip,
+                                                     no_compile_cache, dtype):
+    """The scheduler's pool at qwen2-0.5b: 8 slots, KV width 1024 paged
+    at 128, 14 query / 2 kv heads of 64 (G = 7)."""
+    B, S, Hq, Hkv, D = 8, 1024, 14, 2, 64
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = sds((B, 1, Hq, D), dtype)
+    kv = sds((B, S, Hkv, D), dtype)
+    attend = sds((B,), jnp.int32)
+    text = _compiled_text(
+        lambda q, k, v, a: paged_decode_attention_op(
+            q, k, v, a, page_size=128, interpret=False), q, kv, kv, attend)
+    assert "tpu_custom_call" in text
+
+
+def test_offload_fused_compiles_at_paper_size(one_chip, no_compile_cache):
+    """AgileNN at 96x96: the extractor gives 24x24x24 features per image,
+    so a batch of 64 is 36,864 rows of C 24, split at k 5 and quantized
+    against 8 centers."""
+    rows, C, k, L = 64 * 24 * 24, 24, 5, 8
+    x = jax.ShapeDtypeStruct((rows, C), jnp.float32, sharding=one_chip)
+    centers = jax.ShapeDtypeStruct((L,), jnp.float32, sharding=one_chip)
+    perm = tuple(reversed(range(C)))
+    text = _compiled_text(
+        lambda x, c: fused_offload_op(x, c, perm=perm, k=k, interpret=False),
+        x, centers)
+    assert "tpu_custom_call" in text

@@ -67,6 +67,7 @@ import heapq
 import itertools
 import math
 import time
+from contextlib import nullcontext
 from typing import Optional
 
 import numpy as np
@@ -77,6 +78,8 @@ from repro.serve.scheduler import (
     SchedulerConfig,
     Suspended,
 )
+
+_NULL = nullcontext()     # reentrant: shared no-op for disabled telemetry
 
 # the full degradation ladder, most to least service delivered; the
 # frontend itself resolves requests as served / shed / rejected, the
@@ -263,6 +266,8 @@ class StreamingFrontend:
             mesh=mesh, clock=self._clock, faults=faults,
             telemetry=self.tel)
         self.sched.stream_cb = self._on_stream
+        self.sched.ttft_marks = {}       # filled only while telemetry is
+                                         # enabled: the TTFT split's marks
         sc = self.sched.sched
         self._feed_cap = (self.fcfg.feed_depth if self.fcfg.feed_depth
                           is not None else sc.max_slots + sc.prefill_group)
@@ -287,9 +292,13 @@ class StreamingFrontend:
         self.rejections: list = []               # (t, Priority, Overloaded)
         self.breaker_open = False
         self._rate: Optional[float] = None       # served requests / s
-        self._t_admit: dict[int, float] = {}     # rid -> submit instant
-                                                 # (telemetry-enabled only:
-                                                 # queue_wait span starts)
+        self._t_admit: dict[int, float] = {}     # rid -> submit instant on
+                                                 # the telemetry clock
+                                                 # (enabled only: queue_wait
+                                                 # span starts)
+        self._t_ttft: dict[int, float] = {}      # rid -> the same instant,
+                                                 # until its first token
+                                                 # (the ttft span starts)
         self._t_last = self._clock()
         self._step_events: list = []
         self._closed = False
@@ -393,8 +402,7 @@ class StreamingFrontend:
         if self.tel.enabled:
             self.tel.counter("frontend.admission", verdict="admitted",
                              priority=priority.name).inc()
-            self._t_admit[rid] = now     # queue_wait span start (reuses
-                                         # the admission clock read)
+            self._t_admit[rid] = self._t_ttft[rid] = self.tel.clock()
         heapq.heappush(self._classes[priority],
                        (deadline, next(self._seq), rid))
         if self.fcfg.max_queue is None:
@@ -440,15 +448,17 @@ class StreamingFrontend:
             if deadline <= now:
                 self._shed_waiting(rid, req)
                 continue
-            if self.tel.enabled and rid in self._t_admit:
-                self.tel.trace.add("queue_wait", self._t_admit.pop(rid),
-                                   now, track=f"req {rid}", cat="frontend")
             deadline_at = None if deadline == math.inf else deadline
             if isinstance(req, Suspended):
                 srid = self.sched.submit_suspended(req,
                                                    deadline_at=deadline_at)
             else:
                 srid = self.sched.submit(req, deadline_at=deadline_at)
+            if self.tel.enabled and rid in self._t_admit:
+                # ends where the scheduler's queue starts (its submit mark)
+                self.tel.trace.add("queue_wait", self._t_admit.pop(rid),
+                                   self.sched.ttft_marks[srid][0],
+                                   track=f"req {rid}", cat="frontend")
             self._to_sched[rid] = srid
             self._from_sched[srid] = rid
             if self.journal is not None:
@@ -518,6 +528,9 @@ class StreamingFrontend:
                 continue                 # already finished: drains normally
             del self._from_sched[srid]
             del self._to_sched[rid]
+            if self.tel.enabled:         # its TTFT spans a preemption:
+                self._t_ttft.pop(rid, None)          # no split is written
+                self.sched.ttft_marks.pop(srid, None)
             prio = self._prio[rid]
             self._reqs[rid] = sus
             heapq.heappush(self._classes[prio],
@@ -531,6 +544,33 @@ class StreamingFrontend:
                                  victim=prio.name).inc()
 
     # --------------------------------------------------------- events --
+
+    def _span(self, name: str):
+        """Wall span on the frontend track (profiler name
+        ``frontend/<name>``); shared no-op when telemetry is disabled."""
+        if not self.tel.enabled:
+            return _NULL
+        return self.tel.span(name, track="frontend", cat="frontend")
+
+    def _trace_ttft(self, rid: int) -> None:
+        """At a request's first token, write its time to first token and
+        the parts it splits into, on track ``req <rid>``: ``queue_wait``
+        (written at feed) then ``sched_queue`` (scheduler submit to slot
+        acquired), ``staging`` (to inject dispatched) and
+        ``first_token_lag`` (to this first token).  Adjacent parts share
+        their boundary instant, so they add up to ``ttft``."""
+        t0 = self._t_ttft.pop(rid, None)
+        srid = self._to_sched.get(rid)
+        m = None if srid is None else self.sched.ttft_marks.pop(srid, None)
+        if t0 is None or m is None or None in m:
+            return
+        t1 = self.tel.clock()
+        track = f"req {rid}"
+        add = self.tel.trace.add
+        add("ttft", t0, t1, track=track, cat="frontend")
+        add("sched_queue", m[0], m[1], track=track, cat="sched")
+        add("staging", m[1], m[2], track=track, cat="sched")
+        add("first_token_lag", m[2], t1, track=track, cat="frontend")
 
     def _emit(self, ev) -> None:
         self.events.append(ev)
@@ -548,6 +588,8 @@ class StreamingFrontend:
         if self.journal is not None:     # write-ahead: the chunk is
             self.journal.append(         # durable before it is emitted
                 "chunk", rid, t, toks=[int(x) for x in toks[n:]])
+        if n == 0 and self.tel.enabled:
+            self._trace_ttft(rid)
         for k in range(n, len(toks)):
             cls = FirstToken if k == 0 else Delta
             self._emit(cls(rid, int(toks[k]), t))
@@ -578,9 +620,10 @@ class StreamingFrontend:
         t = self._clock()
         if self.tel.enabled:
             self.tel.counter("frontend.finish", status=status).inc()
+            self._t_ttft.pop(rid, None)
             t0 = self._t_admit.pop(rid, None)
             if t0 is not None:
-                self.tel.trace.add("queue_wait", t0, t,
+                self.tel.trace.add("queue_wait", t0, self.tel.clock(),
                                    track=f"req {rid}", cat="frontend",
                                    status=status)
         if self.journal is not None:
@@ -590,14 +633,17 @@ class StreamingFrontend:
 
     def _finish_sched(self, srid: int) -> str:
         rid = self._from_sched.pop(srid)
-        self._to_sched.pop(rid)
         self._deadline.pop(rid, None)
         self._prio.pop(rid, None)
         self._t_submit.pop(rid, None)
         comp = self.sched.pop_completion(srid)
         toks = np.asarray(comp.tokens)
         self._emit_tokens(rid, toks)     # tail the stream never saw
+        self._to_sched.pop(rid)
         self._published.pop(rid, None)
+        if self.tel.enabled:             # no first token ever came
+            self._t_ttft.pop(rid, None)
+            self.sched.ttft_marks.pop(srid, None)
         status = "shed" if comp.timed_out else "served"
         self._results[rid] = (status, toks)
         if self.tel.enabled:
@@ -619,31 +665,34 @@ class StreamingFrontend:
         up to the backpressure depth, run one scheduler round, resolve
         its completions, update the service-rate estimate and the
         breaker.  Returns this round's events, in emission order."""
-        self._step_events = []
-        self._expire_waiting()
-        self._maybe_preempt()
-        self._feed()
-        done = self.sched.step()
-        n_served = sum(self._finish_sched(srid) == "served"
-                       for srid in done)
-        now = self._clock()
-        dt = now - self._t_last
-        self._t_last = now
-        if n_served and dt > 0:
-            inst = n_served / dt
-            a = self.fcfg.ewma
-            self._rate = (inst if self._rate is None
-                          else (1 - a) * self._rate + a * inst)
-        self._update_breaker()
-        if self.tel.enabled:
-            m = self.tel.metrics
-            for p in Priority:
-                m.gauge("frontend.queue_depth",
-                        priority=p.name).set(len(self._classes[p]))
-            m.gauge("frontend.queue_depth_total").set(self.queue_depth())
-            m.gauge("frontend.service_rate_rps").set(self._rate or 0.0)
-            m.gauge("frontend.breaker_open").set(int(self.breaker_open))
-        return self._step_events
+        with self._span("step"):
+            self._step_events = []
+            with self._span("feed"):
+                self._expire_waiting()
+                self._maybe_preempt()
+                self._feed()
+            done = self.sched.step()
+            with self._span("finish"):
+                n_served = sum(self._finish_sched(srid) == "served"
+                               for srid in done)
+            now = self._clock()
+            dt = now - self._t_last
+            self._t_last = now
+            if n_served and dt > 0:
+                inst = n_served / dt
+                a = self.fcfg.ewma
+                self._rate = (inst if self._rate is None
+                              else (1 - a) * self._rate + a * inst)
+            self._update_breaker()
+            if self.tel.enabled:
+                m = self.tel.metrics
+                for p in Priority:
+                    m.gauge("frontend.queue_depth",
+                            priority=p.name).set(len(self._classes[p]))
+                m.gauge("frontend.queue_depth_total").set(self.queue_depth())
+                m.gauge("frontend.service_rate_rps").set(self._rate or 0.0)
+                m.gauge("frontend.breaker_open").set(int(self.breaker_open))
+            return self._step_events
 
     def run(self) -> dict:
         """Drain every admitted request; returns (and forgets)
@@ -698,7 +747,7 @@ class StreamingFrontend:
         if self.tel.enabled:
             self.tel.counter("frontend.admission", verdict="restored",
                              priority=priority.name).inc()
-            self._t_admit[rid] = now
+            self._t_admit[rid] = self.tel.clock()
         heapq.heappush(self._classes[priority],
                        (deadline, next(self._seq), rid))
         if self.fcfg.max_queue is None:

@@ -234,8 +234,9 @@ class ContinuousScheduler:
         telemetry: a `repro.serve.telemetry.Telemetry`; the module
         default is disabled, and every hook below guards on
         `tel.enabled`, so an uninstrumented run does zero extra clock
-        reads or device->host copies (telemetry never reads `clock` —
-        injected test clocks advance on every read)."""
+        reads, profiler annotations or device->host copies (telemetry
+        stamps with its own clock and never reads `clock` — injected
+        test clocks advance on every read)."""
         assert supports_continuous_batching(cfg), \
             f"{cfg.name}: continuous batching needs a pure-attention " \
             "RoPE decoder (use ServeEngine's equal-length grouping)"
@@ -311,6 +312,11 @@ class ContinuousScheduler:
         # None (the default) skips the per-round buf/gen host copies
         # entirely: a non-streaming run does no extra device->host work
         self.stream_cb: Optional[object] = None
+        # time-to-first-token hook (serve.frontend attaches a dict):
+        # rid -> [submitted, slot acquired, inject dispatched] on the
+        # telemetry clock, recorded only while telemetry is enabled; the
+        # consumer pops each entry.  None (the default) records nothing
+        self.ttft_marks: Optional[dict] = None
         self.prefix = None
         if self.sched.prefix_cache:
             from repro.serve.prefix_cache import PrefixCache
@@ -410,26 +416,45 @@ class ContinuousScheduler:
     # --------------------------------------------------------------- host --
 
     def _span(self, name: str):
-        """Wall span on the scheduler track; shared no-op when telemetry
-        is disabled (no clock read, no allocation)."""
+        """Wall span on the scheduler track (profiler name
+        ``scheduler/<name>``); shared no-op when telemetry is disabled
+        (no clock read, no annotation, no allocation)."""
         if not self.tel.enabled:
             return _NULL
         return self.tel.span(name, track="scheduler", cat="sched",
                              round=self._round)
 
-    def export_metrics(self) -> None:
-        """Refresh per-round gauges, compile counters, and the re-export
-        of the prefix cache's `stats` dict into the registry.  Called at
-        the end of every round while telemetry is enabled (and by the
-        launcher before the final dump)."""
-        tel = self.tel
-        if not tel.enabled:
+    def _stamp(self, rids, i: int) -> None:
+        """Time-to-first-token mark ``i`` (1: slot acquired, 2: inject
+        dispatched) for each rid, one telemetry clock read for all."""
+        marks = self.ttft_marks
+        if marks is None or not self.tel.enabled:
             return
-        m = tel.metrics
+        t = self.tel.clock()
+        for rid in rids:
+            m = marks.get(rid)
+            if m is not None:
+                m[i] = t
+
+    def _export_round_gauges(self) -> None:
+        """The gauges sampled after every round while telemetry is
+        enabled: pool occupancy, backlog, staging admissions."""
+        m = self.tel.metrics
         m.gauge("sched.pool_occupancy").set(
             sum(r is not None for r in self._slots.rids))
         m.gauge("sched.backlog").set(self.backlog())
         m.gauge("sched.staging").set(len(self._staging))
+
+    def export_metrics(self) -> None:
+        """Refresh the round gauges, the decode-chunk and inject compile
+        counters, and the re-export of the prefix cache's `stats` dict
+        into the registry.  Called at the end of `run()` and by the
+        launcher before its final dump (rounds refresh only the gauges)."""
+        tel = self.tel
+        if not tel.enabled:
+            return
+        m = tel.metrics
+        self._export_round_gauges()
         tel.note_compiles("sched.decode_chunk", self._chunk,
                           shape=f"slots{len(self._slots)}")
         tel.note_compiles("sched.inject", self._inject,
@@ -476,6 +501,8 @@ class ContinuousScheduler:
             self._deadlines[rid] = self._clock() + request.deadline_s
         self._req_of[rid] = request
         self._queue.append((rid, request))
+        if self.ttft_marks is not None and self.tel.enabled:
+            self.ttft_marks[rid] = [self.tel.clock(), None, None]
         return rid
 
     @property
@@ -692,6 +719,7 @@ class ContinuousScheduler:
             self._slots.acquire(slot, rid)
             if self.prefix is not None:
                 pkeys.append(self.prefix.lookup(req.tokens)[0])
+        self._stamp([rid for rid, _ in take], 1)
         return {"bucket": head_bucket, "tokens": tokens, "lengths": lengths,
                 "slots": slots, "eos": eos, "max_new": max_new,
                 "temps": temps, "pkeys": pkeys,
@@ -720,6 +748,7 @@ class ContinuousScheduler:
         self._queue = keep
         for (rid, _, _), slot in zip(take, free):
             self._slots.acquire(slot, rid)
+        self._stamp([rid for rid, _, _ in take], 1)
         return {"prefix": True, "take": take, "slots": free[:len(take)],
                 "bucket": bucket, "n_hit": n_hit}
 
@@ -770,6 +799,7 @@ class ContinuousScheduler:
             self._pool, jnp.asarray(g["slots"]), rows, logits0,
             jnp.asarray(g["lengths"]), jnp.asarray(g["eos"]),
             jnp.asarray(g["max_new"]), jnp.asarray(g["temps"]), sub)
+        self._stamp(g["rids"], 2)
 
     def _launch_prefix(self, g: dict) -> None:
         """Admit a wave of prefix-hit requests in one batch: seed each
@@ -843,6 +873,7 @@ class ContinuousScheduler:
             self._pool, jnp.asarray(slots), cache, logits0,
             jnp.asarray(lengths), jnp.asarray(eos),
             jnp.asarray(max_new), jnp.asarray(temps), sub)
+        self._stamp([rid for rid, _, _ in take], 2)
 
     # ------------------------------------------------- chunked prefill --
 
@@ -874,6 +905,7 @@ class ContinuousScheduler:
                 cache["v"] = cache["v"].at[:, :, :, :depth].set(
                     kv["v"][:, :, None, :depth].astype(cache["v"].dtype))
         self._slots.acquire(slot, rid)
+        self._stamp([rid], 1)
         self._staging.append({
             "rid": rid, "req": req, "slot": slot, "depth": depth, "T": T,
             "bucket": bucket, "tokens": toks, "logits0": None, "keys": keys,
@@ -924,6 +956,7 @@ class ContinuousScheduler:
             jnp.asarray([req.eos_id], jnp.int32),
             jnp.asarray([req.max_new_tokens], jnp.int32),
             jnp.asarray([req.temperature], jnp.float32), sub)
+        self._stamp([st["rid"]], 2)
 
     # ----------------------------------------------------------- loop --
 
@@ -1019,15 +1052,17 @@ class ContinuousScheduler:
 
     def _drain(self) -> list[int]:
         """Evict finished slots: one host copy of buf/gen per segment."""
-        done = np.asarray(self._pool["done"])
+        with self._span("wait_done"):
+            done = np.asarray(self._pool["done"])
         stag = self._staging_slots()
         fin = [i for i, rid in enumerate(self._slot_rid)
                if rid is not None and done[i] and i not in stag]
         if self.stream_cb is not None:
             live = [i for i, rid in enumerate(self._slot_rid)
                     if rid is not None and i not in stag and not done[i]]
-            self._stream_rows(live, self._pool["buf"], self._pool["gen"],
-                              self._slot_rid)
+            with self._span("stream"):
+                self._stream_rows(live, self._pool["buf"],
+                                  self._pool["gen"], self._slot_rid)
         if not fin:
             return []
         return self._complete(fin, np.asarray(self._pool["buf"]),
@@ -1072,7 +1107,8 @@ class ContinuousScheduler:
         p, self._pending = self._pending, None
         if p is None:
             return []
-        done = np.asarray(p["done"])
+        with self._span("wait_done"):      # the host waits on the device
+            done = np.asarray(p["done"])
         eligible = [i for i, rid in enumerate(self._slot_rid)
                     if rid is not None and p["active"][i]
                     and p["rids"][i] == rid]
@@ -1081,8 +1117,9 @@ class ContinuousScheduler:
             # stream from the drained chunk's own snapshot: the rows are
             # consistent with the done flags just read, even though the
             # next chunk is already in flight on the device
-            self._stream_rows([i for i in eligible if not done[i]],
-                              p["buf"], p["gen"], p["rids"])
+            with self._span("stream"):
+                self._stream_rows([i for i in eligible if not done[i]],
+                                  p["buf"], p["gen"], p["rids"])
         if not fin:
             return []
         return self._complete(fin, np.asarray(p["buf"]),
@@ -1099,6 +1136,10 @@ class ContinuousScheduler:
         active = self._active_mask()
         if not active.any():
             return None
+        if self.tel.enabled:
+            self.tel.counter("sched.decode_chunks").inc()
+            self.tel.counter("sched.decode_rows_active").inc(
+                int(active.sum()))
         self._key, sub = jax.random.split(self._key)
         # a meshed chunk traces under its mesh, so the decode kernel can
         # run per shard (`kernels.decode_attention.ops._per_shard`)
@@ -1106,6 +1147,15 @@ class ContinuousScheduler:
             self._pool, _ = self._chunk(self.params, self._pool,
                                         jnp.asarray(active), sub,
                                         jnp.int32(self.sched.chunk))
+        return active
+
+    def _decode_chunk(self) -> Optional[np.ndarray]:
+        """`_dispatch_chunk` under the round's ``decode_chunk`` span,
+        which records how many rows the chunk decodes (``rows``)."""
+        with self._span("decode_chunk") as sp:
+            active = self._dispatch_chunk()
+            if sp is not None and active is not None:
+                sp["rows"] = int(active.sum())
         return active
 
     def step(self) -> list[int]:
@@ -1136,14 +1186,14 @@ class ContinuousScheduler:
                     self._advance_staging()
                 with self._span("admit"):
                     self._admit()
-                with self._span("decode_chunk"):
-                    dispatched = self._dispatch_chunk()
+                dispatched = self._decode_chunk()
                 if dispatched is None:
                     out = expired
                 else:
                     with self._span("evict"):
                         out = expired + self._drain()
-        self.export_metrics()
+        if self.tel.enabled:
+            self._export_round_gauges()
         return out
 
     def _step_overlapped(self) -> list[int]:
@@ -1169,8 +1219,7 @@ class ContinuousScheduler:
             self._admit()                      # wait); freed slots admit
                                                # before this round's chunk
         rids = list(self._slot_rid)            # occupancy at dispatch time
-        with self._span("decode_chunk"):
-            active = self._dispatch_chunk()
+        active = self._decode_chunk()
         if active is not None:
             self._snapshot_chunk(rids, active)
         return out
@@ -1179,5 +1228,6 @@ class ContinuousScheduler:
         """Drain queue and pool; returns (and forgets) {rid: Completion}."""
         while self._queue or self._staging or self._slots.any_occupied():
             self.step()
+        self.export_metrics()
         out, self._results = self._results, {}
         return out

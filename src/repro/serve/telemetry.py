@@ -14,6 +14,10 @@ gateway, engine/kernels):
   ``VirtualClock`` timestamps through :meth:`Tracer.add`, wall-clock
   components use the :meth:`Telemetry.span` context manager, so
   simulated and wall runs produce structurally comparable traces.
+  While enabled, a wall span also enters
+  ``jax.profiler.TraceAnnotation("<track>/<name>")`` around the same
+  interval, so a running JAX profiler records it on the device planes'
+  clock (a no-op costing about a microsecond when none runs).
 * ``Telemetry`` — the facade components accept (``telemetry=None``
   falls back to the module-wide disabled default).  The hard contract:
   while ``enabled`` is False, instrumentation sites are skipped
@@ -33,6 +37,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
@@ -380,17 +386,21 @@ class Telemetry:
 
     @contextmanager
     def span(self, name: str, *, track: str = "main", cat: str = "",
-             **args) -> Iterator[Optional[Span]]:
-        """Wall-clock span; no clock read when disabled."""
+             **args) -> Iterator[Optional[dict]]:
+        """Wall-clock span, also annotated for the JAX profiler as
+        ``<track>/<name>``.  Yields the span's ``args`` dict, so the body
+        can attach what it learns (the recorded span carries it); no
+        clock read and no annotation when disabled (yields None)."""
         if not self.enabled:
             yield None
             return
-        t0 = self.clock()
-        try:
-            yield None
-        finally:
-            self.trace.add(name, t0, self.clock(), track=track, cat=cat,
-                           **args)
+        with TraceAnnotation(f"{track}/{name}"):
+            t0 = self.clock()
+            try:
+                yield args
+            finally:
+                self.trace.add(name, t0, self.clock(), track=track, cat=cat,
+                               **args)
 
     # -- jit compile accounting --------------------------------------------
 

@@ -284,3 +284,176 @@ def test_gateway_request_spans_cover_e2e_latency(gw_system):
         parent = next(s for s in spans if s.name == "request")
         assert _union_coverage(spans, parent) >= 0.95, \
             f"{track}: uninstrumented gap in the request timeline"
+
+
+# ------------------------------- profiler spans, TTFT split, row counts --
+
+
+def _frontend(cfg, params, tel, **sched_kw):
+    from repro.serve.frontend import StreamingFrontend
+    from repro.serve.scheduler import SchedulerConfig
+    kw = dict(buckets=(8, 16, 32), max_slots=4, prefill_group=2, chunk=2,
+              page_size=8, prefill_segment=8)
+    kw.update(sched_kw)
+    return StreamingFrontend(cfg, params, sched=SchedulerConfig(**kw),
+                             max_len=48, telemetry=tel)
+
+
+def _mixed_requests(cfg, seed=5):
+    """Bucketed prompts (<= 8 tokens) and staged ones (9-24 tokens)."""
+    from repro.serve.engine import Request
+    rng = np.random.RandomState(seed)
+    return [Request(tokens=rng.randint(0, cfg.vocab, L), max_new_tokens=n)
+            for L, n in ((5, 4), (20, 3), (8, 6), (13, 2), (24, 5), (3, 3))]
+
+
+def _host_events(log_dir, prefixes):
+    """(start_ns, end_ns, name) of the host-plane events of the newest
+    profile under log_dir whose names start with one of `prefixes`."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(prefixes):
+                        s = int(ev.start_ns)
+                        out.append((s, s + int(ev.duration_ns), ev.name))
+    return out
+
+
+def test_round_spans_reach_the_profiler_host_plane_nested(lm_system,
+                                                          tmp_path):
+    """Enabled wall spans are also profiler annotations: an overlapped
+    round under `jax.profiler.trace` leaves `frontend/step` holding
+    `frontend/feed`, `scheduler/round` and `frontend/finish`, the round
+    holding its phases, and `evict` holding `wait_done` and `stream`,
+    every child inside its parent on the host plane."""
+    cfg, params = lm_system
+    fe = _frontend(cfg, params, Telemetry(enabled=True))
+    for r in _mixed_requests(cfg):
+        fe.submit(r)
+    with jax.profiler.trace(str(tmp_path)):
+        fe.run()
+    evs = _host_events(str(tmp_path), ("scheduler/", "frontend/"))
+    names = {n for _, _, n in evs}
+    assert {"frontend/step", "frontend/feed", "frontend/finish",
+            "scheduler/round", "scheduler/prefill_segment",
+            "scheduler/admit", "scheduler/evict", "scheduler/decode_chunk",
+            "scheduler/wait_done", "scheduler/stream"} <= names
+
+    def parents(child):
+        return {n for s, e, n in evs
+                if s <= child[0] and child[1] <= e and (s, e, n) != child}
+
+    want = {"frontend/feed": "frontend/step",
+            "frontend/finish": "frontend/step",
+            "scheduler/round": "frontend/step",
+            "scheduler/prefill_segment": "scheduler/round",
+            "scheduler/admit": "scheduler/round",
+            "scheduler/evict": "scheduler/round",
+            "scheduler/decode_chunk": "scheduler/round",
+            "scheduler/wait_done": "scheduler/evict",
+            "scheduler/stream": "scheduler/evict"}
+    for ev in evs:
+        if ev[2] in want:
+            assert want[ev[2]] in parents(ev), ev
+    # properly nested: two spans either hold one another or are apart
+    for a in evs:
+        for b in evs:
+            overlap = a[0] < b[1] and b[0] < a[1]
+            nested = (a[0] <= b[0] and b[1] <= a[1]) or \
+                (b[0] <= a[0] and a[1] <= b[1])
+            assert not overlap or nested, (a, b)
+
+
+def test_disabled_telemetry_enters_no_annotation(lm_system, monkeypatch):
+    """With telemetry disabled the scheduler and frontend enter no
+    profiler annotation and read no telemetry clock."""
+    import repro.serve.telemetry as telemetry_mod
+
+    def boom(*a, **k):
+        raise AssertionError("disabled telemetry was touched")
+
+    monkeypatch.setattr(telemetry_mod, "TraceAnnotation", boom)
+    cfg, params = lm_system
+    tel = Telemetry(enabled=False, clock=boom)
+    fe = _frontend(cfg, params, tel)
+    for r in _mixed_requests(cfg):
+        fe.submit(r)
+    out = fe.run()
+    assert all(status == "served" for status, _ in out.values())
+    assert tel.trace.spans == [] and not fe.sched.ttft_marks
+
+
+def test_ttft_split_adds_up_to_each_first_token(lm_system):
+    """Each served request's queue_wait + sched_queue + staging +
+    first_token_lag equals its FirstToken time minus its submit instant
+    (within 1% or 1 ms); staged prompts spend rounds in `staging`."""
+    import time
+    from repro.serve.frontend import FirstToken
+    cfg, params = lm_system
+    tel = Telemetry(enabled=True, clock=time.monotonic)
+    fe = _frontend(cfg, params, tel)
+    reqs = _mixed_requests(cfg)
+    t_sub = {}
+    for r in reqs:
+        t = time.monotonic()
+        t_sub[fe.submit(r)] = t
+    fe.run()
+    firsts = {ev.rid: ev.t for ev in fe.events if isinstance(ev, FirstToken)}
+    assert set(firsts) == set(t_sub)
+    parts = ("queue_wait", "sched_queue", "staging", "first_token_lag")
+    for rid, t_first in firsts.items():
+        spans = {s.name: s for s in tel.trace.by_track(f"req {rid}")}
+        assert set(parts) | {"ttft"} <= set(spans), sorted(spans)
+        total = sum(spans[p].dur for p in parts)
+        assert total == pytest.approx(spans["ttft"].dur, abs=1e-9)
+        want = t_first - t_sub[rid]
+        assert abs(total - want) <= max(0.01 * want, 1e-3), (rid, total,
+                                                              want)
+        assert all(spans[p].dur >= 0 for p in parts)
+    staged = [rid for rid, r in zip(sorted(t_sub), reqs)
+              if len(r.tokens) > 8]
+    bucketed = [rid for rid, r in zip(sorted(t_sub), reqs)
+                if len(r.tokens) <= 8]
+    shortest_round = min(s.dur for s in tel.trace.spans
+                         if s.name == "round")
+    for rid in staged:       # its segments take rounds: one lies inside
+        assert {s.name: s for s in tel.trace.by_track(
+            f"req {rid}")}["staging"].dur >= shortest_round
+    assert not fe._t_ttft and not fe._t_admit and not fe.sched.ttft_marks
+    assert bucketed
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_decode_rows_active_hand_count(lm_system, overlap):
+    """Three requests admitted together with budgets 3, 5 and 7 decode
+    in chunks of 2 steps: the first chunk runs 3 rows, then 2, then 1
+    (each finisher leaves before the next chunk, in both modes) —
+    3 chunks and 6 rows, on the counters and on the decode_chunk
+    spans."""
+    from repro.serve.engine import Request
+    from repro.serve.scheduler import ContinuousScheduler, SchedulerConfig
+    cfg, params = lm_system
+    tel = Telemetry(enabled=True)
+    sched = ContinuousScheduler(
+        cfg, params, max_len=32,
+        sched=SchedulerConfig(buckets=(8,), max_slots=4, prefill_group=4,
+                              chunk=2, overlap=overlap),
+        telemetry=tel)
+    rng = np.random.RandomState(2)
+    for n in (3, 5, 7):
+        sched.submit(Request(tokens=rng.randint(0, cfg.vocab, 8),
+                             max_new_tokens=n))
+    outs = sched.run()
+    assert sorted(len(c.tokens) for c in outs.values()) == [3, 5, 7]
+    assert tel.counter("sched.decode_chunks").n == 3
+    assert tel.counter("sched.decode_rows_active").n == 6
+    rows = [s.args["rows"] for s in tel.trace.spans
+            if s.name == "decode_chunk" and "rows" in s.args]
+    assert rows == [3, 2, 1]

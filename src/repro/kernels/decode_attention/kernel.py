@@ -1,23 +1,44 @@
 """Pallas TPU paged decode-attention kernel (flash-decoding over KV pages).
 
 One decode step attends a single query token per sequence against a
-block-paged KV cache.  The grid walks (batch, kv_page); the page axis is
-sequential ("arbitrary"), so the online-softmax accumulators for one row
-live in VMEM scratch across page visits.  `attend_len` is a
-scalar-prefetch operand: pages at or past a row's valid depth are skipped
-entirely (the early-exit that makes a 1024-wide cache cost only as much
-as the row's actual context), and lanes past the depth inside the last
-live page are masked.
+block-paged KV cache.  The grid is ragged: one step per *live* page of
+each row (the pages below its `attend_len`), rows one after another, so
+a 2,048-wide cache costs only as many steps and page fetches as its rows'
+actual contexts; a pool slot at depth 1 costs one.  The schedule (row and
+page of each step) and `attend_len` are scalar-prefetch operands, the
+grid's length is their traced total, and the online-softmax accumulators
+of a row live in VMEM scratch across its pages.  Lanes past the depth
+inside a row's last page are masked.
 
-One grid step loads a whole page for *all* kv heads: the K/V block is
-(page_size, Hkv, D), whose last two dimensions equal the cache's, which
-is what the TPU's block-shape rule asks of a dimension under its (8, 128)
-tile (a per-head (page_size, 1, D) block over an Hkv > 1 cache is
-refused).  GQA is handled in-kernel: the query tile holds all
-G = Hq/Hkv group heads of every kv head, so every K/V page is streamed
-from HBM exactly once per row.
+One grid step loads a whole page for *all* kv heads.  The kernel reads
+K/V with the token axis minor, as (Hkv, D, page_size) blocks of a
+(..., Hkv, D, S) operand: that is the order in which the TPU keeps a
+(..., S, Hkv, D) cache in HBM (its default layout tiles the (D, S) plane,
+since Hkv and D are narrower than a tile), so the framework-layout cache
+reaches the kernel through a transpose the compiler makes a bitcast, and
+a page is one dense (D, page_size) tile run per head, with no lane
+padding and no relayout copy of the cache.  GQA is handled in-kernel:
+the query tile holds all G = Hq/Hkv group heads of every kv head, so
+every K/V page is streamed from HBM exactly once per row.
 
-Layout: q (B, Hkv, G, D); k/v (B, S, Hkv, D); out (B, Hkv, G, D).
+The K/V operands are a layer-stacked pool, with any number of leading
+layer axes, and the layer to attend is a second scalar-prefetch operand
+(one index per leading axis): the page index map leads with it, so a
+decode step that carries the whole pool through its layer loop hands the
+kernel the pool itself and only that layer's live pages leave HBM (no
+per-layer slice is ever materialized).  A single cache is a pool of one.
+
+With `append`, the kernel also writes the step's new K/V token of every
+row into the pool, in place (the pool operands alias the pool outputs):
+the page holding a row's write slot is the one block of that row's pool
+outputs, filled from the fetched page with the token's lane replaced
+when the grid reaches it, and attended in that form.  The TPU's scatter
+and per-row dynamic-update-slice would each want the pool in another
+layout (a relayout copy of the whole pool per layer) or one op per row;
+here the write costs one page per row.
+
+Layout: q (B, Hkv, G, D); k/v (*L, B, Hkv, D, S); new k/v (B, Hkv, D, 1);
+out (B, Hkv, G, D).
 """
 from __future__ import annotations
 
@@ -32,17 +53,27 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_decode_kernel(attend_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *,
-                         scale: float, page_size: int, n_pages: int):
-    """Refs (VMEM): q (Hkv, G, D); k/v (page_size, Hkv, D); o (Hkv, G, D).
+def _paged_decode_kernel(layer_ref, attend_ref, slot_ref, row_ref,
+                         page_ref, q_ref, k_ref, v_ref, *refs, scale: float,
+                         page_size: int, n_pages: int, append: bool):
+    """Refs (VMEM): q (Hkv, G, D); k/v (Hkv, D, page_size); o (Hkv, G, D);
+    with `append` also new k/v (Hkv, D, 1) in and the k/v pool pages
+    (Hkv, D, page_size) out.  layer_ref (SMEM) is read by the index maps
+    only; slot_ref (SMEM) holds each row's write slot, row_ref and
+    page_ref the row and page of each grid step.
 
     Scratch: m/l (Hkv, G, 128) lane-replicated running max / normalizer,
     acc (Hkv, G, D) unnormalized output accumulator.
     """
-    b = pl.program_id(0)
-    page = pl.program_id(1)
+    if append:
+        kn_ref, vn_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, m_ref, l_ref, acc_ref = refs
+    step = pl.program_id(0)
+    b = row_ref[step]
+    page = page_ref[step]
     attend = attend_ref[b]
+    last = _live_pages(attend, page_size, n_pages) - 1
     n_kv = q_ref.shape[0]
 
     @pl.when(page == 0)
@@ -51,76 +82,132 @@ def _paged_decode_kernel(attend_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(page * page_size < attend)
-    def _visit():
-        for h in range(n_kv):                               # static unroll
-            q = q_ref[h].astype(jnp.float32) * scale        # (G, D)
-            k = k_ref[:, h, :].astype(jnp.float32)          # (page, D)
-            v = v_ref[:, h, :].astype(jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            col = page * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            mask = col < attend
-            s = jnp.where(mask, s, NEG_INF)
+    if append:
+        slot = slot_ref[b]
+        here = page == slot // page_size
+        lane = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape[1:], 1)
+        fresh = here & (lane == slot % page_size)           # (D, page)
 
-            m_prev = m_ref[h][:, :1]                        # (G, 1)
-            l_prev = l_ref[h][:, :1]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-            p = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
-            corr = jnp.where(m_prev <= NEG_INF / 2, 0.0,
-                             jnp.exp(m_prev - m_safe))
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(
-                corr * l_prev + jnp.sum(p, axis=-1, keepdims=True),
-                l_ref.shape[1:])
-            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        @pl.when(here)
+        def _append():
+            for h in range(n_kv):
+                ko_ref[h] = jnp.where(fresh, kn_ref[h], k_ref[h])
+                vo_ref[h] = jnp.where(fresh, vn_ref[h], v_ref[h])
 
-    @pl.when(page == n_pages - 1)
+    for h in range(n_kv):                               # static unroll
+        q = q_ref[h].astype(jnp.float32) * scale        # (G, D)
+        k = k_ref[h]                                    # (D, page)
+        v = v_ref[h]
+        if append:
+            k = jnp.where(fresh, kn_ref[h], k)
+            v = jnp.where(fresh, vn_ref[h], v)
+        k = k.astype(jnp.float32)
+        v = v.astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        col = page * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        mask = col < attend
+        s = jnp.where(mask, s, NEG_INF)
+
+        m_prev = m_ref[h][:, :1]                        # (G, 1)
+        l_prev = l_ref[h][:, :1]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+        p = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
+        corr = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                         jnp.exp(m_prev - m_safe))
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(
+            corr * l_prev + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape[1:])
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(page == last)
     def _finish():
         l = jnp.maximum(l_ref[...][:, :, :1], 1e-20)
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def paged_decode_attention_tpu(q, k, v, attend_len, *, page_size: int = 128,
+def _live_pages(attend, page_size: int, n_pages: int):
+    """Pages a row visits: those below its depth, at least one."""
+    return jnp.clip((attend + page_size - 1) // page_size, 1, n_pages)
+
+
+def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
+                               slot=None, *, page_size: int = 128,
                                interpret: bool = False):
-    """q: (B, Hkv, G, D); k/v: (B, S, Hkv, D); attend_len: (B,) int32.
-    S must be a multiple of page_size.  Returns (B, Hkv, G, D) in q.dtype.
+    """q: (B, Hkv, G, D); k/v: (*L, B, Hkv, D, S) pool, token axis minor;
+    attend_len: (B,) int32; layer: (len(L),) int32 index of the layer to
+    attend.  S must be a multiple of page_size.  Returns (B, Hkv, G, D)
+    in q.dtype.
+
+    new: optional (k_new, v_new), each (B, Hkv, D, 1), written at
+    slot (B,) int32 (< attend_len) of each row of that layer before it is
+    attended; the call then returns (out, k, v) with k/v updated in place.
     """
     B, Hkv, G, D = q.shape
-    S = k.shape[1]
+    n_lead = k.ndim - 4
+    S = k.shape[-1]
     assert S % page_size == 0, (S, page_size)
     n_pages = S // page_size
     scale = 1.0 / math.sqrt(D)
+    append = new is not None
+    attend_len = jnp.asarray(attend_len, jnp.int32)
+    if slot is None:
+        slot = jnp.zeros((B,), jnp.int32)
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               page_size=page_size, n_pages=n_pages)
+                               page_size=page_size, n_pages=n_pages,
+                               append=append)
 
-    def kv_page(b, p, attend):
-        # clamp the fetched page to the row's last live one: Pallas skips
-        # the HBM->VMEM copy when consecutive grid steps map to the same
-        # block, so pages past attend_len cost no bandwidth (the compute
-        # guard in the kernel body already skips their math)
-        live = jnp.maximum((attend[b] + page_size - 1) // page_size, 1)
-        return (b, jnp.minimum(p, live - 1), 0, 0)
+    # the ragged schedule: row b's live pages are steps
+    # [ends[b] - live[b], ends[b]); entries past the total are never run
+    # (one compare-and-count: a searchsorted is a loop of small ops on
+    # the TPU, run for every layer of every step)
+    live = _live_pages(attend_len, page_size, n_pages)
+    ends = jnp.cumsum(live)
+    steps = jnp.arange(B * n_pages, dtype=jnp.int32)
+    row_of = jnp.minimum(jnp.sum(ends[None, :] <= steps[:, None], axis=1,
+                                 dtype=jnp.int32), B - 1)
+    page_of = steps - (ends - live)[row_of]
 
-    row = lambda b, p, _: (b, 0, 0, 0)
+    def lead(layer):
+        return tuple(layer[j] for j in range(n_lead))
+
+    def kv_page(i, layer, attend, slot, row, page):
+        return (*lead(layer), row[i], 0, 0, page[i])
+
+    def slot_page(i, layer, attend, slot, row, page):
+        # one output block per row, the page of its write slot: written
+        # back once, when the grid moves on to the next row
+        return (*lead(layer), row[i], 0, 0, slot[row[i]] // page_size)
+
+    row = lambda i, layer, attend, slot, rows, page: (rows[i], 0, 0, 0)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    page_block = (None,) * (n_lead + 1) + (Hkv, D, page_size)
+    in_specs = [vmem((None, Hkv, G, D), row),
+                vmem(page_block, kv_page), vmem(page_block, kv_page)]
+    out_specs = vmem((None, Hkv, G, D), row)
+    out_shape = jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype)
+    operands = [q, k, v]
+    aliases = {}
+    if append:
+        in_specs += [vmem((None, Hkv, D, 1), row)] * 2
+        out_specs = [out_specs, vmem(page_block, slot_page),
+                     vmem(page_block, slot_page)]
+        out_shape = [out_shape, jax.ShapeDtypeStruct(k.shape, k.dtype),
+                     jax.ShapeDtypeStruct(v.shape, v.dtype)]
+        operands += [n.astype(k.dtype) for n in new]
+        aliases = {6: 1, 7: 2}      # k, v (after 5 prefetch operands, q)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, n_pages),
-        in_specs=[
-            pl.BlockSpec((None, Hkv, G, D), row, memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, page_size, Hkv, D), kv_page,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, page_size, Hkv, D), kv_page,
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((None, Hkv, G, D), row,
-                               memory_space=pltpu.VMEM),
+        num_scalar_prefetch=5,
+        grid=(ends[-1],),
+        in_specs=in_specs,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((Hkv, G, 128), jnp.float32),   # running max
             pltpu.VMEM((Hkv, G, 128), jnp.float32),   # running normalizer
@@ -130,8 +217,10 @@ def paged_decode_attention_tpu(q, k, v, attend_len, *, page_size: int = 128,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(jnp.asarray(attend_len, jnp.int32), q, k, v)
+    )(jnp.asarray(layer, jnp.int32).reshape(n_lead), attend_len,
+      jnp.asarray(slot, jnp.int32), row_of, page_of, *operands)

@@ -12,6 +12,14 @@ bit-identical to the full-width dense path while doing only W/S of its
 work, so swapping it under `nn.attention.decode_attention` cannot change
 a single greedy token.  ``paged_decode_attention`` picks per backend and
 falls back to the dense reference when the cache width doesn't page.
+
+``paged_decode_append`` is the compiled kernel's write-and-attend step
+over a layer-stacked pool (*L, B, S, Hkv, D) with a ``layer`` index
+tuple, one index per leading axis: the form a decode step that updates
+its KV cache in place hands over.  The kernel writes the step's token
+and fetches that layer's pages straight from the pool; off the TPU the
+caller writes the token and slices the layer out (`layer_of`), then runs
+exactly the math above on a single cache.
 """
 from __future__ import annotations
 
@@ -25,6 +33,14 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels.common import auto_page_size
 from repro.kernels.decode_attention.kernel import paged_decode_attention_tpu
 from repro.kernels.decode_attention.ref import decode_attention_ref
+
+
+def layer_of(pool, layer):
+    """Layer `layer` (one index per leading axis) of a stacked pool."""
+    n = len(layer)
+    start = tuple(layer) + (0,) * (pool.ndim - n)
+    return jax.lax.dynamic_slice(pool, start, (1,) * n + pool.shape[n:]
+                                 ).reshape(pool.shape[n:])
 
 
 def _width_ladder(S: int, page_size: int) -> tuple[int, ...]:
@@ -70,18 +86,51 @@ def paged_decode_attention_jnp(q, k_cache, v_cache, attend_len, *,
 
 
 @partial(jax.jit, static_argnames=("page_size", "interpret"))
-def paged_decode_attention_op(q, k_cache, v_cache, attend_len, *,
-                              page_size: int = 128, interpret: bool):
-    """Pallas path in the framework layout: q (B, 1, Hq, D) -> same."""
+def paged_decode_attention_op(q, k_cache, v_cache, attend_len, layer=None,
+                              new=None, slot=None, *, page_size: int = 128,
+                              interpret: bool):
+    """Pallas path in the framework layout: q (B, 1, Hq, D) -> same.
+
+    k/v_cache: (B, S, Hkv, D), or with `layer` (an index per leading
+    axis) a (*L, B, S, Hkv, D) pool whose layer the kernel reads in
+    place.  new: optional (k_new, v_new), each (B, Hkv, D), written at
+    slot (B,) of each row before it is attended; the call then returns
+    (out, k_cache, v_cache), the caches updated in place."""
+    lead = layer is None
+    if lead:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], (0,)
     B, _, Hq, D = q.shape
-    Hkv = k_cache.shape[2]
+    Hkv = k_cache.shape[-2]
     G = Hq // Hkv
     attend = jnp.broadcast_to(jnp.asarray(attend_len, jnp.int32), (B,))
     qg = q[:, 0].reshape(B, Hkv, G, D)
-    out = paged_decode_attention_tpu(qg, k_cache, v_cache, attend,
-                                     page_size=page_size,
+    # token axis minor: the TPU's own HBM order for this cache, so the
+    # transpose is a bitcast and the kernel reads pages in place
+    tokens_minor = lambda c: jnp.moveaxis(c, -3, -1)
+    if new is not None:
+        new = tuple(n[..., None] for n in new)          # (B, Hkv, D, 1)
+    res = paged_decode_attention_tpu(qg, tokens_minor(k_cache),
+                                     tokens_minor(v_cache), attend,
+                                     jnp.stack(layer).astype(jnp.int32),
+                                     new, slot, page_size=page_size,
                                      interpret=interpret)
-    return out.reshape(B, 1, Hq, D)
+    if new is None:
+        return res.reshape(B, 1, Hq, D)
+    out, k_cache, v_cache = res
+    back = lambda c: jnp.moveaxis(c, -1, -3)[0] if lead \
+        else jnp.moveaxis(c, -1, -3)
+    return out.reshape(B, 1, Hq, D), back(k_cache), back(v_cache)
+
+
+def tpu_page(S: int, page_size: int | None = None) -> int:
+    """Page width of the compiled kernel over a cache of width S, 0 where
+    it does not run: off the TPU, or where the width does not page.  The
+    token axis is the kernel's lane axis, so a page is a multiple of 128
+    lanes or the whole width."""
+    page = page_size or auto_page_size(S)
+    if not page or jax.default_backend() != "tpu":
+        return 0
+    return page if page % 128 == 0 else S
 
 
 def paged_decode_attention(q, k_cache, v_cache, attend_len, *,
@@ -92,19 +141,11 @@ def paged_decode_attention(q, k_cache, v_cache, attend_len, *,
     on TPU, bit-identical blocked jnp elsewhere); anything else takes the
     dense reference, so callers never pay page-padding for tiny caches.
     """
-    S = k_cache.shape[1]
-    page = page_size or auto_page_size(S)
-    backend = jax.default_backend()
-    if not page:
-        return decode_attention_ref(q, k_cache, v_cache, attend_len)
-    if backend == "tpu":
-        op = partial(paged_decode_attention_op, page_size=page,
-                     interpret=False)
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh.empty:
-            return op(q, k_cache, v_cache, attend_len)
-        return _per_shard(op, mesh, q, k_cache, v_cache, attend_len)
-    if backend != "cpu":
+    tpu = tpu_page(k_cache.shape[1], page_size)
+    if tpu:
+        return _kernel(q, k_cache, v_cache, attend_len, None, None, tpu)
+    page = page_size or auto_page_size(k_cache.shape[1])
+    if not page or jax.default_backend() != "cpu":
         # the divisor-ladder bit-identity is an XLA *CPU* dot-panelization
         # property; an unverified backend (GPU) gets the dense reference
         # rather than a maybe-ULP-off switch branch
@@ -113,22 +154,56 @@ def paged_decode_attention(q, k_cache, v_cache, attend_len, *,
                                       page_size=page)
 
 
-def _per_shard(op, mesh, q, k_cache, v_cache, attend_len):
-    """Run the kernel on each device's shard of a meshed program (the
-    compiler cannot partition a Mosaic kernel itself).  Rows split over
-    the data axes and kv heads over "model", the layout
+def paged_decode_append(q, k_cache, v_cache, k_new, v_new, slot,
+                        attend_len, *, page_size: int, layer=None):
+    """The compiled kernel's write-and-attend step, at the page size
+    `tpu_page` gives: the step's K/V token k/v_new (B, Hkv, D) goes into
+    slot (B,) of each row (of layer `layer` of a pool), in place, and
+    that layer is attended over attend_len slots.  Returns
+    (out, k_cache, v_cache)."""
+    slot = jnp.broadcast_to(jnp.asarray(slot, jnp.int32), q.shape[:1])
+    return _kernel(q, k_cache, v_cache, attend_len, layer,
+                   ((k_new, v_new), slot), page_size)
+
+
+def _kernel(q, k_cache, v_cache, attend_len, layer, append, page_size):
+    """The compiled kernel, per shard of the ambient mesh if there is one
+    (the compiler cannot partition a Mosaic kernel itself).  Rows split
+    over the data axes and kv heads over "model", the layout
     `launch.partition` gives the slot pool and the attention weights; an
-    axis that does not divide stays whole.  Query heads split with their
-    kv heads: a contiguous block of Hq/m query heads uses exactly the
-    block of Hkv/m kv heads on the same device."""
-    B, Hkv = k_cache.shape[0], k_cache.shape[2]
+    axis that does not divide stays whole, and so do the pool's layer
+    axes.  Query heads split with their kv heads: a contiguous block of
+    Hq/m query heads uses exactly the block of Hkv/m kv heads on the same
+    device."""
+    op = partial(paged_decode_attention_op, page_size=page_size,
+                 interpret=False)
+    new, slot = append or (None, None)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return op(q, k_cache, v_cache, attend_len, layer, new, slot)
+    B, Hkv = k_cache.shape[-4], k_cache.shape[-2]
+    n_lead = k_cache.ndim - 4
     data = tuple(a for a in mesh.axis_names if a != "model")
     dsize = math.prod(mesh.shape[a] for a in data)
     msize = mesh.shape.get("model", 1)
     rows = data if data and B % dsize == 0 else None
     heads = "model" if msize > 1 and Hkv % msize == 0 else None
     spec = P(rows, None, heads, None)
+    pool = P(*(None,) * n_lead, rows, None, heads, None)
     attend = jnp.broadcast_to(jnp.asarray(attend_len, jnp.int32), (B,))
-    return jax.shard_map(op, mesh=mesh, in_specs=(spec, spec, spec, P(rows)),
-                         out_specs=spec, check_vma=False)(
-        q, k_cache, v_cache, attend)
+    lay = jnp.stack(layer or (0,)).astype(jnp.int32)
+    args = [q, k_cache, v_cache, attend, lay]
+    in_specs = [spec, pool, pool, P(rows), P()]
+    out_specs = spec
+    if new is not None:
+        tok = P(rows, heads, None)
+        args += [*new, slot]
+        in_specs += [tok, tok, P(rows)]
+        out_specs = (spec, pool, pool)
+
+    def per_shard(q, k, v, a, lay, *append):
+        new = (append[:2], append[2]) if append else ()
+        return op(q, k, v, a, tuple(lay) if n_lead else None, *new)
+
+    return jax.shard_map(per_shard, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)(*args)

@@ -520,49 +520,58 @@ def init_cache(cfg: ArchConfig, batch: int, context_len: int, *,
     return out
 
 
-def _decode_sublayer(cfg: ArchConfig, spec, p, x, cache_sb, counters, cache_len):
-    """One-token sublayer.  counters track per-kind index within superblock."""
+# carried-cache leaves of each recurrent kind, as (leaf, state key)
+_STATE_LEAVES = {
+    "mamba": (("mamba_conv", "conv"), ("mamba_ssm", "ssm")),
+    "mlstm": (("mlstm_C", "C"), ("mlstm_n", "n"), ("mlstm_m", "m")),
+    "slstm": (("slstm_h", "h"), ("slstm_c", "c"), ("slstm_n", "n"),
+              ("slstm_m", "m")),
+}
+
+
+def _layer_get(leaf, sb, i: int):
+    """Sublayer i of superblock sb (traced) of a (n_sb, n_kind, ...) leaf."""
+    start = (sb, i) + (0,) * (leaf.ndim - 2)
+    return jax.lax.dynamic_slice(leaf, start, (1, 1) + leaf.shape[2:])[0, 0]
+
+
+def _layer_put(leaf, sb, i: int, new):
+    """Write sublayer i of superblock sb into the leaf, in place."""
+    start = (sb, i) + (0,) * (leaf.ndim - 2)
+    return jax.lax.dynamic_update_slice(
+        leaf, new.astype(leaf.dtype)[None, None], start)
+
+
+def _decode_sublayer(cfg: ArchConfig, spec, p, x, cache, sb, counters,
+                     cache_len):
+    """One-token sublayer of superblock sb (traced) against the whole
+    carried decode cache, which it updates in place.  counters track the
+    per-kind index within the superblock."""
     h = _norm_apply(cfg, p["norm"], x)
-    if spec["kind"] == "attn":
-        i = counters["attn"]
-        y, k_new, v_new = attention_decode_apply(
-            p["attn"], h, cache_sb["k"][i], cache_sb["v"][i], cache_len,
+    kind = spec["kind"]
+    i = counters[kind]
+    counters[kind] += 1
+    cache = dict(cache)
+    if kind == "attn":
+        y, cache["k"], cache["v"] = attention_decode_apply(
+            p["attn"], h, cache["k"], cache["v"], cache_len,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta)
-        cache_sb = dict(cache_sb)
-        cache_sb["k"] = cache_sb["k"].at[i].set(k_new)
-        cache_sb["v"] = cache_sb["v"].at[i].set(v_new)
-        counters["attn"] += 1
-    elif spec["kind"] == "mamba":
-        i = counters["mamba"]
-        st = {"conv": cache_sb["mamba_conv"][i], "ssm": cache_sb["mamba_ssm"][i]}
-        y, st = mamba_decode_apply(p["mamba"], h, st, **_mamba_kwargs(cfg))
-        cache_sb = dict(cache_sb)
-        cache_sb["mamba_conv"] = cache_sb["mamba_conv"].at[i].set(st["conv"].astype(cache_sb["mamba_conv"].dtype))
-        cache_sb["mamba_ssm"] = cache_sb["mamba_ssm"].at[i].set(st["ssm"])
-        counters["mamba"] += 1
-    elif spec["kind"] == "mlstm":
-        i = counters["mlstm"]
-        st = {"C": cache_sb["mlstm_C"][i], "n": cache_sb["mlstm_n"][i],
-              "m": cache_sb["mlstm_m"][i]}
-        y, st = mlstm_decode_apply(p["cell"], h, st, n_heads=cfg.n_heads)
-        cache_sb = dict(cache_sb)
-        for nm in ("C", "n", "m"):
-            cache_sb[f"mlstm_{nm}"] = cache_sb[f"mlstm_{nm}"].at[i].set(st[nm])
-        counters["mlstm"] += 1
-    else:  # slstm
-        i = counters["slstm"]
-        st = {"h": cache_sb["slstm_h"][i], "c": cache_sb["slstm_c"][i],
-              "n": cache_sb["slstm_n"][i], "m": cache_sb["slstm_m"][i]}
-        y, st = slstm_decode_apply(p["cell"], h, st, n_heads=cfg.n_heads)
-        cache_sb = dict(cache_sb)
-        for nm in ("h", "c", "n", "m"):
-            cache_sb[f"slstm_{nm}"] = cache_sb[f"slstm_{nm}"].at[i].set(
-                st[nm].astype(cache_sb[f"slstm_{nm}"].dtype))
-        counters["slstm"] += 1
+            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+            layer=(sb, i))
+    else:
+        leaves = _STATE_LEAVES[kind]
+        st = {nm: _layer_get(cache[leaf], sb, i) for leaf, nm in leaves}
+        if kind == "mamba":
+            y, st = mamba_decode_apply(p["mamba"], h, st, **_mamba_kwargs(cfg))
+        elif kind == "mlstm":
+            y, st = mlstm_decode_apply(p["cell"], h, st, n_heads=cfg.n_heads)
+        else:
+            y, st = slstm_decode_apply(p["cell"], h, st, n_heads=cfg.n_heads)
+        for leaf, nm in leaves:
+            cache[leaf] = _layer_put(cache[leaf], sb, i, st[nm])
     x = x + y
     x, _ = _apply_ffn(cfg, spec, p, x, dropless=True)
-    return x, cache_sb
+    return x, cache
 
 
 def decode_step(cfg: ArchConfig, params, tokens, cache, cache_len):
@@ -572,6 +581,14 @@ def decode_step(cfg: ArchConfig, params, tokens, cache, cache_len):
     a (B,) vector (slot-pool decode: every row tracks its own context
     depth; attention writes/attends per row).  Returns (logits (B, vocab),
     new_cache).
+
+    The layer loop carries the whole cache, indexed by superblock: each
+    attention layer writes its new K/V with one update at [layer, row,
+    cache_len % S] and attends that layer of the stacked pool (the TPU
+    kernel reads its pages straight from it), recurrent layers update
+    their state slice.  Under a donated cache, as in the scheduler's
+    decode chunk, the step therefore moves one token of K/V per layer,
+    not the pool.  The encoder-decoder branch keeps its per-layer scan.
     """
     if jnp.ndim(cache_len) > 0:
         assert cfg.rope_theta > 0, \
@@ -602,15 +619,16 @@ def decode_step(cfg: ArchConfig, params, tokens, cache, cache_len):
     else:
         specs = sublayer_specs(cfg)
 
-        def body(h, xs):
-            sb_params, cache_sb = xs
-            counters = {"attn": 0, "mamba": 0, "mlstm": 0, "slstm": 0}
+        def body(carry, sb_params):
+            h, cache, sb = carry
+            counters = dict.fromkeys(_STATE_LEAVES, 0) | {"attn": 0}
             for spec, p in zip(specs, sb_params):
-                h, cache_sb = _decode_sublayer(cfg, spec, p, h, cache_sb,
-                                               counters, cache_len)
-            return h, cache_sb
+                h, cache = _decode_sublayer(cfg, spec, p, h, cache, sb,
+                                            counters, cache_len)
+            return (h, cache, sb + 1), None
 
-        x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+        (x, new_cache, _), _ = jax.lax.scan(
+            body, (x, cache, jnp.zeros((), jnp.int32)), params["blocks"])
 
     x = _norm_apply(cfg, params["final_norm"], x)
     logits = (x[:, 0].astype(jnp.float32)

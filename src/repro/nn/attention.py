@@ -18,7 +18,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode_attention.ops import paged_decode_attention
+from repro.kernels.decode_attention.ops import (
+    layer_of,
+    paged_decode_append,
+    paged_decode_attention,
+    tpu_page,
+)
 from repro.nn import init as initializers
 from repro.nn.linear import dense_apply, dense_init
 from repro.nn.module import split_keys
@@ -228,7 +233,7 @@ def cross_attention_decode(params, x, k, v, *, n_heads: int, head_dim: int):
 
 def attention_decode_apply(params, x, k_cache, v_cache, cache_len, *,
                            n_heads: int, n_kv_heads: int, head_dim: int,
-                           rope_theta: float = 10000.0):
+                           rope_theta: float = 10000.0, layer=None):
     """One-token decode.  x: (B, 1, d_model); cache_len: () or (B,) tokens
     seen so far (per-row counts let a slot pool decode rows that sit at
     different context depths in one program).
@@ -237,9 +242,15 @@ def attention_decode_apply(params, x, k_cache, v_cache, cache_len, *,
     full-attention archs size it to the max context).  The new token's K/V
     are written at cache_len % S; attention covers min(cache_len + 1, S)
     slots.  Returns (out (B,1,d_model), new_k_cache, new_v_cache).
+
+    With `layer` (one index per leading axis), k/v_cache are
+    (*L, B, S, Hkv, D) pools: the new K/V go in with one update at
+    [*layer, row, cache_len % S] and that layer is attended, so a caller
+    that carries the pool through its layer loop updates it in place
+    instead of slicing and restacking it per layer.
     """
     B = x.shape[0]
-    S = k_cache.shape[1]
+    S = k_cache.shape[-3]
     q, k, v = project_qkv(params, x, n_heads=n_heads, n_kv_heads=n_kv_heads,
                           head_dim=head_dim)
     pos = jnp.asarray(cache_len)
@@ -247,16 +258,32 @@ def attention_decode_apply(params, x, k_cache, v_cache, cache_len, *,
     if rope_theta > 0:
         q = apply_rope(q, pos_b, rope_theta)
         k = apply_rope(k, pos_b, rope_theta)
-    if pos.ndim == 0:
-        idx = pos % S
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), idx, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), idx, axis=1)
-    else:
-        idx = pos_b[:, 0] % S                  # per-row write slot
-        k_cache = k_cache.at[jnp.arange(B), idx].set(k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[jnp.arange(B), idx].set(v[:, 0].astype(v_cache.dtype))
     attend_len = jnp.minimum(pos + 1, S)
-    out = decode_attention(q, k_cache, v_cache, attend_len)
+    slot = pos % S
+    page = tpu_page(S)
+    if page:       # the TPU kernel writes the token and attends, in place
+        out, k_cache, v_cache = paged_decode_append(
+            q, k_cache, v_cache, k[:, 0], v[:, 0], slot, attend_len,
+            page_size=page, layer=layer)
+    else:
+        lead = () if layer is None else tuple(layer)
+        if pos.ndim == 0:
+            def put(cache, new):
+                new = new.astype(cache.dtype).reshape(
+                    (1,) * len(lead) + new.shape)
+                return jax.lax.dynamic_update_slice(
+                    cache, new, (*lead, 0, slot, 0, 0))
+        else:
+            idx = (*lead, jnp.arange(B), slot)    # per-row write slot
+
+            def put(cache, new):
+                return cache.at[idx].set(new[:, 0].astype(cache.dtype))
+        k_cache, v_cache = put(k_cache, k), put(v_cache, v)
+        if layer is None:
+            out = decode_attention(q, k_cache, v_cache, attend_len)
+        else:
+            out = decode_attention(q, layer_of(k_cache, layer),
+                                   layer_of(v_cache, layer), attend_len)
     out = out.reshape(B, 1, n_heads * head_dim)
     return dense_apply(params["wo"], out), k_cache, v_cache
 

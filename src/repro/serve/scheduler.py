@@ -16,9 +16,13 @@ bucket's page-rounded width (`page_size`) instead of the full pool width,
 so injecting a request copies only the pages its prompt covers — slots
 keep whatever stale keys the previous occupant left past that point, and
 decode masks them out by depth (a cache slot only becomes attendable the
-step its row writes it).  `decode_step`'s attention visits only the KV
-pages below the pool's deepest live row (`repro.kernels.decode_attention`),
-so a wide pool costs what its occupancy costs, not its capacity.
+step its row writes it).  The decode chunk donates the pool and
+`decode_step` carries it through its layer loop, writing each row's new
+K/V token in place, so a step moves one token per row and layer, never
+the pool.  Attention reads only the KV pages below each row's depth
+(`repro.kernels.decode_attention`), so its cost follows the pool's
+occupancy; the rest of the step (weights, projections, FFN, readout)
+runs every slot whatever its occupancy.
 
 Long prompts admit through *chunked prefill*: a prompt whose bucket
 exceeds `prefill_segment` is staged one segment at a time between decode

@@ -147,6 +147,68 @@ def test_pallas_paged_decode_bf16():
                                want.astype(jnp.float32), atol=3e-2, rtol=3e-2)
 
 
+# ---------------------------------------- layer-stacked pool, in place --
+
+
+def _pool(n_sb, n_attn, B, S, Hkv, D, key=KEY):
+    kk, kv = jax.random.split(key)
+    shape = (n_sb, n_attn, B, S, Hkv, D)
+    return jax.random.normal(kk, shape), jax.random.normal(kv, shape)
+
+
+@pytest.mark.parametrize("layer", [(0, 0), (2, 1)])     # first and last
+@pytest.mark.parametrize("B,S,Hq,Hkv,D,page", [
+    (4, 512, 14, 2, 64, 128),                           # qwen2-0.5b, G = 7
+    (3, 256, 4, 4, 32, 64),
+])
+def test_pallas_layer_indexed_equals_sliced_layer(layer, B, S, Hq, Hkv, D,
+                                                  page):
+    """The kernel reading one layer of a stacked pool in place gives what
+    the same kernel gives on that layer sliced out, for ragged depths
+    from 1 to the full width."""
+    k, v = _pool(3, 2, B, S, Hkv, D)
+    q = jax.random.normal(jax.random.PRNGKey(5), (B, 1, Hq, D))
+    attend = jnp.asarray([1, S, 77, page + 1][:B], jnp.int32)
+    got = paged_decode_attention_op(q, k, v, attend, layer, page_size=page,
+                                    interpret=True)
+    want = paged_decode_attention_op(q, k[layer], v[layer], attend,
+                                     page_size=page, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(
+        got, decode_attention_ref(q, k[layer], v[layer], attend),
+        atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [(0, 0), (2, 1), None])
+def test_pallas_append_writes_pool_in_place(layer):
+    """With the step's token, the kernel writes it into its slot of each
+    row of that layer (the rest of the pool untouched) and attends the
+    written layer: equal to writing with a scatter, then attending.
+    Slots cover a page's first and last lane, a mid page and a full ring
+    (attend_len == S with the slot behind it)."""
+    B, S, Hq, Hkv, D, page = 4, 512, 14, 2, 64, 128
+    k, v = _pool(3, 2, B, S, Hkv, D)
+    if layer is None:                   # a single cache
+        k, v = k[0, 0], v[0, 0]
+    kq, kn, vn = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(kq, (B, 1, Hq, D))
+    k_new = jax.random.normal(kn, (B, Hkv, D))
+    v_new = jax.random.normal(vn, (B, Hkv, D))
+    slot = jnp.asarray([0, 127, 300, 40], jnp.int32)
+    attend = jnp.asarray([1, 128, 301, S], jnp.int32)
+    out, k2, v2 = paged_decode_attention_op(
+        q, k, v, attend, layer, (k_new, v_new), slot, page_size=page,
+        interpret=True)
+    lead = () if layer is None else layer
+    idx = (*lead, jnp.arange(B), slot)
+    k_want, v_want = k.at[idx].set(k_new), v.at[idx].set(v_new)
+    np.testing.assert_array_equal(np.asarray(k2), np.asarray(k_want))
+    np.testing.assert_array_equal(np.asarray(v2), np.asarray(v_want))
+    want = paged_decode_attention_op(q, k_want[lead], v_want[lead],
+                                     attend, page_size=page, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
 # ---------------------------------------------- SWA ring / per-row depths --
 
 

@@ -1,6 +1,7 @@
 """Serving engine: batched generation, stop handling, determinism."""
 import jax
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.models import backbone as bb
@@ -157,3 +158,103 @@ def test_generate_matches_manual_decode_loop():
         toks.append(int(jnp.argmax(logits, -1)[0]))
         cl += 1
     np.testing.assert_array_equal(got, np.asarray(toks))
+
+
+# ------------------------------------------- carried-cache decode_step ----
+
+
+def _xs_ys_decode_step(cfg, params, tokens, cache, cache_len):
+    """The decode step as it was before the layer loop carried the cache:
+    each superblock's cache slice goes into the scan as xs, is updated
+    out of place, and is restacked as ys.  Kept here as the oracle."""
+    import jax.numpy as jnp
+    from repro.nn.attention import attention_decode_apply
+    from repro.nn.linear import embedding_apply
+    from repro.nn.ssm import mamba_decode_apply
+    from repro.nn.xlstm import mlstm_decode_apply, slstm_decode_apply
+
+    def sublayer(spec, p, x, cache_sb, counters):
+        h = bb._norm_apply(cfg, p["norm"], x)
+        kind = spec["kind"]
+        i = counters[kind]
+        counters[kind] += 1
+        cache_sb = dict(cache_sb)
+        if kind == "attn":
+            y, k_new, v_new = attention_decode_apply(
+                p["attn"], h, cache_sb["k"][i], cache_sb["v"][i], cache_len,
+                n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta)
+            cache_sb["k"] = cache_sb["k"].at[i].set(k_new)
+            cache_sb["v"] = cache_sb["v"].at[i].set(v_new)
+        else:
+            leaves = bb._STATE_LEAVES[kind]
+            st = {nm: cache_sb[leaf][i] for leaf, nm in leaves}
+            if kind == "mamba":
+                y, st = mamba_decode_apply(p["mamba"], h, st,
+                                           **bb._mamba_kwargs(cfg))
+            elif kind == "mlstm":
+                y, st = mlstm_decode_apply(p["cell"], h, st,
+                                           n_heads=cfg.n_heads)
+            else:
+                y, st = slstm_decode_apply(p["cell"], h, st,
+                                           n_heads=cfg.n_heads)
+            for leaf, nm in leaves:
+                cache_sb[leaf] = cache_sb[leaf].at[i].set(
+                    st[nm].astype(cache_sb[leaf].dtype))
+        x, _ = bb._apply_ffn(cfg, spec, p, x + y, dropless=True)
+        return x, cache_sb
+
+    specs = bb.sublayer_specs(cfg)
+
+    def body(h, xs):
+        sb_params, cache_sb = xs
+        counters = {"attn": 0, "mamba": 0, "mlstm": 0, "slstm": 0}
+        for spec, p in zip(specs, sb_params):
+            h, cache_sb = sublayer(spec, p, h, cache_sb, counters)
+        return h, cache_sb
+
+    x = embedding_apply(params["embed"], tokens)
+    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+    x = bb._norm_apply(cfg, params["final_norm"], x)
+    logits = (x[:, 0].astype(jnp.float32)
+              @ bb._readout_weight(cfg, params).astype(jnp.float32))
+    return logits, new_cache
+
+
+@pytest.mark.parametrize("arch,per_row", [
+    ("qwen2-0.5b", True), ("qwen2-0.5b", False),
+    ("jamba-1.5-large-398b", True), ("jamba-1.5-large-398b", False),
+    ("xlstm-350m", False),
+])
+def test_carried_cache_decode_step_bit_identical_to_xs_ys(arch, per_row):
+    """decode_step, which carries the whole cache through its layer loop
+    and updates it in place, gives bit for bit the logits and cache of the
+    xs/ys form, over several steps from a prefilled cache, with per-row
+    (slot pool) and scalar (equal-length engine) cache_len."""
+    import dataclasses
+    import jax.numpy as jnp
+
+    cfg = get_config(arch).reduced()
+    # two superblocks, so the carried superblock index is exercised
+    cfg = dataclasses.replace(cfg, n_layers=2 * cfg.superblock)
+    params = bb.init_params(cfg, KEY)
+    B, T = 3, 6
+    tokens = jax.random.randint(KEY, (B, T), 0, cfg.vocab)
+    logits, cache, total_T = bb.prefill(cfg, params, {"tokens": tokens},
+                                        max_len=64)
+    if per_row:
+        depth = jnp.asarray([total_T, total_T - 2, total_T - 5], jnp.int32)
+    else:
+        depth = jnp.int32(total_T)
+    new = jax.jit(lambda p, t, c, n: bb.decode_step(cfg, p, t, c, n))
+    old = jax.jit(lambda p, t, c, n: _xs_ys_decode_step(cfg, p, t, c, n))
+    c_new = c_old = cache
+    tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+    for _ in range(4):
+        lg_new, c_new = new(params, tok, c_new, depth)
+        lg_old, c_old = old(params, tok, c_old, depth)
+        np.testing.assert_array_equal(np.asarray(lg_new), np.asarray(lg_old))
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), c_new, c_old)
+        tok = jnp.argmax(lg_new, -1)[:, None].astype(jnp.int32)
+        depth = depth + 1

@@ -478,3 +478,28 @@ def test_engine_routes_deadlines_through_scheduler(system):
     assert eng._sched is not None             # scheduler path was taken
     assert not outs[0].timed_out
     np.testing.assert_array_equal(outs[0].tokens, ref)
+
+
+def test_decode_chunk_updates_the_pool_in_place(system):
+    """The compiled decode chunk never copies, broadcasts or rebuilds the
+    KV pool: no copy, broadcast or dynamic-update-slice in its optimized
+    HLO has the pool's K/V shape (the layer loop carries the pool and
+    writes one token per row and layer into it)."""
+    import re
+
+    import jax.numpy as jnp
+
+    cfg, params = system
+    sched = ContinuousScheduler(
+        cfg, params, max_len=64,
+        sched=SchedulerConfig(buckets=(8, 16), max_slots=4,
+                              prefill_group=2, chunk=4))
+    pool = sched._pool
+    k = pool["cache"]["k"]
+    text = sched._chunk.lower(params, pool, jnp.ones((4,), bool), KEY,
+                              jnp.int32(4)).compile().as_text()
+    shape = rf"f32\[{','.join(map(str, k.shape))}\]"
+    ops = re.findall(rf"= {shape}\S* ([a-z-]+)\(", text)
+    assert ops, "the pool's shape does not appear in the chunk's HLO"
+    bad = {"copy", "broadcast", "dynamic-update-slice"} & set(ops)
+    assert not bad, (bad, sorted(set(ops)))

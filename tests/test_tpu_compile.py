@@ -65,6 +65,43 @@ def test_decode_kernel_compiles_at_qwen2_0_5b_shapes(one_chip,
     assert "tpu_custom_call" in text
 
 
+def test_decode_chunk_compiles_in_place_for_the_chip(one_chip,
+                                                     no_compile_cache,
+                                                     monkeypatch):
+    """The scheduler's decode chunk compiled for the chip holds the
+    Pallas kernel and no copy, broadcast, dynamic-slice or
+    dynamic-update-slice of the KV pool's shape: the kernel writes each
+    row's token into the pool and reads its pages from it, in the layout
+    the TPU keeps the pool in.  The backend is reported as the TPU so
+    the chunk traces the kernel path."""
+    import re
+
+    from repro.configs import get_config
+    from repro.models import backbone as bb
+    from repro.serve.scheduler import ContinuousScheduler, SchedulerConfig
+
+    cfg = get_config("qwen2-0.5b").reduced()      # 14/2 heads of 64 kept
+    params = bb.init_params(cfg, jax.random.PRNGKey(0))
+    sched = ContinuousScheduler(
+        cfg, params, max_len=256,
+        sched=SchedulerConfig(buckets=(64,), max_slots=4, chunk=4))
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                             sharding=one_chip)
+    pool = jax.tree.map(on_chip, sched._pool)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = sched._chunk.lower(
+        jax.tree.map(on_chip, params), pool,
+        on_chip(jnp.ones((4,), bool)), on_chip(jax.random.PRNGKey(1)),
+        on_chip(jnp.int32(4))).compile().as_text()
+    assert "tpu_custom_call" in text
+    k = pool["cache"]["k"]
+    shape = rf"f32\[{','.join(map(str, k.shape))}\]"
+    ops = set(re.findall(rf"= {shape}\S* ([a-z-]+)\(", text))
+    bad = {"copy", "broadcast", "dynamic-slice", "dynamic-update-slice",
+           "fusion"} & ops
+    assert not bad, (bad, sorted(ops))
+
+
 def test_offload_fused_compiles_at_paper_size(one_chip, no_compile_cache):
     """AgileNN at 96x96: the extractor gives 24x24x24 features per image,
     so a batch of 64 is 36,864 rows of C 24, split at k 5 and quantized

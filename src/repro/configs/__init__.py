@@ -15,6 +15,8 @@ from repro.configs.agilenn_cifar import AgileNNConfig  # noqa: F401
 from repro.configs.base import (  # noqa: F401
     ArchConfig,
     InputShape,
+    MLASpec,
+    MoESpec,
     get_config,
     list_configs,
 )

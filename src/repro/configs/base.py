@@ -10,13 +10,48 @@ import jax.numpy as jnp
 
 @dataclass(frozen=True)
 class MoESpec:
-    n_experts: int
+    n_experts: int                 # the router's width: every expert of a layer
     top_k: int
     expert_d_ff: int
     every: int = 1                 # MoE FFN every `every`-th layer (jamba: 2)
     dense_residual_ff: int = 0     # arctic: parallel dense FFN width
     shared_expert_ff: int = 0      # moonshot: always-on shared expert width
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training dispatch only; serving is dropless
+    scoring: str = "softmax"       # softmax | sigmoid (DeepSeek-V3 / Moonlight)
+    select_bias: bool = False      # top-k chosen on score + a learned per-expert
+                                   # bias, weighted by the score alone (noaux_tc)
+    norm_topk: bool = True         # renormalize the chosen scores to sum to 1
+    routed_scale: float = 1.0      # routed_scaling_factor on the routed sum
+    n_held: int = 0                # experts whose weights this device holds
+                                   # (expert parallelism); 0: all of them
+    held_first: int = 0            # index of the first held expert
+
+    @property
+    def held(self) -> int:
+        """Experts this device holds and computes."""
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLASpec:
+    """Multi-head latent attention (DeepSeek-V2/V3), without a query
+    low-rank projection: keys and values are expanded per head from one
+    shared latent of `kv_lora_rank`, beside a `qk_rope_head_dim` key part
+    shared by every head that alone carries RoPE.  The decode cache holds
+    the normed latent and the roped shared key, one row of
+    kv_lora_rank + qk_rope_head_dim per token and layer."""
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -77,6 +112,10 @@ class ArchConfig:
     sliding_window: int = 0        # native SWA (mixtral: 4096)
     long_context_window: int = 8192  # SWA used for long_500k on full-attn archs
     moe: Optional[MoESpec] = None
+    mla: Optional[MLASpec] = None
+    first_dense: int = 0           # leading layers with a dense d_ff FFN in an
+                                   # MoE model (first_k_dense_replace)
+    norm_eps: float = 1e-6         # RMSNorm epsilon
     hybrid: Optional[HybridSpec] = None
     xlstm: Optional[XLSTMSpec] = None
     encdec: Optional[EncDecSpec] = None
@@ -100,8 +139,10 @@ class ArchConfig:
 
     @property
     def n_superblocks(self) -> int:
-        assert self.n_layers % self.superblock == 0, (self.name, self.n_layers, self.superblock)
-        return self.n_layers // self.superblock
+        """Scanned superblocks after the leading dense layers."""
+        n = self.n_layers - self.first_dense
+        assert n % self.superblock == 0, (self.name, n, self.superblock)
+        return n // self.superblock
 
     @property
     def dtype(self):
@@ -121,7 +162,12 @@ class ArchConfig:
                 top_k=min(2, self.moe.top_k),
                 expert_d_ff=min(128, self.moe.expert_d_ff),
                 dense_residual_ff=min(128, self.moe.dense_residual_ff),
-                shared_expert_ff=min(128, self.moe.shared_expert_ff))
+                shared_expert_ff=min(128, self.moe.shared_expert_ff),
+                n_held=0, held_first=0)
+        mla = None
+        if self.mla is not None:
+            mla = MLASpec(kv_lora_rank=32, qk_nope_head_dim=16,
+                          qk_rope_head_dim=8, v_head_dim=16)
         encdec = None
         if self.encdec is not None:
             encdec = dataclasses.replace(self.encdec, n_encoder_layers=2, n_frames=16)
@@ -133,10 +179,11 @@ class ArchConfig:
         max_sb = 1 if self.superblock > 1 else 2
         return dataclasses.replace(
             self, name=self.name + "-reduced",
-            n_layers=self.superblock * min(max_sb, self.n_superblocks),
+            n_layers=self.first_dense
+            + self.superblock * min(max_sb, self.n_superblocks),
             d_model=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
             head_dim=0, d_ff=min(self.d_ff, 512), vocab=min(self.vocab, 512),
-            moe=moe, encdec=encdec, vlm=vlm, param_dtype="float32")
+            moe=moe, mla=mla, encdec=encdec, vlm=vlm, param_dtype="float32")
 
 
 @dataclass(frozen=True)

@@ -37,8 +37,12 @@ and per-row dynamic-update-slice would each want the pool in another
 layout (a relayout copy of the whole pool per layer) or one op per row;
 here the write costs one page per row.
 
+Latent attention (MLA) caches one row per token, shared by every head:
+its keys are the whole row and its values the row's first `dv` rows, so
+the pool is one operand, Hkv is 1 and the G query heads are all heads.
+
 Layout: q (B, Hkv, G, D); k/v (*L, B, Hkv, D, S); new k/v (B, Hkv, D, 1);
-out (B, Hkv, G, D).
+out (B, Hkv, G, Dv).
 """
 from __future__ import annotations
 
@@ -54,21 +58,28 @@ NEG_INF = -1e30
 
 
 def _paged_decode_kernel(layer_ref, attend_ref, slot_ref, row_ref,
-                         page_ref, q_ref, k_ref, v_ref, *refs, scale: float,
-                         page_size: int, n_pages: int, append: bool):
-    """Refs (VMEM): q (Hkv, G, D); k/v (Hkv, D, page_size); o (Hkv, G, D);
-    with `append` also new k/v (Hkv, D, 1) in and the k/v pool pages
-    (Hkv, D, page_size) out.  layer_ref (SMEM) is read by the index maps
-    only; slot_ref (SMEM) holds each row's write slot, row_ref and
-    page_ref the row and page of each grid step.
+                         page_ref, q_ref, *refs, scale: float,
+                         page_size: int, n_pages: int, append: bool,
+                         dv: int):
+    """Refs (VMEM): q (Hkv, G, D); the pool pages, k and v (Hkv, D,
+    page_size) each, or with `dv` one shared page whose first dv rows are
+    the values; o (Hkv, G, Dv); with `append` also the new token of each
+    pool operand (Hkv, D, 1) in and its pool page (Hkv, D, page_size) out.
+    layer_ref (SMEM) is read by the index maps only; slot_ref (SMEM) holds
+    each row's write slot, row_ref and page_ref the row and page of each
+    grid step.
 
     Scratch: m/l (Hkv, G, 128) lane-replicated running max / normalizer,
-    acc (Hkv, G, D) unnormalized output accumulator.
+    acc (Hkv, G, Dv) unnormalized output accumulator.
     """
+    n_pool = 1 if dv else 2
+    pool_refs, refs = refs[:n_pool], refs[n_pool:]
     if append:
-        kn_ref, vn_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref = refs
+        new_refs, refs = refs[:n_pool], refs[n_pool:]
+        o_ref, out_refs = refs[0], refs[1:1 + n_pool]
     else:
-        o_ref, m_ref, l_ref, acc_ref = refs
+        o_ref = refs[0]
+    m_ref, l_ref, acc_ref = refs[-3:]
     step = pl.program_id(0)
     b = row_ref[step]
     page = page_ref[step]
@@ -85,24 +96,23 @@ def _paged_decode_kernel(layer_ref, attend_ref, slot_ref, row_ref,
     if append:
         slot = slot_ref[b]
         here = page == slot // page_size
-        lane = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape[1:], 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, pool_refs[0].shape[1:], 1)
         fresh = here & (lane == slot % page_size)           # (D, page)
 
         @pl.when(here)
         def _append():
             for h in range(n_kv):
-                ko_ref[h] = jnp.where(fresh, kn_ref[h], k_ref[h])
-                vo_ref[h] = jnp.where(fresh, vn_ref[h], v_ref[h])
+                for src, new, out in zip(pool_refs, new_refs, out_refs):
+                    out[h] = jnp.where(fresh, new[h], src[h])
 
     for h in range(n_kv):                               # static unroll
         q = q_ref[h].astype(jnp.float32) * scale        # (G, D)
-        k = k_ref[h]                                    # (D, page)
-        v = v_ref[h]
+        pages = [r[h] for r in pool_refs]               # (D, page) each
         if append:
-            k = jnp.where(fresh, kn_ref[h], k)
-            v = jnp.where(fresh, vn_ref[h], v)
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
+            pages = [jnp.where(fresh, new[h], pg)
+                     for new, pg in zip(new_refs, pages)]
+        k = pages[0].astype(jnp.float32)
+        v = (k[:dv] if dv else pages[1].astype(jnp.float32))
         s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         col = page * page_size + jax.lax.broadcasted_iota(
@@ -139,22 +149,28 @@ def _live_pages(attend, page_size: int, n_pages: int):
 
 def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
                                slot=None, *, page_size: int = 128,
-                               interpret: bool = False):
-    """q: (B, Hkv, G, D); k/v: (*L, B, Hkv, D, S) pool, token axis minor;
-    attend_len: (B,) int32; layer: (len(L),) int32 index of the layer to
-    attend.  S must be a multiple of page_size.  Returns (B, Hkv, G, D)
-    in q.dtype.
+                               interpret: bool = False,
+                               scale: float | None = None, dv: int = 0):
+    """q: (B, Hkv, G, D); k/v: (*L, B, Hkv, D, S) pool, token axis minor,
+    or with `dv` (latent attention) v None and the values the first dv
+    rows of each k row; attend_len: (B,) int32; layer: (len(L),) int32
+    index of the layer to attend.  S must be a multiple of page_size.
+    scale defaults to 1/sqrt(D).  Returns (B, Hkv, G, Dv) in q.dtype.
 
-    new: optional (k_new, v_new), each (B, Hkv, D, 1), written at
-    slot (B,) int32 (< attend_len) of each row of that layer before it is
-    attended; the call then returns (out, k, v) with k/v updated in place.
+    new: optional new token of each pool operand (k_new, v_new), or
+    (k_new,) with `dv`, each (B, Hkv, D, 1), written at slot (B,) int32
+    (< attend_len) of each row of that layer before it is attended; the
+    call then returns (out, k, v), or (out, k) with `dv`, the pool
+    updated in place.
     """
     B, Hkv, G, D = q.shape
     n_lead = k.ndim - 4
     S = k.shape[-1]
     assert S % page_size == 0, (S, page_size)
     n_pages = S // page_size
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
+    Dv = dv or D
+    pool = (k,) if dv else (k, v)
     append = new is not None
     attend_len = jnp.asarray(attend_len, jnp.int32)
     if slot is None:
@@ -162,7 +178,7 @@ def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                page_size=page_size, n_pages=n_pages,
-                               append=append)
+                               append=append, dv=dv)
 
     # the ragged schedule: row b's live pages are steps
     # [ends[b] - live[b], ends[b]); entries past the total are never run
@@ -189,20 +205,20 @@ def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
     row = lambda i, layer, attend, slot, rows, page: (rows[i], 0, 0, 0)
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     page_block = (None,) * (n_lead + 1) + (Hkv, D, page_size)
-    in_specs = [vmem((None, Hkv, G, D), row),
-                vmem(page_block, kv_page), vmem(page_block, kv_page)]
-    out_specs = vmem((None, Hkv, G, D), row)
-    out_shape = jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype)
-    operands = [q, k, v]
+    in_specs = [vmem((None, Hkv, G, D), row)] + \
+        [vmem(page_block, kv_page)] * len(pool)
+    out_specs = vmem((None, Hkv, G, Dv), row)
+    out_shape = jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype)
+    operands = [q, *pool]
     aliases = {}
     if append:
-        in_specs += [vmem((None, Hkv, D, 1), row)] * 2
-        out_specs = [out_specs, vmem(page_block, slot_page),
-                     vmem(page_block, slot_page)]
-        out_shape = [out_shape, jax.ShapeDtypeStruct(k.shape, k.dtype),
-                     jax.ShapeDtypeStruct(v.shape, v.dtype)]
+        in_specs += [vmem((None, Hkv, D, 1), row)] * len(pool)
+        out_specs = [out_specs] + [vmem(page_block, slot_page)] * len(pool)
+        out_shape = [out_shape] + [jax.ShapeDtypeStruct(p.shape, p.dtype)
+                                   for p in pool]
         operands += [n.astype(k.dtype) for n in new]
-        aliases = {6: 1, 7: 2}      # k, v (after 5 prefetch operands, q)
+        # each pool operand (after 5 prefetch operands and q) is its output
+        aliases = {6 + j: 1 + j for j in range(len(pool))}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(ends[-1],),
@@ -211,7 +227,7 @@ def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
         scratch_shapes=[
             pltpu.VMEM((Hkv, G, 128), jnp.float32),   # running max
             pltpu.VMEM((Hkv, G, 128), jnp.float32),   # running normalizer
-            pltpu.VMEM((Hkv, G, D), jnp.float32),     # unnormalized acc
+            pltpu.VMEM((Hkv, G, Dv), jnp.float32),    # unnormalized acc
         ],
     )
     return pl.pallas_call(

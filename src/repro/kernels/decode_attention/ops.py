@@ -13,6 +13,10 @@ work, so swapping it under `nn.attention.decode_attention` cannot change
 a single greedy token.  ``paged_decode_attention`` picks per backend and
 falls back to the dense reference when the cache width doesn't page.
 
+``mla_decode_attention_op`` runs latent attention's absorbed decode on
+the same kernel: one cached row per token holds the keys, and its first
+`dv` entries the values.
+
 ``paged_decode_append`` is the compiled kernel's write-and-attend step
 over a layer-stacked pool (*L, B, S, Hkv, D) with a ``layer`` index
 tuple, one index per leading axis: the form a decode step that updates
@@ -85,6 +89,44 @@ def paged_decode_attention_jnp(q, k_cache, v_cache, attend_len, *,
                           q, k_cache, v_cache, attend_len)
 
 
+def _pool_op(q, k_cache, v_cache, attend_len, layer, new, slot, *,
+             page_size: int, interpret: bool, scale=None, dv: int = 0):
+    """The kernel in the framework layout: q (B, 1, Hq, D) -> (B, 1, Hq,
+    Dv).  k/v_cache: (B, S, Hkv, D), or with `layer` (an index per leading
+    axis) a (*L, B, S, Hkv, D) pool whose layer the kernel reads in
+    place; with `dv` v_cache is None and the values are the first dv
+    entries of each k row.  new: optional new token of each pool operand,
+    (B, Hkv, D) each, written at slot (B,) of each row before it is
+    attended; the call then also returns the pool operands, updated in
+    place."""
+    lead = layer is None
+    pool = (k_cache,) if dv else (k_cache, v_cache)
+    if lead:
+        pool, layer = tuple(c[None] for c in pool), (0,)
+    B, _, Hq, D = q.shape
+    Hkv = pool[0].shape[-2]
+    G = Hq // Hkv
+    attend = jnp.broadcast_to(jnp.asarray(attend_len, jnp.int32), (B,))
+    qg = q[:, 0].reshape(B, Hkv, G, D)
+    # token axis minor: the TPU's own HBM order for this cache, so the
+    # transpose is a bitcast and the kernel reads pages in place
+    tokens_minor = lambda c: jnp.moveaxis(c, -3, -1)
+    if new is not None:
+        new = tuple(n[..., None] for n in new)          # (B, Hkv, D, 1)
+    pool_t = [tokens_minor(c) for c in pool]
+    res = paged_decode_attention_tpu(
+        qg, pool_t[0], None if dv else pool_t[1], attend,
+        jnp.stack(layer).astype(jnp.int32), new, slot, page_size=page_size,
+        interpret=interpret, scale=scale, dv=dv)
+    Dv = dv or D
+    if new is None:
+        return res.reshape(B, 1, Hq, Dv)
+    out, *pool = res
+    back = lambda c: jnp.moveaxis(c, -1, -3)[0] if lead \
+        else jnp.moveaxis(c, -1, -3)
+    return (out.reshape(B, 1, Hq, Dv), *map(back, pool))
+
+
 @partial(jax.jit, static_argnames=("page_size", "interpret"))
 def paged_decode_attention_op(q, k_cache, v_cache, attend_len, layer=None,
                               new=None, slot=None, *, page_size: int = 128,
@@ -96,30 +138,25 @@ def paged_decode_attention_op(q, k_cache, v_cache, attend_len, layer=None,
     place.  new: optional (k_new, v_new), each (B, Hkv, D), written at
     slot (B,) of each row before it is attended; the call then returns
     (out, k_cache, v_cache), the caches updated in place."""
-    lead = layer is None
-    if lead:
-        k_cache, v_cache, layer = k_cache[None], v_cache[None], (0,)
-    B, _, Hq, D = q.shape
-    Hkv = k_cache.shape[-2]
-    G = Hq // Hkv
-    attend = jnp.broadcast_to(jnp.asarray(attend_len, jnp.int32), (B,))
-    qg = q[:, 0].reshape(B, Hkv, G, D)
-    # token axis minor: the TPU's own HBM order for this cache, so the
-    # transpose is a bitcast and the kernel reads pages in place
-    tokens_minor = lambda c: jnp.moveaxis(c, -3, -1)
-    if new is not None:
-        new = tuple(n[..., None] for n in new)          # (B, Hkv, D, 1)
-    res = paged_decode_attention_tpu(qg, tokens_minor(k_cache),
-                                     tokens_minor(v_cache), attend,
-                                     jnp.stack(layer).astype(jnp.int32),
-                                     new, slot, page_size=page_size,
-                                     interpret=interpret)
-    if new is None:
-        return res.reshape(B, 1, Hq, D)
-    out, k_cache, v_cache = res
-    back = lambda c: jnp.moveaxis(c, -1, -3)[0] if lead \
-        else jnp.moveaxis(c, -1, -3)
-    return out.reshape(B, 1, Hq, D), back(k_cache), back(v_cache)
+    return _pool_op(q, k_cache, v_cache, attend_len, layer, new, slot,
+                    page_size=page_size, interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("page_size", "interpret", "scale", "dv"))
+def mla_decode_attention_op(q, cache, attend_len, layer=None, new=None,
+                            slot=None, *, page_size: int = 128,
+                            interpret: bool, scale: float, dv: int):
+    """Latent attention's decode step on the same kernel, under its own
+    name in a device trace.  q: (B, 1, H, Dl) absorbed queries (the
+    latent part folded through W_UK, then the rope part); cache: (B, S,
+    1, Dl) latent rows, or a (*L, B, S, 1, Dl) pool with `layer`; the
+    values are each row's first dv entries.  new: optional (B, 1, Dl)
+    latent row written at slot (B,) first; the call then returns
+    (out (B, 1, H, dv), cache)."""
+    return _pool_op(q, cache, None, attend_len, layer,
+                    None if new is None else (new,), slot,
+                    page_size=page_size, interpret=interpret, scale=scale,
+                    dv=dv)
 
 
 def tpu_page(S: int, page_size: int | None = None) -> int:

@@ -8,6 +8,13 @@ jax.lax.scan — this keeps HLO size and compile time bounded at
 (remat) so train-time activation memory is O(layers * B * T * d_model)
 instead of O(layers * B * T * d_ff).
 
+A model may lead with dense layers before its superblocks
+(`ArchConfig.first_dense`, DeepSeek-V3's first_k_dense_replace): they
+form a stack of their own (params["lead"]), scanned before the
+superblocks; the decode cache counts them first along its superblock
+axis.  Attention is GQA, or multi-head latent attention (`ArchConfig.mla`,
+`nn.mla`), whose cache is one "latent" leaf of rows shared by all heads.
+
 Modes:
   forward(..., labels)      training loss (+ MoE aux losses)
   prefill(...)              logits of last position + decode cache
@@ -35,7 +42,8 @@ from repro.nn.attention import (
     project_qkv,
 )
 from repro.nn.linear import dense_apply, dense_init, embedding_apply, embedding_init
-from repro.nn.moe import moe_apply, moe_init
+from repro.nn.mla import mla_apply, mla_chunk_attend, mla_decode_apply, mla_init, mla_latent
+from repro.nn.moe import moe_apply, moe_init, moe_serve
 from repro.nn.module import split_keys
 from repro.nn.norm import layernorm_apply, layernorm_init, rmsnorm_apply, rmsnorm_init
 from repro.nn.rope import apply_rope
@@ -65,7 +73,8 @@ def _norm_init(cfg: ArchConfig, dim=None):
 
 
 def _norm_apply(cfg: ArchConfig, p, x):
-    return rmsnorm_apply(p, x) if cfg.norm == "rmsnorm" else layernorm_apply(p, x)
+    return (rmsnorm_apply(p, x, eps=cfg.norm_eps) if cfg.norm == "rmsnorm"
+            else layernorm_apply(p, x))
 
 
 def sinusoidal_positions(T: int, d: int, offset=0) -> jnp.ndarray:
@@ -94,6 +103,45 @@ def sublayer_specs(cfg: ArchConfig) -> list[dict]:
     return specs
 
 
+_LEAD_SPECS = [{"kind": "attn", "ffn": "dense"}]
+
+
+def _stacks(cfg: ArchConfig, params) -> list:
+    """(sublayer specs, stacked params, index of the stack's first layer
+    along the cache's superblock axis) of each scanned stack: the leading
+    dense layers, if any, then the superblocks."""
+    out = []
+    if cfg.first_dense:
+        out.append((_LEAD_SPECS, params["lead"], 0))
+    out.append((sublayer_specs(cfg), params["blocks"], cfg.first_dense))
+    return out
+
+
+_EXPERT_LEAVES = ("gate", "up", "down")
+
+
+def _hoist_experts(specs, stack):
+    """A serving scan's stack with each expert layer's expert weights
+    taken out, and those weights, still stacked over the stack's layers
+    (one dict per sublayer, None where it has no experts): the expert
+    kernel reads the layer it needs from the stack in place, where the
+    scan would first slice a copy of all of a layer's experts."""
+    stack, experts = list(stack), []
+    for j, spec in enumerate(specs):
+        if spec["ffn"] != "moe":
+            experts.append(None)
+            continue
+        moe = dict(stack[j]["moe"])
+        experts.append({k: moe.pop(k) for k in _EXPERT_LEAVES})
+        stack[j] = dict(stack[j], moe=moe)
+    return tuple(stack), experts
+
+
+def _with_experts(p, experts):
+    """Sublayer params p with its hoisted expert stacks put back."""
+    return p if experts is None else dict(p, moe=dict(p["moe"], **experts))
+
+
 def _mamba_kwargs(cfg: ArchConfig) -> dict:
     h = cfg.hybrid
     return dict(d_state=h.d_state, d_conv=h.d_conv)
@@ -105,7 +153,10 @@ def _mamba_kwargs(cfg: ArchConfig) -> dict:
 def _init_sublayer(cfg: ArchConfig, spec: dict, key) -> dict:
     kk = split_keys(key, ["mix", "norm", "ffn", "ffn_norm", "extra", "shared"])
     p: dict[str, Any] = {"norm": _norm_init(cfg)}
-    if spec["kind"] == "attn":
+    if spec["kind"] == "attn" and cfg.mla is not None:
+        p["attn"] = mla_init(kk["mix"], cfg.d_model, cfg.n_heads, cfg.mla,
+                             dtype=cfg.dtype)
+    elif spec["kind"] == "attn":
         p["attn"] = attention_init(kk["mix"], cfg.d_model, cfg.n_heads,
                                    cfg.n_kv_heads, cfg.resolved_head_dim,
                                    qkv_bias=cfg.qkv_bias, dtype=cfg.dtype)
@@ -127,7 +178,9 @@ def _init_sublayer(cfg: ArchConfig, spec: dict, key) -> dict:
     elif spec["ffn"] == "moe":
         p["ffn_norm"] = _norm_init(cfg)
         p["moe"] = moe_init(kk["ffn"], cfg.d_model, cfg.moe.expert_d_ff,
-                            cfg.moe.n_experts, dtype=cfg.dtype)
+                            cfg.moe.n_experts, dtype=cfg.dtype,
+                            n_held=cfg.moe.n_held,
+                            select_bias=cfg.moe.select_bias)
         if cfg.moe.dense_residual_ff:
             p["dense_res"] = swiglu_ffn_init(kk["extra"], cfg.d_model,
                                              cfg.moe.dense_residual_ff, dtype=cfg.dtype)
@@ -137,8 +190,8 @@ def _init_sublayer(cfg: ArchConfig, spec: dict, key) -> dict:
     return p
 
 
-def _init_superblock(cfg: ArchConfig, key):
-    specs = sublayer_specs(cfg)
+def _init_superblock(cfg: ArchConfig, key, specs=None):
+    specs = specs or sublayer_specs(cfg)
     keys = jax.random.split(key, len(specs))
     return tuple(_init_sublayer(cfg, s, k) for s, k in zip(specs, keys))
 
@@ -170,7 +223,7 @@ def _init_decoder_layer_encdec(cfg: ArchConfig, key) -> dict:
 
 def init_params(cfg: ArchConfig, key) -> dict:
     kk = split_keys(key, ["embed", "blocks", "final_norm", "head", "vision",
-                          "encoder"])
+                          "encoder", "lead"])
     params: dict[str, Any] = {
         "embed": embedding_init(kk["embed"], cfg.vocab, cfg.d_model, cfg.dtype),
         "final_norm": _norm_init(cfg),
@@ -184,6 +237,10 @@ def init_params(cfg: ArchConfig, key) -> dict:
     else:
         sb_keys = jax.random.split(kk["blocks"], cfg.n_superblocks)
         params["blocks"] = jax.vmap(partial(_init_superblock, cfg))(sb_keys)
+        if cfg.first_dense:
+            params["lead"] = jax.vmap(partial(
+                _init_superblock, cfg, specs=_LEAD_SPECS))(
+                    jax.random.split(kk["lead"], cfg.first_dense))
     if cfg.vlm is not None:
         params["vision_proj"] = dense_init(kk["vision"], cfg.vlm.vision_dim,
                                            cfg.d_model, dtype=cfg.dtype)
@@ -196,12 +253,16 @@ def init_params(cfg: ArchConfig, key) -> dict:
 # --------------------------------------------------------------- forward ---
 
 
-def _apply_ffn(cfg: ArchConfig, spec, p, x, *, dropless: bool = False):
+def _apply_ffn(cfg: ArchConfig, spec, p, x, *, dropless: bool = False,
+               valid=None, layer=None):
     """Post-mixer FFN sublayer.  Returns (x, aux).
 
-    dropless=True (inference) sizes MoE capacity so no token is dropped;
-    training keeps the configured capacity factor (tokens over capacity
-    fall through the residual, standard GShard/Switch behaviour).
+    dropless=True (prefill, decode) runs MoE through the dropless serving
+    layer over the held experts (`moe_serve`; `valid` (B, T) marks the
+    rows that route; `layer`, with expert weights stacked over layers,
+    which of them to use) and aux holds its expert counts; training keeps the
+    capacity dispatch (tokens over capacity fall through the residual,
+    standard GShard/Switch behaviour) and aux its losses.
     """
     aux = {}
     if spec["ffn"] == "none":
@@ -213,16 +274,14 @@ def _apply_ffn(cfg: ArchConfig, spec, p, x, *, dropless: bool = False):
         else:
             y = swiglu_ffn_apply(p["ffn"], h)
     else:
+        m = cfg.moe
         if dropless:
-            # provably dropless when the expert count is small; for very
-            # wide MoEs (arctic: 128e) a 4x capacity factor keeps memory
-            # bounded with negligible overflow probability
-            e_over_k = cfg.moe.n_experts / cfg.moe.top_k
-            cap = e_over_k if cfg.moe.n_experts <= 8 else min(4.0, e_over_k)
+            y, aux = moe_serve(p["moe"], h, m, valid=valid, layer=layer)
         else:
-            cap = cfg.moe.capacity_factor
-        y, aux = moe_apply(p["moe"], h, top_k=cfg.moe.top_k,
-                           capacity_factor=cap)
+            y, aux = moe_apply(p["moe"], h, top_k=m.top_k,
+                               capacity_factor=m.capacity_factor,
+                               scoring=m.scoring, norm_topk=m.norm_topk,
+                               routed_scale=m.routed_scale)
         if "dense_res" in p:
             y = y + swiglu_ffn_apply(p["dense_res"], h)
         if "shared" in p:
@@ -231,11 +290,19 @@ def _apply_ffn(cfg: ArchConfig, spec, p, x, *, dropless: bool = False):
 
 
 def _apply_sublayer(cfg: ArchConfig, spec, p, x, *, window: int,
-                    dropless: bool = False, kv_valid_len=None):
+                    dropless: bool = False, kv_valid_len=None, layer=None):
     """Full-sequence (train/prefill) sublayer.  Returns (x, kv_or_state, aux)."""
     h = _norm_apply(cfg, p["norm"], x)
     state = None
-    if spec["kind"] == "attn":
+    valid = None
+    if kv_valid_len is not None:
+        valid = jnp.arange(x.shape[1])[None, :] < kv_valid_len[:, None]
+    if spec["kind"] == "attn" and cfg.mla is not None:
+        y, latent = mla_apply(p["attn"], h, n_heads=cfg.n_heads,
+                              spec=cfg.mla, rope_theta=cfg.rope_theta,
+                              window=window, kv_valid_len=kv_valid_len)
+        state = latent[:, :, None]                     # (B, T, 1, Dl)
+    elif spec["kind"] == "attn":
         y, k, v = attention_apply(
             p["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.resolved_head_dim, causal=True, window=window,
@@ -249,7 +316,8 @@ def _apply_sublayer(cfg: ArchConfig, spec, p, x, *, window: int,
     elif spec["kind"] == "slstm":
         y, state = slstm_apply(p["cell"], h, n_heads=cfg.n_heads, return_state=True)
     x = x + y
-    x, aux = _apply_ffn(cfg, spec, p, x, dropless=dropless)
+    x, aux = _apply_ffn(cfg, spec, p, x, dropless=dropless, valid=valid,
+                        layer=layer)
     return x, state, aux
 
 
@@ -272,35 +340,48 @@ def _moe_layer_count(cfg: ArchConfig) -> int:
 def _run_superblocks(cfg: ArchConfig, params, x, *, window: int,
                      collect_cache: bool = False, remat: bool = True,
                      dropless: bool = False, kv_valid_len=None):
-    """Scan over stacked superblocks.  Returns (x, aux, caches or None)."""
-    specs = sublayer_specs(cfg)
+    """Scan over the stacked leading layers, then superblocks.  Returns
+    (x, aux, caches or None); the stacks' caches join along the
+    superblock axis."""
     n_moe = _moe_layer_count(cfg)
+    aux, caches = _zero_aux(), []
+    for specs, stack, _ in _stacks(cfg, params):
+        experts = [None] * len(specs)
+        if dropless:
+            stack, experts = _hoist_experts(specs, stack)
 
-    # NOTE: sb_params is a tuple of per-sublayer dicts (the scan strips the
-    # stacked leading axis); iterate positionally.
-    def body(carry, sb_params):
-        h, aux_acc = carry
-        states = []
-        for spec, p in zip(specs, sb_params):
-            h, st, aux = _apply_sublayer(cfg, spec, p, h, window=window,
-                                         dropless=dropless,
-                                         kv_valid_len=kv_valid_len)
-            aux_acc = _acc_aux(aux_acc, aux, n_moe)
-            states.append(st)
-        out = _stack_states(cfg, specs, states) if collect_cache else None
-        return (h, aux_acc), out
+        # NOTE: sb_params is a tuple of per-sublayer dicts (the scan strips
+        # the stacked leading axis); iterate positionally.
+        def body(carry, sb_params, specs=specs, experts=experts):
+            h, aux_acc, l = carry
+            states = []
+            for spec, p, ex in zip(specs, sb_params, experts):
+                h, st, aux = _apply_sublayer(
+                    cfg, spec, _with_experts(p, ex), h, window=window,
+                    dropless=dropless, kv_valid_len=kv_valid_len,
+                    layer=None if ex is None else l)
+                if not dropless:
+                    aux_acc = _acc_aux(aux_acc, aux, n_moe)
+                states.append(st)
+            out = _stack_states(cfg, specs, states) if collect_cache else None
+            return (h, aux_acc, l + 1), out
 
-    if remat:
-        body = jax.checkpoint(body)
-    (x, aux), caches = jax.lax.scan(body, (x, _zero_aux()), params["blocks"])
-    return x, aux, caches
+        if remat:
+            body = jax.checkpoint(body)
+        (x, aux, _), c = jax.lax.scan(body, (x, aux, jnp.int32(0)), stack)
+        caches.append(c)
+    if len(caches) > 1 and collect_cache:
+        caches = [jax.tree.map(lambda *a: jnp.concatenate(a), *caches)]
+    return x, aux, caches[-1]
 
 
 def _stack_states(cfg, specs, states):
     """Group per-sublayer prefill states by kind for the decode cache."""
     out = {}
     attn_states = [s for spec, s in zip(specs, states) if spec["kind"] == "attn"]
-    if attn_states:
+    if attn_states and cfg.mla is not None:
+        out["latent"] = jnp.stack(attn_states)   # (n_attn, B, T, 1, Dl)
+    elif attn_states:
         out["k"] = jnp.stack([k for k, _ in attn_states])   # (n_attn, B, T, Hkv, D)
         out["v"] = jnp.stack([v for _, v in attn_states])
     mamba_states = [s for spec, s in zip(specs, states) if spec["kind"] == "mamba"]
@@ -325,6 +406,14 @@ def _readout_weight(cfg: ArchConfig, params):
     if cfg.tie_embeddings:
         return params["embed"]["table"].T          # (d, V)
     return params["lm_head"]["w"]
+
+
+def _readout(cfg: ArchConfig, params, x):
+    """Float32 logits of hidden states x (..., d): x in the weights' dtype
+    against them, accumulated in float32 (a bfloat16 head is never
+    widened in memory)."""
+    w = _readout_weight(cfg, params)
+    return jnp.matmul(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
 
 def chunked_cross_entropy(x, w_vocab, labels, *, chunk: int = 512,
@@ -499,7 +588,13 @@ def init_cache(cfg: ArchConfig, batch: int, context_len: int, *,
     n_mamba = sum(1 for s in specs if s["kind"] == "mamba")
     n_ml = sum(1 for s in specs if s["kind"] == "mlstm")
     n_sl = sum(1 for s in specs if s["kind"] == "slstm")
-    if n_attn:
+    if cfg.first_dense:
+        assert n_attn == 1 and cfg.superblock == 1, cfg.name
+        n_sb += cfg.first_dense        # the leading layers come first
+    if n_attn and cfg.mla is not None:
+        out["latent"] = jnp.zeros((n_sb, n_attn, batch, S, 1,
+                                   cfg.mla.latent_dim), cfg.dtype)
+    elif n_attn:
         shape = (n_sb, n_attn, batch, S, cfg.n_kv_heads, hd)
         out["k"] = jnp.zeros(shape, cfg.dtype)
         out["v"] = jnp.zeros(shape, cfg.dtype)
@@ -519,6 +614,10 @@ def init_cache(cfg: ArchConfig, batch: int, context_len: int, *,
         out["slstm_m"] = jnp.full((n_sb, n_sl, batch, cfg.d_model), -1e30, jnp.float32)
     return out
 
+
+# cache leaves with a token axis, (..., B, S, H, D): what the slot pool
+# pages, the prefix cache shares, and prefill ring-compacts
+_TOKEN_LEAVES = ("k", "v", "latent")
 
 # carried-cache leaves of each recurrent kind, as (leaf, state key)
 _STATE_LEAVES = {
@@ -543,16 +642,21 @@ def _layer_put(leaf, sb, i: int, new):
 
 
 def _decode_sublayer(cfg: ArchConfig, spec, p, x, cache, sb, counters,
-                     cache_len):
+                     cache_len, live, layer=None):
     """One-token sublayer of superblock sb (traced) against the whole
     carried decode cache, which it updates in place.  counters track the
-    per-kind index within the superblock."""
+    per-kind index within the superblock.  Returns (x, cache, MoE counts
+    [held experts hit, routings landed here])."""
     h = _norm_apply(cfg, p["norm"], x)
     kind = spec["kind"]
     i = counters[kind]
     counters[kind] += 1
     cache = dict(cache)
-    if kind == "attn":
+    if kind == "attn" and cfg.mla is not None:
+        y, cache["latent"] = mla_decode_apply(
+            p["attn"], h, cache["latent"], cache_len, n_heads=cfg.n_heads,
+            spec=cfg.mla, rope_theta=cfg.rope_theta, layer=(sb, i))
+    elif kind == "attn":
         y, cache["k"], cache["v"] = attention_decode_apply(
             p["attn"], h, cache["k"], cache["v"], cache_len,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
@@ -570,25 +674,43 @@ def _decode_sublayer(cfg: ArchConfig, spec, p, x, cache, sb, counters,
         for leaf, nm in leaves:
             cache[leaf] = _layer_put(cache[leaf], sb, i, st[nm])
     x = x + y
-    x, _ = _apply_ffn(cfg, spec, p, x, dropless=True)
-    return x, cache
+    x, aux = _apply_ffn(cfg, spec, p, x, dropless=True,
+                        valid=None if live is None else live[:, None],
+                        layer=layer)
+    counts = (jnp.stack([aux["experts_hit"], aux["routed_local"]])
+              if spec["ffn"] == "moe" else jnp.zeros((2,), jnp.int32))
+    return x, cache, counts
 
 
-def decode_step(cfg: ArchConfig, params, tokens, cache, cache_len):
+def decode_step(cfg: ArchConfig, params, tokens, cache, cache_len,
+                live=None):
     """One decoding step.  tokens: (B, 1) int32; cache from init_cache/prefill.
+    Returns (logits (B, vocab), new_cache); see `decode_step_counted`."""
+    logits, cache, _ = decode_step_counted(cfg, params, tokens, cache,
+                                           cache_len, live)
+    return logits, cache
+
+
+def decode_step_counted(cfg: ArchConfig, params, tokens, cache, cache_len,
+                        live=None):
+    """One decoding step that also counts its expert work.
 
     cache_len may be a scalar (all rows at one depth, the classic path) or
     a (B,) vector (slot-pool decode: every row tracks its own context
-    depth; attention writes/attends per row).  Returns (logits (B, vocab),
-    new_cache).
+    depth; attention writes/attends per row).  live: optional (B,) bool,
+    the rows that decode a request: the others route no token to an
+    expert (their logits are computed and ignored).  Returns (logits
+    (B, vocab), new_cache, counts (2,) int32: held experts hit and
+    routings that landed on them, summed over the MoE layers).
 
     The layer loop carries the whole cache, indexed by superblock: each
-    attention layer writes its new K/V with one update at [layer, row,
-    cache_len % S] and attends that layer of the stacked pool (the TPU
-    kernel reads its pages straight from it), recurrent layers update
-    their state slice.  Under a donated cache, as in the scheduler's
-    decode chunk, the step therefore moves one token of K/V per layer,
-    not the pool.  The encoder-decoder branch keeps its per-layer scan.
+    attention layer writes its new K/V (or latent row) with one update at
+    [layer, row, cache_len % S] and attends that layer of the stacked
+    pool (the TPU kernel reads its pages straight from it), recurrent
+    layers update their state slice.  Under a donated cache, as in the
+    scheduler's decode chunk, the step therefore moves one token of K/V
+    per layer, not the pool.  The encoder-decoder branch keeps its
+    per-layer scan.
     """
     if jnp.ndim(cache_len) > 0:
         assert cfg.rope_theta > 0, \
@@ -597,6 +719,7 @@ def decode_step(cfg: ArchConfig, params, tokens, cache, cache_len):
     x = embedding_apply(params["embed"], tokens)
     if cfg.rope_theta == 0:
         x = x + sinusoidal_positions(1, cfg.d_model, offset=cache_len).astype(x.dtype)
+    counts = jnp.zeros((2,), jnp.int32)
 
     if cfg.encdec is not None:
         def body(h, xs):
@@ -617,23 +740,26 @@ def decode_step(cfg: ArchConfig, params, tokens, cache, cache_len):
 
         x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
     else:
-        specs = sublayer_specs(cfg)
+        new_cache = cache
+        for specs, stack, sb0 in _stacks(cfg, params):
+            stack, experts = _hoist_experts(specs, stack)
 
-        def body(carry, sb_params):
-            h, cache, sb = carry
-            counters = dict.fromkeys(_STATE_LEAVES, 0) | {"attn": 0}
-            for spec, p in zip(specs, sb_params):
-                h, cache = _decode_sublayer(cfg, spec, p, h, cache, sb,
-                                            counters, cache_len)
-            return (h, cache, sb + 1), None
+            def body(carry, sb_params, specs=specs, experts=experts, sb0=sb0):
+                h, cache, sb, n = carry
+                counters = dict.fromkeys(_STATE_LEAVES, 0) | {"attn": 0}
+                for spec, p, ex in zip(specs, sb_params, experts):
+                    h, cache, c = _decode_sublayer(
+                        cfg, spec, _with_experts(p, ex), h, cache, sb,
+                        counters, cache_len, live,
+                        layer=None if ex is None else sb - sb0)
+                    n = n + c
+                return (h, cache, sb + 1, n), None
 
-        (x, new_cache, _), _ = jax.lax.scan(
-            body, (x, cache, jnp.zeros((), jnp.int32)), params["blocks"])
+            (x, new_cache, _, counts), _ = jax.lax.scan(
+                body, (x, new_cache, jnp.int32(sb0), counts), stack)
 
     x = _norm_apply(cfg, params["final_norm"], x)
-    logits = (x[:, 0].astype(jnp.float32)
-              @ _readout_weight(cfg, params).astype(jnp.float32))
-    return logits, new_cache
+    return _readout(cfg, params, x[:, 0]), new_cache, counts
 
 
 def prefill(cfg: ArchConfig, params, batch, *, long_context: bool = False,
@@ -683,31 +809,29 @@ def prefill(cfg: ArchConfig, params, batch, *, long_context: bool = False,
             assert total_T <= S, \
                 f"bucket {total_T} exceeds cache window {S}: ring " \
                 "compaction would drop real (non-pad) tokens"
-        if "k" in caches:
-            caches = dict(caches)
-            caches["k"] = _ring_compact(caches["k"], S, total_T)
-            caches["v"] = _ring_compact(caches["v"], S, total_T)
+        caches = {k: _ring_compact(v, S, total_T) if k in _TOKEN_LEAVES
+                  else v for k, v in caches.items()}
     x = _norm_apply(cfg, params["final_norm"], x)
     last = x[:, -1] if lengths is None else x[jnp.arange(B), lengths - 1]
-    logits = (last.astype(jnp.float32)
-              @ _readout_weight(cfg, params).astype(jnp.float32))
-    return logits, caches, total_T
+    return _readout(cfg, params, last), caches, total_T
 
 
 def prefill_chunk(cfg: ArchConfig, params, tokens, cache, depth, *,
-                  attend_width: int, last_index=0):
+                  attend_width: int, last_index=0, lengths=None):
     """Advance a chunked prefill by one token segment.
 
     tokens: (B, C) the next C prompt tokens (pad-extended past the prompt
-    tail); cache: k/v decode cache from `init_cache` whose rows [0, depth)
-    already hold the previous segments' keys; depth: () tokens already
-    prefilled (traced — one compiled program serves every segment of a
-    bucket).  Attention runs the segment's queries against the first
+    tail); cache: attention decode cache from `init_cache` whose rows
+    [0, depth) already hold the previous segments' keys; depth: () tokens
+    already prefilled (traced — one compiled program serves every segment
+    of a bucket).  Attention runs the segment's queries against the first
     `attend_width` cache slots via `flash_attention(q_offset=depth)`, so
     a row at absolute position depth+i sees exactly the keys a one-shot
     prefill of the same padded width would show it — segment boundaries
     cannot move a logit by one ULP.  Stale keys past depth+C are causally
     masked (slot index == absolute position for a non-ring prefill).
+    lengths: optional (B,) prompt lengths; positions at or past them are
+    pads, which route nothing to an expert.
 
     Returns (logits (B, vocab) at segment row `last_index`, new cache).
     Chunked prefill is attention-only, like bucketed prefill: recurrent
@@ -715,53 +839,81 @@ def prefill_chunk(cfg: ArchConfig, params, tokens, cache, depth, *,
     from the slot==position layout this relies on.
     """
     assert (cfg.encdec is None and cfg.hybrid is None and cfg.xlstm is None
-            and cfg.vlm is None and cfg.moe is None and cfg.rope_theta > 0
+            and cfg.vlm is None and cfg.rope_theta > 0
             and cfg.sliding_window == 0), \
-        f"{cfg.name}: chunked prefill needs a pure-attention dense-FFN " \
-        "RoPE decoder (MoE capacity couples rows across the segment)"
+        f"{cfg.name}: chunked prefill needs a pure-attention RoPE decoder"
     B, C = tokens.shape
-    assert attend_width <= cache["k"].shape[3], (attend_width, cache["k"].shape)
-    specs = sublayer_specs(cfg)
+    leaf = "latent" if cfg.mla is not None else "k"
+    assert attend_width <= cache[leaf].shape[3], (attend_width,
+                                                  cache[leaf].shape)
     x = embedding_apply(params["embed"], tokens)
     depth = jnp.asarray(depth, jnp.int32)
     positions = depth + jnp.arange(C)[None, :]
+    valid = None if lengths is None else positions < lengths[:, None]
     hd = cfg.resolved_head_dim
 
-    def body(h, xs):
-        sb_params, cache_sb = xs
-        counters = {"attn": 0}
-        for spec, p in zip(specs, sb_params):
-            hn = _norm_apply(cfg, p["norm"], h)
-            i = counters["attn"]
-            q, k, v = project_qkv(p["attn"], hn, n_heads=cfg.n_heads,
-                                  n_kv_heads=cfg.n_kv_heads, head_dim=hd)
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
-            kc = jax.lax.dynamic_update_slice_in_dim(
-                cache_sb["k"][i], k.astype(cache_sb["k"].dtype), depth,
-                axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(
-                cache_sb["v"][i], v.astype(cache_sb["v"].dtype), depth,
-                axis=1)
-            cache_sb = dict(cache_sb)
-            cache_sb["k"] = cache_sb["k"].at[i].set(kc)
-            cache_sb["v"] = cache_sb["v"].at[i].set(vc)
-            counters["attn"] += 1
-            out = flash_attention(
-                q, jax.lax.slice_in_dim(kc, 0, attend_width, axis=1),
-                jax.lax.slice_in_dim(vc, 0, attend_width, axis=1),
-                causal=True, q_offset=depth)
-            h = h + dense_apply(p["attn"]["wo"],
-                                out.reshape(B, C, cfg.n_heads * hd))
-            h, _ = _apply_ffn(cfg, spec, p, h, dropless=True)
-        return h, cache_sb
+    def put(cache_sb, name, i, new):
+        """Write the segment's rows of layer i at depth; returns them
+        with the rows before it."""
+        c = jax.lax.dynamic_update_slice_in_dim(
+            cache_sb[name][i], new.astype(cache_sb[name].dtype), depth,
+            axis=1)
+        cache_sb[name] = cache_sb[name].at[i].set(c)
+        return c
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+    def body(carry, xs, specs, experts):
+        h, l = carry
+        sb_params, cache_sb = xs
+        cache_sb = dict(cache_sb)
+        for i, (spec, p, ex) in enumerate(zip(specs, sb_params, experts)):
+            p = _with_experts(p, ex)
+            hn = _norm_apply(cfg, p["norm"], h)
+            if cfg.mla is not None:
+                lc = put(cache_sb, "latent", i, mla_latent(
+                    p["attn"], hn, positions, cfg.mla,
+                    cfg.rope_theta)[:, :, None])
+                h = h + mla_chunk_attend(
+                    p["attn"], hn, lc[:, :, 0], positions, depth,
+                    n_heads=cfg.n_heads, spec=cfg.mla,
+                    rope_theta=cfg.rope_theta, attend_width=attend_width)
+            else:
+                q, k, v = project_qkv(p["attn"], hn, n_heads=cfg.n_heads,
+                                      n_kv_heads=cfg.n_kv_heads, head_dim=hd)
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k = apply_rope(k, positions, cfg.rope_theta)
+                kc = put(cache_sb, "k", i, k)
+                vc = put(cache_sb, "v", i, v)
+                out = flash_attention(
+                    q, jax.lax.slice_in_dim(kc, 0, attend_width, axis=1),
+                    jax.lax.slice_in_dim(vc, 0, attend_width, axis=1),
+                    causal=True, q_offset=depth)
+                h = h + dense_apply(p["attn"]["wo"],
+                                    out.reshape(B, C, cfg.n_heads * hd))
+            h, _ = _apply_ffn(cfg, spec, p, h, dropless=True, valid=valid,
+                              layer=None if ex is None else l)
+        return (h, l + 1), cache_sb
+
+    def run(x, specs, stack, part):
+        stack, experts = _hoist_experts(specs, stack)
+        (x, _), part = jax.lax.scan(
+            partial(body, specs=specs, experts=experts), (x, jnp.int32(0)),
+            (stack, part))
+        return x, part
+
+    stacks = _stacks(cfg, params)
+    if len(stacks) == 1:
+        x, new_cache = run(x, stacks[0][0], stacks[0][1], cache)
+    else:       # the leading layers' rows, then the superblocks'
+        outs = []
+        for specs, stack, sb0 in stacks:
+            n = jax.tree.leaves(stack)[0].shape[0]
+            part = jax.tree.map(lambda c: c[sb0:sb0 + n], cache)
+            x, part = run(x, specs, stack, part)
+            outs.append(part)
+        new_cache = jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
     x = _norm_apply(cfg, params["final_norm"], x)
     last = x[jnp.arange(B), jnp.asarray(last_index)]
-    logits = (last.astype(jnp.float32)
-              @ _readout_weight(cfg, params).astype(jnp.float32))
-    return logits, new_cache
+    return _readout(cfg, params, last), new_cache
 
 
 def _ring_compact(kv, S: int, T: int):

@@ -98,14 +98,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, kv_valid_len=None) -> jnp.ndarray:
     """Blocked attention; never materializes (T, S).
 
-    q: (B, T, Hq, D), k/v: (B, S, Hkv, D).  q_offset: absolute position of
+    q: (B, T, Hq, D), k: (B, S, Hkv, D), v: (B, S, Hkv, Dv) (latent
+    attention's values are narrower than its keys).  q_offset: absolute position of
     q[0] relative to k[0] (for chunked prefill continuation).
     kv_valid_len: optional (B,) count of valid keys per row — keys at or
     beyond it never receive probability mass (bucketed prefill padding).
-    Returns (B, T, Hq, D) in q.dtype.
+    Returns (B, T, Hq, Dv) in q.dtype.
     """
     B, T, Hq, D = q.shape
-    S, Hkv = k.shape[1], k.shape[2]
+    S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = Hq // Hkv
     scale = 1.0 / math.sqrt(D)
 
@@ -122,7 +123,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
     qg = qp.reshape(B, Tp // qb, qb, Hkv, G, D)
     kg = kp.reshape(B, Sp // kb, kb, Hkv, D)
-    vg = vp.reshape(B, Sp // kb, kb, Hkv, D)
+    vg = vp.reshape(B, Sp // kb, kb, Hkv, Dv)
     qpos_g = q_positions.reshape(Tp // qb, qb)
     kpos_g = k_positions.reshape(Sp // kb, kb)
 
@@ -130,7 +131,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         # q_chunk: (B, qb, Hkv, G, D)
         m0 = jnp.full((B, Hkv, G, qb), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, qb), jnp.float32)
-        a0 = jnp.zeros((B, qb, Hkv, G, D), jnp.float32)
+        a0 = jnp.zeros((B, qb, Hkv, G, Dv), jnp.float32)
 
         def body(carry, kv):
             m, l, a = carry
@@ -146,15 +147,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             (kg.swapaxes(0, 1), vg.swapaxes(0, 1), kpos_g))
         l = jnp.maximum(l, 1e-20)
         out = a / l.transpose(0, 3, 1, 2)[..., None]
-        return out  # (B, qb, Hkv, G, D)
+        return out  # (B, qb, Hkv, G, Dv)
 
     def q_body(_, qc):
         q_chunk, q_pos = qc
         return None, per_q_chunk(q_chunk, q_pos)
 
     _, outs = jax.lax.scan(q_body, None, (qg.swapaxes(0, 1), qpos_g))
-    # outs: (nq, B, qb, Hkv, G, D)
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Tp, Hq, D)
+    # outs: (nq, B, qb, Hkv, G, Dv)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Tp, Hq, Dv)
     return out[:, :T].astype(q.dtype)
 
 

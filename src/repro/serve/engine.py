@@ -17,7 +17,7 @@ Two execution paths share one `generate` API for the Remote-NN role:
 
 Per-request temperature (0 => greedy) and EOS ids are honoured in-graph
 on both paths; architectures the scheduler cannot serve (recurrent state,
-MoE, absolute positions) fall back to equal-length grouping.
+sliding windows, absolute positions) fall back to equal-length grouping.
 """
 from __future__ import annotations
 

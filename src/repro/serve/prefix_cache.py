@@ -44,6 +44,10 @@ Ownership model (the scheduler's side of the contract is in
     need bit-exact replay size ``hot_pages`` to their working set or set
     ``cold_bytes=0`` so cold pages drop instead of degrade.
 
+A page holds every token-axis leaf of the pool's cache by name ("k" and
+"v", or latent attention's "latent"), each sliced on its token axis, so
+the cache stays agnostic of what a row of K/V state holds.
+
 Everything here is host-side bookkeeping plus whole-page device
 slices/concats — no compiled program changes shape because of sharing,
 which is what lets the scheduler's one-program-per-bucket discipline
@@ -88,8 +92,8 @@ class _Entry:
     def __init__(self, key: bytes):
         self.key = key
         self.refs = 0        # live slots whose cache was seeded/built here
-        self.hot = None      # {"k","v"}: (n_sb, n_attn, page, n_kv, hd)
-        self.cold = None     # {"k","v"}: (payload, lo, hi) packed indices
+        self.hot = None      # {leaf: (n_sb, n_attn, page, ...)} on the device
+        self.cold = None     # {leaf: (payload, lo, hi)} packed indices
         self.stamp = 0       # LRU tick
 
 
@@ -118,8 +122,8 @@ class PrefixCache:
         self._parked: dict[object, list[bytes]] = {}
         self._tick = 0
         self._cold_used = 0
-        self._page_shape = None      # (n_sb, n_attn, page, n_kv, hd)
-        self._dtype = None
+        self._page_shape = {}        # leaf -> (n_sb, n_attn, page, ...)
+        self._dtype = {}
         self.stats = {"page_lookups": 0, "page_hits": 0, "inserts": 0,
                       "demotions": 0, "promotions": 0, "hot_drops": 0,
                       "cold_drops": 0}
@@ -166,27 +170,26 @@ class PrefixCache:
     # ----------------------------------------------------------- transfer --
 
     def fetch(self, keys: list[bytes]) -> dict:
-        """Concatenated device K/V for a resident run of pages (token
-        axis 2), promoting cold pages back to the device on the way."""
-        ks, vs = [], []
+        """Concatenated device rows of each leaf for a resident run of
+        pages (token axis 2), promoting cold pages back to the device on
+        the way."""
+        parts = []
         for key in keys:
             e = self._index[key]
             self._touch(e)
             if e.hot is None:
-                e.hot = {"k": self._decompress(e.cold["k"]),
-                         "v": self._decompress(e.cold["v"])}
+                e.hot = {n: self._decompress(n, b) for n, b in e.cold.items()}
                 self.stats["promotions"] += 1
-            ks.append(e.hot["k"])
-            vs.append(e.hot["v"])
-        if len(ks) == 1:
-            return {"k": ks[0], "v": vs[0]}
-        return {"k": jnp.concatenate(ks, axis=2),
-                "v": jnp.concatenate(vs, axis=2)}
+            parts.append(e.hot)
+        if len(parts) == 1:
+            return dict(parts[0])
+        return {n: jnp.concatenate([p[n] for p in parts], axis=2)
+                for n in parts[0]}
 
-    def pin(self, slot: int, keys: list[bytes], k_rows, v_rows) -> None:
+    def pin(self, slot: int, keys: list[bytes], rows: dict) -> None:
         """Reference every shareable page of a freshly admitted slot,
-        inserting the ones it prefilled itself.  k_rows/v_rows are the
-        slot's cache rows, (n_sb, n_attn, W, n_kv, hd) with
+        inserting the ones it prefilled itself.  rows holds the slot's
+        cache rows by leaf, each (n_sb, n_attn, W, ...) with
         W >= len(keys) * page_size; per-page slices are device copies, so
         entries never alias (or pin) a slot's cache buffer."""
         assert slot not in self._slot_keys, f"slot {slot} already pinned"
@@ -196,14 +199,14 @@ class PrefixCache:
             sl = (slice(None), slice(None), slice(p * page, (p + 1) * page))
             if e is None:
                 e = _Entry(key)
-                e.hot = {"k": jnp.copy(k_rows[sl]), "v": jnp.copy(v_rows[sl])}
-                self._register_shape(e.hot["k"])
+                e.hot = {n: jnp.copy(r[sl]) for n, r in rows.items()}
+                self._register_shape(e.hot)
                 self._index[key] = e
                 self.stats["inserts"] += 1
             elif e.hot is None:
                 # resident only as a cold blob: the slot's own rows hold
                 # the bytes it was seeded from — rehydrate for free
-                e.hot = {"k": jnp.copy(k_rows[sl]), "v": jnp.copy(v_rows[sl])}
+                e.hot = {n: jnp.copy(r[sl]) for n, r in rows.items()}
             e.refs += 1
             self._touch(e)
         self._slot_keys[slot] = list(keys)
@@ -247,10 +250,10 @@ class PrefixCache:
 
     # ----------------------------------------------------------- internal --
 
-    def _register_shape(self, leaf) -> None:
-        if self._page_shape is None:
-            self._page_shape = tuple(leaf.shape)
-            self._dtype = leaf.dtype
+    def _register_shape(self, page: dict) -> None:
+        if not self._page_shape:
+            self._page_shape = {n: tuple(a.shape) for n, a in page.items()}
+            self._dtype = {n: a.dtype for n, a in page.items()}
 
     def _touch(self, e: _Entry) -> None:
         self._tick += 1
@@ -268,16 +271,16 @@ class PrefixCache:
         idx = np.asarray(hard_indices(qp, jnp.asarray(x)))
         return pack_indices(idx, self.bits), lo, hi
 
-    def _decompress(self, blob: tuple[bytes, float, float]):
+    def _decompress(self, name: str, blob: tuple[bytes, float, float]):
         payload, lo, hi = blob
         qp = quantizer_init(1 << self.bits, lo, hi)
-        count = int(np.prod(self._page_shape))
-        idx = unpack_indices(payload, self.bits, count)
-        x = dequantize(qp, jnp.asarray(idx)).reshape(self._page_shape)
-        return x.astype(self._dtype)
+        shape = self._page_shape[name]
+        idx = unpack_indices(payload, self.bits, int(np.prod(shape)))
+        x = dequantize(qp, jnp.asarray(idx)).reshape(shape)
+        return x.astype(self._dtype[name])
 
     def _cold_nbytes(self, e: _Entry) -> int:
-        return len(e.cold["k"][0]) + len(e.cold["v"][0])
+        return sum(len(b[0]) for b in e.cold.values())
 
     def _demote(self, e: _Entry) -> None:
         """Hot -> cold (or gone, with no cold budget).  A page that
@@ -285,8 +288,7 @@ class PrefixCache:
         never re-quantizes, so a page degrades at most once."""
         if self.cold_bytes > 0:
             if e.cold is None:
-                e.cold = {"k": self._compress(e.hot["k"]),
-                          "v": self._compress(e.hot["v"])}
+                e.cold = {n: self._compress(a) for n, a in e.hot.items()}
                 self._cold_used += self._cold_nbytes(e)
             e.hot = None
             self.stats["demotions"] += 1

@@ -67,9 +67,13 @@ Correctness invariants (tested against one-request-at-a-time decode):
 
 The padded-prefill path needs per-row attention masking and per-row cache
 depths, so the scheduler serves attention-only token models (no recurrent
-state to pollute with pads, no MoE capacity for pads to compete over);
-`supports_continuous_batching` gates it and `ServeEngine` falls back to
-equal-length grouping elsewhere.
+state to pollute with pads); `supports_continuous_batching` gates it and
+`ServeEngine` falls back to equal-length grouping elsewhere.  MoE layers
+serve through the dropless expert layer (`nn.moe.moe_serve`): no capacity
+for pads to compete over, and pads and idle slots route nothing.  The
+pool's cache is whatever token-axis leaves the model's attention keeps
+(K and V, or latent attention's one latent row per token), moved by
+leaf, never by name.
 """
 from __future__ import annotations
 
@@ -127,12 +131,12 @@ class SchedulerConfig:
 
 def supports_continuous_batching(cfg: ArchConfig) -> bool:
     """Bucketed prefill + slot-pool decode needs a pure-attention decoder:
-    recurrent layers would integrate pad tokens into their state, MoE
-    capacity would let pads evict real tokens, absolute sinusoidal
-    positions are scalar-offset only, and SWA ring compaction could drop
-    real tokens behind the pads."""
+    recurrent layers would integrate pad tokens into their state, absolute
+    sinusoidal positions are scalar-offset only, and SWA ring compaction
+    could drop real tokens behind the pads.  MoE is served dropless, with
+    pads routing nothing, so no pad can displace a real token."""
     return (cfg.hybrid is None and cfg.xlstm is None and cfg.encdec is None
-            and cfg.vlm is None and cfg.moe is None and cfg.rope_theta > 0
+            and cfg.vlm is None and cfg.rope_theta > 0
             and cfg.sliding_window == 0)
 
 
@@ -144,6 +148,30 @@ def sample_tokens(logits, temps, key):
     scaled = logits / jnp.maximum(temps, 1e-6)[..., None]
     drawn = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temps <= 0.0, greedy_t, drawn)
+
+
+class _CacheDonated:
+    """The inject program, called with the whole pool as
+    ``(pool, slots, rows, logits0, ...)``, hands the pool's cache over as
+    a donated argument: the page scatter then writes the pool in place
+    instead of copying every slot's cache.  Only the cache is donated:
+    the previous chunk's done flags and token buffer, which overlap mode
+    reads after this round's injects, stay valid."""
+
+    def __init__(self, fn):
+        self.fn = fn
+
+    def __call__(self, pool, *args):
+        rest = {k: v for k, v in pool.items() if k != "cache"}
+        return self.fn(rest, pool["cache"], *args)
+
+    def _cache_size(self) -> int:      # telemetry's compile counter
+        return self.fn._cache_size()
+
+
+def _row(cache: dict, i: int) -> dict:
+    """Batch row i of every leaf of a (n_sb, n_attn, B, S, ...) cache."""
+    return {n: c[:, :, i] for n, c in cache.items()}
 
 
 class SlotError(RuntimeError):
@@ -268,7 +296,7 @@ class ContinuousScheduler:
                            .bit_length())
         self._kv_len = page * n_pages
         cache = bb.init_cache(cfg, S, self._kv_len)
-        assert set(cache) == {"k", "v"}, sorted(cache)
+        assert set(cache) <= set(bb._TOKEN_LEAVES), sorted(cache)
         self._pool = {
             "buf": jnp.zeros((S, L), jnp.int32),
             "gen": jnp.zeros((S,), jnp.int32),
@@ -280,6 +308,10 @@ class ContinuousScheduler:
             "max_new": jnp.ones((S,), jnp.int32),
             "temps": jnp.zeros((S,), jnp.float32),
         }
+        if cfg.moe is not None:
+            # the last decode chunk's expert work: held experts hit and
+            # routings landed on them, summed over layers and steps
+            self._pool["expert_counts"] = jnp.zeros((2,), jnp.int32)
         if mesh is not None:
             from repro.launch.mesh import axis_size, data_axes
             from repro.launch.partition import param_shardings, pool_shardings
@@ -309,6 +341,7 @@ class ContinuousScheduler:
         self._resume: dict[int, np.ndarray] = {}
         self._parked_tok: dict[int, object] = {}
         self._pending: Optional[dict] = None   # in-flight chunk snapshot
+        self._chunk_span = None                # args of the last chunk's span
         # streaming hook (serve.frontend): called between rounds with
         # (rid, tokens_so_far) for every live pooled request — overlap
         # rounds publish from the drained chunk's snapshot, serialized
@@ -338,16 +371,19 @@ class ContinuousScheduler:
         self._prefill_chunk = jax.jit(         # compiles once per bucket
             partial(bb.prefill_chunk, cfg),
             static_argnames=("attend_width",))
-        self._inject = jax.jit(self._inject_impl)
+        self._inject = _CacheDonated(jax.jit(self._inject_impl,
+                                             donate_argnums=(1,)))
         # the chunk updates the pool in place on every backend: no pool
         # leaf read before a dispatch may be read after it
         self._chunk = jax.jit(self._chunk_impl, donate_argnums=(1,))
 
     # ------------------------------------------------------------- device --
 
-    def _inject_impl(self, pool, slots, rows, logits0, prompt_lens, eos,
-                     max_new, temps, key):
+    def _inject_impl(self, pool, cache, slots, rows, logits0, prompt_lens,
+                     eos, max_new, temps, key):
         """Seed freshly prefilled requests into pool slots, in-graph.
+        pool: the pool without its cache; cache: the pool's cache,
+        donated and updated in place.
 
         slots: (G,) target slot per group row; dummy rows (group padding)
         carry slot == max_slots and are dropped by the scatters.  The
@@ -374,7 +410,7 @@ class ContinuousScheduler:
             return leaf.at[:, :, slots, :W].set(
                 r[:, :, :, :W].astype(leaf.dtype), mode="drop")
 
-        new["cache"] = jax.tree.map(put_pages, pool["cache"], rows)
+        new["cache"] = jax.tree.map(put_pages, cache, rows)
         new["cache_len"] = pool["cache_len"].at[slots].set(
             prompt_lens, mode="drop")
         new["eos"] = pool["eos"].at[slots].set(eos, mode="drop")
@@ -394,11 +430,12 @@ class ContinuousScheduler:
 
         def body(state):
             step, pool, key = state
-            logits, cache = bb.decode_step(self.cfg, params, pool["tok"],
-                                           pool["cache"], pool["cache_len"])
+            run = active & ~pool["done"]
+            logits, cache, counts = bb.decode_step_counted(
+                self.cfg, params, pool["tok"], pool["cache"],
+                pool["cache_len"], run)
             key, sub = jax.random.split(key)
             t = sample_tokens(logits, pool["temps"], sub)
-            run = active & ~pool["done"]
             pos = jnp.where(run, pool["gen"], L)     # OOB rows -> dropped
             buf = pool["buf"].at[jnp.arange(S), pos].set(t, mode="drop")
             gen = pool["gen"] + run.astype(jnp.int32)
@@ -411,8 +448,13 @@ class ContinuousScheduler:
             new = dict(pool, buf=buf, gen=gen, done=done, cache=cache,
                        tok=jnp.where(run[:, None], t[:, None], pool["tok"]),
                        cache_len=pool["cache_len"] + run.astype(jnp.int32))
+            if "expert_counts" in pool:
+                new["expert_counts"] = pool["expert_counts"] + counts
             return step + 1, new, key
 
+        if "expert_counts" in pool:
+            pool = dict(pool, expert_counts=jnp.zeros_like(
+                pool["expert_counts"]))
         _, pool, key = jax.lax.while_loop(
             cond, body, (jnp.zeros((), jnp.int32), pool, key))
         return pool, key
@@ -794,8 +836,7 @@ class ContinuousScheduler:
             for i, (keys, slot) in enumerate(zip(g["pkeys"], g["slots"])):
                 self.prefix.record(len(keys), 0)
                 if keys:
-                    self.prefix.pin(int(slot), keys,
-                                    rows["k"][:, :, i], rows["v"][:, :, i])
+                    self.prefix.pin(int(slot), keys, _row(rows, i))
         for rid in g["rids"]:          # after pin: parked pages stay hot
             self._unpark(rid)          # until the new pins hold them
         self._key, sub = jax.random.split(self._key)
@@ -828,15 +869,11 @@ class ContinuousScheduler:
         seeded = H * page
         kvs = [self.prefix.fetch(keys[:H]) for _, _, keys in take]
         pad = G - len(take)
-        kk = jnp.stack([kv["k"] for kv in kvs]
-                       + [jnp.zeros_like(kvs[0]["k"])] * pad, axis=2)
-        vv = jnp.stack([kv["v"] for kv in kvs]
-                       + [jnp.zeros_like(kvs[0]["v"])] * pad, axis=2)
         cache = dict(bb.init_cache(self.cfg, G, Wc))
-        cache["k"] = cache["k"].at[:, :, :, :seeded].set(
-            kk.astype(cache["k"].dtype))
-        cache["v"] = cache["v"].at[:, :, :, :seeded].set(
-            vv.astype(cache["v"].dtype))
+        for n, c in cache.items():
+            seed = jnp.stack([kv[n] for kv in kvs]
+                             + [jnp.zeros_like(kvs[0][n])] * pad, axis=2)
+            cache[n] = c.at[:, :, :, :seeded].set(seed.astype(c.dtype))
         toks = np.zeros((G, Wc), np.int32)
         lengths = np.ones((G,), np.int32)        # dummies: 1 valid token
         slots = np.full((G,), self.sched.max_slots, np.int32)
@@ -857,7 +894,7 @@ class ContinuousScheduler:
             lg, cache = self._prefill_chunk(
                 self.params, jnp.asarray(toks[:, d:d + page]), cache,
                 jnp.int32(d), attend_width=g["bucket"],
-                last_index=jnp.asarray(last))
+                last_index=jnp.asarray(last), lengths=jnp.asarray(lengths))
             ends_here = (d <= lengths - 1) & (lengths - 1 < d + page)
             logits0 = lg if logits0 is None else jnp.where(
                 jnp.asarray(ends_here)[:, None], lg, logits0)
@@ -869,8 +906,7 @@ class ContinuousScheduler:
             self.tel.counter("sched.admitted", path="prefix").inc(len(take))
         for i, ((rid, _, keys), slot) in enumerate(zip(take, g["slots"])):
             self.prefix.record(len(keys), H)
-            self.prefix.pin(int(slot), keys, cache["k"][:, :, i],
-                            cache["v"][:, :, i])
+            self.prefix.pin(int(slot), keys, _row(cache, i))
             self._unpark(rid)          # resumed rows: new pins now hold
         self._key, sub = jax.random.split(self._key)
         self._pool = self._inject(
@@ -903,11 +939,9 @@ class ContinuousScheduler:
             self.prefix.record(len(keys), depth // page)
             if depth:
                 kv = self.prefix.fetch(keys[:-(-depth // page)])
-                cache = dict(cache)
-                cache["k"] = cache["k"].at[:, :, :, :depth].set(
-                    kv["k"][:, :, None, :depth].astype(cache["k"].dtype))
-                cache["v"] = cache["v"].at[:, :, :, :depth].set(
-                    kv["v"][:, :, None, :depth].astype(cache["v"].dtype))
+                cache = {n: c.at[:, :, :, :depth].set(
+                    kv[n][:, :, None, :depth].astype(c.dtype))
+                    for n, c in cache.items()}
         self._slots.acquire(slot, rid)
         self._stamp([rid], 1)
         self._staging.append({
@@ -932,7 +966,8 @@ class ContinuousScheduler:
         last = min(max(st["T"] - 1 - d, 0), seg - 1)
         logits, st["cache"] = self._prefill_chunk(
             self.params, toks, st["cache"], jnp.int32(d),
-            attend_width=st["bucket"], last_index=jnp.int32(last))
+            attend_width=st["bucket"], last_index=jnp.int32(last),
+            lengths=jnp.asarray([st["T"]], jnp.int32))
         if self.tel.enabled:
             self.tel.note_compiles("sched.prefill_chunk", self._prefill_chunk,
                                    shape=f"bucket{st['bucket']}")
@@ -950,8 +985,7 @@ class ContinuousScheduler:
         if self.tel.enabled:
             self.tel.counter("sched.admitted", path="staged").inc()
         if self.prefix is not None and st["keys"]:
-            self.prefix.pin(st["slot"], st["keys"],
-                            st["cache"]["k"][:, :, 0], st["cache"]["v"][:, :, 0])
+            self.prefix.pin(st["slot"], st["keys"], _row(st["cache"], 0))
         self._unpark(st["rid"])        # resumed rows: new pins now hold
         self._key, sub = jax.random.split(self._key)
         self._pool = self._inject(
@@ -1058,6 +1092,7 @@ class ContinuousScheduler:
         """Evict finished slots: one host copy of buf/gen per segment."""
         with self._span("wait_done"):
             done = np.asarray(self._pool["done"])
+            self._note_expert_counts(self._pool, self._chunk_span)
         stag = self._staging_slots()
         fin = [i for i, rid in enumerate(self._slot_rid)
                if rid is not None and done[i] and i not in stag]
@@ -1092,14 +1127,30 @@ class ContinuousScheduler:
         its device->host copies; the host blocks on them only next round,
         after the following round's work has been dispatched."""
         pend = {"done": self._pool["done"], "buf": self._pool["buf"],
-                "gen": self._pool["gen"], "rids": rids, "active": active}
+                "gen": self._pool["gen"], "rids": rids, "active": active,
+                "span": self._chunk_span}
+        fetch = ["done"]
+        if "expert_counts" in self._pool and self.tel.enabled:
+            pend["expert_counts"] = self._pool["expert_counts"]
+            fetch.append("expert_counts")
         try:
             # the round's one blocking read; buf/gen stay device-side and
             # are only pulled on rounds that actually evict
-            pend["done"].copy_to_host_async()
+            for k in fetch:
+                pend[k].copy_to_host_async()
         except AttributeError:          # non-Array leaves under tracing
             pass
         self._pending = pend
+
+    def _note_expert_counts(self, pool: dict, span) -> None:
+        """With telemetry on, put a decode chunk's expert work on its
+        ``decode_chunk`` span: held experts hit (summed over layers and
+        steps) and routings that landed on them."""
+        if span is None or "expert_counts" not in pool:
+            return
+        hit, local = (int(x) for x in np.asarray(pool["expert_counts"]))
+        span["experts_hit"] = hit
+        span["routed_local"] = local
 
     def _drain_pending(self) -> list[int]:
         """Evict the finishers of the *previous* round's chunk.  Only
@@ -1113,6 +1164,7 @@ class ContinuousScheduler:
             return []
         with self._span("wait_done"):      # the host waits on the device
             done = np.asarray(p["done"])
+            self._note_expert_counts(p, p["span"])
         eligible = [i for i, rid in enumerate(self._slot_rid)
                     if rid is not None and p["active"][i]
                     and p["rids"][i] == rid]
@@ -1155,11 +1207,14 @@ class ContinuousScheduler:
 
     def _decode_chunk(self) -> Optional[np.ndarray]:
         """`_dispatch_chunk` under the round's ``decode_chunk`` span,
-        which records how many rows the chunk decodes (``rows``)."""
+        which records how many rows the chunk decodes (``rows``); the
+        chunk's expert counts join it when its done flags are read."""
+        self._chunk_span = None
         with self._span("decode_chunk") as sp:
             active = self._dispatch_chunk()
             if sp is not None and active is not None:
                 sp["rows"] = int(active.sum())
+                self._chunk_span = sp
         return active
 
     def step(self) -> list[int]:

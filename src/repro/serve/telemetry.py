@@ -399,8 +399,10 @@ class Telemetry:
             try:
                 yield args
             finally:
+                # the span keeps the body's dict: what the caller attaches
+                # to it after the span closes is recorded too
                 self.trace.add(name, t0, self.clock(), track=track, cat=cat,
-                               **args)
+                               **args).args = args
 
     # -- jit compile accounting --------------------------------------------
 

@@ -79,7 +79,7 @@ def test_all_ten_archs_registered():
 def test_full_config_matches_assignment(arch):
     spec = {
         "internvl2-1b": (24, 896, 14, 2, 4864, 151655),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonshot-v1-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
         "qwen2-1.5b": (28, 1536, 12, 2, 8960, 151936),
         "xlstm-350m": (24, 1024, 4, 4, 0, 50304),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
@@ -102,3 +102,14 @@ def test_full_config_matches_assignment(arch):
         assert (cfg.moe.n_experts, cfg.moe.top_k) == moe_spec[arch]
     else:
         assert cfg.moe is None
+    if arch == "moonshot-v1-16b-a3b":       # config.json of Moonlight-16B-A3B
+        m, e = cfg.mla, cfg.moe
+        assert (m.kv_lora_rank, m.qk_nope_head_dim, m.qk_rope_head_dim,
+                m.v_head_dim) == (512, 128, 64, 128)
+        assert (cfg.first_dense, e.expert_d_ff, e.shared_expert_ff,
+                e.scoring, e.select_bias, e.norm_topk, e.routed_scale,
+                cfg.norm_eps, cfg.rope_theta, cfg.tie_embeddings) == \
+            (1, 1408, 2816, "sigmoid", True, True, 2.446, 1e-5, 50_000.0,
+             False)
+    else:
+        assert cfg.mla is None and cfg.first_dense == 0

@@ -242,7 +242,7 @@ def test_cold_roundtrip_error_bounded_by_codebook_step():
     pc = _unit_cache(hot_pages=0, bits=8)
     keys = page_keys(np.arange(9), 8)
     k, v = _fake_rows(rng, 1)
-    pc.pin(0, keys, k, v)
+    pc.pin(0, keys, {"k": k, "v": v})
     pc.release(0)                             # unpinned -> demoted cold
     assert pc.n_hot == 0 and pc.n_cold == 1
     assert pc.stats["demotions"] == 1
@@ -261,7 +261,7 @@ def test_promoted_page_keeps_cold_blob_and_never_requantizes():
     pc = _unit_cache(hot_pages=0)
     keys = page_keys(np.arange(9), 8)
     k, v = _fake_rows(rng, 1)
-    pc.pin(0, keys, k, v)
+    pc.pin(0, keys, {"k": k, "v": v})
     pc.release(0)
     first = pc.fetch(keys)
     pc._enforce_budgets()                     # hot budget 0: demote again
@@ -279,7 +279,7 @@ def test_cold_budget_drops_lru_pages():
     pc = _unit_cache(hot_pages=0, cold_bytes=1 << 30)
     keys = page_keys(np.arange(9), 8)
     k, v = _fake_rows(rng, 1)
-    pc.pin(0, keys, k, v)
+    pc.pin(0, keys, {"k": k, "v": v})
     pc.release(0)
     one_page_cold = pc.cold_used_bytes
     assert one_page_cold > 0
@@ -288,7 +288,7 @@ def test_cold_budget_drops_lru_pages():
     toks = [np.arange(9) + 100 * i for i in range(3)]
     for i, t in enumerate(toks):
         kk, vv = _fake_rows(rng, 1)
-        pc.pin(i, page_keys(t, 8), kk, vv)
+        pc.pin(i, page_keys(t, 8), {"k": kk, "v": vv})
         pc.release(i)
     assert pc.cold_used_bytes <= 2 * one_page_cold
     assert pc.stats["cold_drops"] == 1
@@ -301,7 +301,7 @@ def test_zero_cold_budget_drops_on_demotion():
     pc = _unit_cache(hot_pages=0, cold_bytes=0)
     keys = page_keys(np.arange(9), 8)
     k, v = _fake_rows(rng, 1)
-    pc.pin(0, keys, k, v)
+    pc.pin(0, keys, {"k": k, "v": v})
     pc.release(0)
     assert pc.stats["hot_drops"] == 1
     assert pc.n_hot == pc.n_cold == 0

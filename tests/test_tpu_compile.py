@@ -114,3 +114,81 @@ def test_offload_fused_compiles_at_paper_size(one_chip, no_compile_cache):
         lambda x, c: fused_offload_op(x, c, perm=perm, k=k, interpret=False),
         x, centers)
     assert "tpu_custom_call" in text
+
+
+def _moonlight_cell_cfg():
+    """Moonlight-16B-A3B as the `moonlight-16b-a3b.chat-long` cell serves
+    it: published widths, 8 held experts of the router's 64."""
+    import dataclasses
+
+    from repro.configs import get_config
+    cfg = get_config("moonshot-v1-16b-a3b")
+    return dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                            n_held=8))
+
+
+def test_mla_decode_kernel_compiles_at_moonlight_shapes(one_chip,
+                                                        no_compile_cache):
+    """The latent pool of the cell: 32 slots, 4,096 tokens paged at 128,
+    one 576-wide bfloat16 row per token shared by 16 heads, the values
+    its first 512 entries; the kernel writes the step's row in place."""
+    from repro.kernels.decode_attention.ops import mla_decode_attention_op
+    B, S, H, Dl, L = 32, 4096, 16, 576, 27
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = sds((B, 1, H, Dl), jnp.bfloat16)
+    pool = sds((L, 1, B, S, 1, Dl), jnp.bfloat16)
+    row = sds((B, 1, Dl), jnp.bfloat16)
+    ints = sds((B,), jnp.int32)
+    lay = (sds((), jnp.int32), sds((), jnp.int32))
+    compiled = jax.jit(
+        lambda q, c, a, l, r, s: mla_decode_attention_op(
+            q, c, a, l, r, s, page_size=128, interpret=False,
+            scale=192 ** -0.5, dv=512),
+        donate_argnums=1).lower(q, pool, ints, lay, row, ints).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_moonlight_decode_chunk_compiles_for_the_chip(one_chip,
+                                                      no_compile_cache,
+                                                      monkeypatch, capsys):
+    """The cell's decode chunk (27 layers, 32 slots of 4,096 tokens)
+    compiled for the chip: the MLA kernel and the held experts' grouped
+    SwiGLU are there under their own names, nothing copies the latent
+    pool, and its temporaries stay small beside the 4.1 GB pool and
+    6.7 GB of weights.  Shapes only: nothing is allocated at this size."""
+    import re
+
+    from repro.models import backbone as bb
+    from repro.serve.scheduler import ContinuousScheduler, SchedulerConfig
+
+    cfg = _moonlight_cell_cfg()
+    params = jax.eval_shape(lambda: bb.init_params(cfg, jax.random.PRNGKey(0)))
+    sched = ContinuousScheduler(
+        cfg, jax.tree.map(lambda s: jnp.zeros((), s.dtype), params),
+        max_len=32, sched=SchedulerConfig(buckets=(32,), max_slots=32,
+                                          chunk=8))
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                             sharding=one_chip)
+    pool = dict(jax.tree.map(on_chip, sched._pool))
+    pool["cache"] = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: bb.init_cache(cfg, 32, 4096)))
+    pool["buf"] = on_chip(jax.ShapeDtypeStruct((32, 4096), jnp.int32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = sched._chunk.lower(
+        jax.tree.map(on_chip, params), pool,
+        on_chip(jnp.ones((32,), bool)), on_chip(jax.random.PRNGKey(1)),
+        on_chip(jnp.int32(8))).compile()
+    text = compiled.as_text()
+    assert "mla_decode_attention_op" in text
+    assert "held_experts_ffn_decode" in text
+    lat = pool["cache"]["latent"]
+    shape = rf"bf16\[{','.join(map(str, lat.shape))}\]"
+    ops = set(re.findall(rf"= {shape}\S* ([a-z-]+)\(", text))
+    bad = {"copy", "broadcast", "dynamic-slice", "dynamic-update-slice",
+           "fusion"} & ops
+    assert not bad, (bad, sorted(ops))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    with capsys.disabled():
+        print(f"\nmoonlight decode chunk: temporaries {temp / 2**30:.3f} GiB")
+    assert temp < 2 << 30

@@ -4,11 +4,14 @@ One decode step attends a single query token per sequence against a
 block-paged KV cache.  The grid is ragged: one step per *live* page of
 each row (the pages below its `attend_len`), rows one after another, so
 a 2,048-wide cache costs only as many steps and page fetches as its rows'
-actual contexts; a pool slot at depth 1 costs one.  The schedule (row and
-page of each step) and `attend_len` are scalar-prefetch operands, the
-grid's length is their traced total, and the online-softmax accumulators
-of a row live in VMEM scratch across its pages.  Lanes past the depth
-inside a row's last page are masked.
+actual contexts; a row with `attend_len` 0 (a pool slot that decodes
+nothing) costs none, and its output is left to the caller.  The schedule
+(row and page of each step) and `attend_len` are scalar-prefetch
+operands, the grid's length is their traced total (at least one step:
+a call whose rows are all idle runs one, which writes back unchanged the
+page it read), and the online-softmax accumulators of a row live in VMEM
+scratch across its pages.  Lanes past the depth inside a row's last page
+are masked.
 
 One grid step loads a whole page for *all* kv heads.  The kernel reads
 K/V with the token axis minor, as (Hkv, D, page_size) blocks of a
@@ -32,7 +35,8 @@ With `append`, the kernel also writes the step's new K/V token of every
 row into the pool, in place (the pool operands alias the pool outputs):
 the page holding a row's write slot is the one block of that row's pool
 outputs, filled from the fetched page with the token's lane replaced
-when the grid reaches it, and attended in that form.  The TPU's scatter
+when the grid reaches it, and attended in that form.  A row with
+`attend_len` 0 writes nothing.  The TPU's scatter
 and per-row dynamic-update-slice would each want the pool in another
 layout (a relayout copy of the whole pool per layer) or one op per row;
 here the write costs one page per row.
@@ -97,7 +101,7 @@ def _paged_decode_kernel(layer_ref, attend_ref, slot_ref, row_ref,
         slot = slot_ref[b]
         here = page == slot // page_size
         lane = jax.lax.broadcasted_iota(jnp.int32, pool_refs[0].shape[1:], 1)
-        fresh = here & (lane == slot % page_size)           # (D, page)
+        fresh = here & (lane == slot % page_size) & (attend > 0)
 
         @pl.when(here)
         def _append():
@@ -143,8 +147,8 @@ def _paged_decode_kernel(layer_ref, attend_ref, slot_ref, row_ref,
 
 
 def _live_pages(attend, page_size: int, n_pages: int):
-    """Pages a row visits: those below its depth, at least one."""
-    return jnp.clip((attend + page_size - 1) // page_size, 1, n_pages)
+    """Pages a row visits: those below its depth (none at depth 0)."""
+    return jnp.clip((attend + page_size - 1) // page_size, 0, n_pages)
 
 
 def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
@@ -162,6 +166,9 @@ def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
     (< attend_len) of each row of that layer before it is attended; the
     call then returns (out, k, v), or (out, k) with `dv`, the pool
     updated in place.
+
+    A row with attend_len 0 is not attended and its token not written:
+    its output rows are left unwritten.
     """
     B, Hkv, G, D = q.shape
     n_lead = k.ndim - 4
@@ -175,15 +182,18 @@ def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
     attend_len = jnp.asarray(attend_len, jnp.int32)
     if slot is None:
         slot = jnp.zeros((B,), jnp.int32)
+    # an idle row's slot is in page 0: where every row is idle, the one
+    # step run reads row B-1's page 0 and writes it back as it read it
+    slot = jnp.where(attend_len > 0, slot, 0)
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                page_size=page_size, n_pages=n_pages,
                                append=append, dv=dv)
 
     # the ragged schedule: row b's live pages are steps
-    # [ends[b] - live[b], ends[b]); entries past the total are never run
-    # (one compare-and-count: a searchsorted is a loop of small ops on
-    # the TPU, run for every layer of every step)
+    # [ends[b] - live[b], ends[b]), none for an idle row; entries past
+    # the total are never run (one compare-and-count: a searchsorted is a
+    # loop of small ops on the TPU, run for every layer of every step)
     live = _live_pages(attend_len, page_size, n_pages)
     ends = jnp.cumsum(live)
     steps = jnp.arange(B * n_pages, dtype=jnp.int32)
@@ -221,7 +231,7 @@ def paged_decode_attention_tpu(q, k, v, attend_len, layer, new=None,
         aliases = {6 + j: 1 + j for j in range(len(pool))}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(ends[-1],),
+        grid=(jnp.maximum(ends[-1], 1),),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[

@@ -98,7 +98,8 @@ def _pool_op(q, k_cache, v_cache, attend_len, layer, new, slot, *,
     entries of each k row.  new: optional new token of each pool operand,
     (B, Hkv, D) each, written at slot (B,) of each row before it is
     attended; the call then also returns the pool operands, updated in
-    place."""
+    place.  A row with attend_len 0 is neither attended nor written: its
+    output is zeros."""
     lead = layer is None
     pool = (k_cache,) if dv else (k_cache, v_cache)
     if lead:
@@ -118,13 +119,15 @@ def _pool_op(q, k_cache, v_cache, attend_len, layer, new, slot, *,
         qg, pool_t[0], None if dv else pool_t[1], attend,
         jnp.stack(layer).astype(jnp.int32), new, slot, page_size=page_size,
         interpret=interpret, scale=scale, dv=dv)
-    Dv = dv or D
+    out, *pool = (res,) if new is None else res
+    # the kernel never visits a row at depth 0: its output is zeros here
+    out = jnp.where(attend[:, None, None, None] > 0, out, 0)
+    out = out.reshape(B, 1, Hq, dv or D)
     if new is None:
-        return res.reshape(B, 1, Hq, Dv)
-    out, *pool = res
+        return out
     back = lambda c: jnp.moveaxis(c, -1, -3)[0] if lead \
         else jnp.moveaxis(c, -1, -3)
-    return (out.reshape(B, 1, Hq, Dv), *map(back, pool))
+    return (out, *map(back, pool))
 
 
 @partial(jax.jit, static_argnames=("page_size", "interpret"))

@@ -655,13 +655,14 @@ def _decode_sublayer(cfg: ArchConfig, spec, p, x, cache, sb, counters,
     if kind == "attn" and cfg.mla is not None:
         y, cache["latent"] = mla_decode_apply(
             p["attn"], h, cache["latent"], cache_len, n_heads=cfg.n_heads,
-            spec=cfg.mla, rope_theta=cfg.rope_theta, layer=(sb, i))
+            spec=cfg.mla, rope_theta=cfg.rope_theta, layer=(sb, i),
+            live=live)
     elif kind == "attn":
         y, cache["k"], cache["v"] = attention_decode_apply(
             p["attn"], h, cache["k"], cache["v"], cache_len,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-            layer=(sb, i))
+            layer=(sb, i), live=live)
     else:
         leaves = _STATE_LEAVES[kind]
         st = {nm: _layer_get(cache[leaf], sb, i) for leaf, nm in leaves}
@@ -699,7 +700,8 @@ def decode_step_counted(cfg: ArchConfig, params, tokens, cache, cache_len,
     a (B,) vector (slot-pool decode: every row tracks its own context
     depth; attention writes/attends per row).  live: optional (B,) bool,
     the rows that decode a request: the others route no token to an
-    expert (their logits are computed and ignored).  Returns (logits
+    expert and, on the TPU kernel's path, attend and write no page
+    (their logits are computed and ignored).  Returns (logits
     (B, vocab), new_cache, counts (2,) int32: held experts hit and
     routings that landed on them, summed over the MoE layers).
 

@@ -234,7 +234,8 @@ def cross_attention_decode(params, x, k, v, *, n_heads: int, head_dim: int):
 
 def attention_decode_apply(params, x, k_cache, v_cache, cache_len, *,
                            n_heads: int, n_kv_heads: int, head_dim: int,
-                           rope_theta: float = 10000.0, layer=None):
+                           rope_theta: float = 10000.0, layer=None,
+                           live=None):
     """One-token decode.  x: (B, 1, d_model); cache_len: () or (B,) tokens
     seen so far (per-row counts let a slot pool decode rows that sit at
     different context depths in one program).
@@ -249,6 +250,11 @@ def attention_decode_apply(params, x, k_cache, v_cache, cache_len, *,
     [*layer, row, cache_len % S] and that layer is attended, so a caller
     that carries the pool through its layer loop updates it in place
     instead of slicing and restacking it per layer.
+
+    live: optional (B,) bool, the rows that decode a request.  On the TPU
+    kernel's path the others are neither attended nor written (their
+    output is zeros, and their slots keep what they held); elsewhere it
+    is not read.
     """
     B = x.shape[0]
     S = k_cache.shape[-3]
@@ -263,8 +269,9 @@ def attention_decode_apply(params, x, k_cache, v_cache, cache_len, *,
     slot = pos % S
     page = tpu_page(S)
     if page:       # the TPU kernel writes the token and attends, in place
+        attend = attend_len if live is None else jnp.where(live, attend_len, 0)
         out, k_cache, v_cache = paged_decode_append(
-            q, k_cache, v_cache, k[:, 0], v[:, 0], slot, attend_len,
+            q, k_cache, v_cache, k[:, 0], v[:, 0], slot, attend,
             page_size=page, layer=layer)
     else:
         lead = () if layer is None else tuple(layer)

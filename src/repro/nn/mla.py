@@ -139,11 +139,12 @@ def _attend_rows(q, cache, attend_len, scale: float, dv: int):
 
 
 def mla_decode_apply(params, x, cache, cache_len, *, n_heads: int, spec,
-                     rope_theta: float, layer=None):
+                     rope_theta: float, layer=None, live=None):
     """One-token decode in the absorbed form.  x: (B, 1, d); cache: (B, S,
     1, Dl) latent rows, or with `layer` (one index per leading axis) a
     (*L, B, S, 1, Dl) pool updated in place; cache_len () or (B,) tokens
-    seen.  The new row goes in at cache_len % S.  Returns (y (B, 1, d),
+    seen.  The new row goes in at cache_len % S.  live: optional (B,)
+    bool, as in `attention_decode_apply`.  Returns (y (B, 1, d),
     cache)."""
     B = x.shape[0]
     S = cache.shape[-3]
@@ -161,8 +162,11 @@ def mla_decode_apply(params, x, cache, cache_len, *, n_heads: int, spec,
     slot = jnp.broadcast_to(pos % S, (B,)).astype(jnp.int32)
     page = tpu_page(S)
     if page:       # the TPU kernel writes the row and attends, in place
+        attend = jnp.broadcast_to(attend_len, (B,))
+        if live is not None:
+            attend = jnp.where(live, attend, 0)
         o, cache = mla_decode_attention_op(
-            q, cache, jnp.broadcast_to(attend_len, (B,)), layer,
+            q, cache, attend, layer,
             row[:, 0, None].astype(cache.dtype), slot, page_size=page,
             interpret=False, scale=scale, dv=r)
     else:

@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels.common import auto_page_size
 from repro.kernels.decode_attention.ops import (
+    mla_decode_attention_op,
     paged_decode_attention,
     paged_decode_attention_jnp,
     paged_decode_attention_op,
@@ -207,6 +208,133 @@ def test_pallas_append_writes_pool_in_place(layer):
     want = paged_decode_attention_op(q, k_want[lead], v_want[lead],
                                      attend, page_size=page, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+# ----------------------------------- idle rows (attend_len 0) visit nothing --
+
+_IDLE = {"first": [0], "middle": [2], "last": [4], "mixed": [0, 2, 4],
+         "all": [0, 1, 2, 3, 4]}
+
+
+def _idle_call(form, append, attend, slot, idx):
+    """One kernel call on a 2x2-layer pool of rows `idx` (of 5) at layer
+    (1, 0), in the GQA form (14/2 heads of 64) or the latent form (4
+    heads, rows of 80 whose first 64 are the values): a call on a subset
+    of rows sees the same rows.  Returns (out, pool operands after the
+    call, pool operands before)."""
+    S, page, layer = 512, 128, (1, 0)
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    rows = lambda key, shape: jax.random.normal(key, (5, *shape))[idx]
+    if form == "gqa":
+        Hq, Hkv, D = 14, 2, 64
+        pool = tuple(jnp.moveaxis(rows(k, (2, 2, S, Hkv, D)), 0, 2)
+                     for k in ks[:2])
+    else:
+        Hq, D = 4, 80
+        pool = (jnp.moveaxis(rows(ks[0], (2, 2, S, 1, D)), 0, 2),)
+    q = rows(ks[2], (1, Hq, D))
+    new = tuple(rows(jax.random.fold_in(ks[3], j), (pool[0].shape[-2], D))
+                for j in range(len(pool))) if append else None
+    attend, slot = jnp.asarray(attend, jnp.int32), jnp.asarray(slot, jnp.int32)
+    if form == "gqa":
+        res = paged_decode_attention_op(q, *pool, attend, layer, new, slot,
+                                        page_size=page, interpret=True)
+    else:
+        res = mla_decode_attention_op(q, *pool, attend, layer,
+                                      new and new[0], slot, page_size=page,
+                                      interpret=True, scale=0.1, dv=64)
+    out, *after = res if append else (res,)
+    return out, after, pool
+
+
+@pytest.mark.parametrize("idle", list(_IDLE))
+@pytest.mark.parametrize("append", [False, True])
+@pytest.mark.parametrize("form", ["gqa", "mla"])
+def test_pallas_idle_rows_visit_no_page(form, append, idle):
+    """Rows with attend_len 0 among live rows at several depths (one
+    page, a page and one lane, a mid page, the full width): the live
+    rows' outputs and pool pages are bit-identical to a call without the
+    idle rows, the idle rows' outputs are exactly zero, and their pool
+    rows keep every value they held, write slot included."""
+    depth = np.asarray([1, 129, 300, 512, 128])
+    slot = depth - 1
+    gone = _IDLE[idle]
+    attend = np.where(np.isin(np.arange(5), gone), 0, depth)
+    out, after, before = _idle_call(form, append, attend, slot,
+                                    np.arange(5))
+    out = np.asarray(out)
+    np.testing.assert_array_equal(out[gone], 0)
+    for a, b in zip(after, before):
+        np.testing.assert_array_equal(np.asarray(a)[:, :, gone],
+                                      np.asarray(b)[:, :, gone])
+    kept = np.setdiff1d(np.arange(5), gone)
+    if not len(kept):
+        return
+    want, want_after, _ = _idle_call(form, append, attend[kept], slot[kept],
+                                     kept)
+    np.testing.assert_array_equal(out[kept], np.asarray(want))
+    for a, w in zip(after, want_after):
+        np.testing.assert_array_equal(np.asarray(a)[:, :, kept],
+                                      np.asarray(w))
+
+
+def _kernel_path(monkeypatch):
+    """Send the decode step through the Pallas kernel in interpret mode,
+    at pages of 128, as the TPU backend would send it compiled."""
+    import repro.kernels.decode_attention.ops as ops
+    import repro.nn.attention as attn
+    import repro.nn.mla as mla
+
+    page = lambda S, page_size=None: 128 if S % 128 == 0 else 0
+    monkeypatch.setattr(attn, "tpu_page", page)
+    monkeypatch.setattr(mla, "tpu_page", page)
+    for mod, name in ((ops, "paged_decode_attention_op"),
+                      (mla, "mla_decode_attention_op")):
+        op = getattr(mod, name)
+        monkeypatch.setattr(mod, name, lambda *a, interpret, _op=op, **k:
+                            _op(*a, interpret=True, **k))
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "moonlight"])
+def test_decode_step_live_mask_on_the_kernel_path(arch, monkeypatch):
+    """`decode_step_counted` with a live mask, on the kernel path: the
+    live rows' logits and pool rows are those of the same step with
+    live=None, and the free slot (depth 0) and the finished row keep
+    every pool value they held, their next write slot included, where
+    live=None writes it."""
+    from repro.configs import get_config
+    from repro.models import backbone as bb
+
+    if arch == "moonlight":
+        import moonlight_ref as twin
+        cfg, params = twin.program_config(), twin.make_params()
+    else:
+        cfg = get_config(arch).reduced()
+        params = bb.init_params(cfg, KEY)
+    _kernel_path(monkeypatch)
+    B, S = 4, 256
+    cache = bb.init_cache(cfg, B, S)
+    cache = {n: jax.random.normal(jax.random.fold_in(KEY, i), c.shape
+                                  ).astype(c.dtype)
+             for i, (n, c) in enumerate(sorted(cache.items()))}
+    depth = jnp.asarray([5, 0, 130, 40], jnp.int32)
+    live = jnp.asarray([True, False, True, False])
+    tok = jnp.asarray([[3], [1], [4], [1]], jnp.int32)
+    step = jax.jit(lambda c, m: bb.decode_step_counted(cfg, params, tok, c,
+                                                       depth, m)[:2])
+    lg_all, c_all = step(cache, None)
+    lg, c_live = step(cache, live)
+    on, off = np.asarray([0, 2]), np.asarray([1, 3])
+    np.testing.assert_array_equal(np.asarray(lg)[on], np.asarray(lg_all)[on])
+    for name in c_live:
+        got, was = np.asarray(c_live[name]), np.asarray(cache[name])
+        np.testing.assert_array_equal(got[:, :, on],
+                                      np.asarray(c_all[name])[:, :, on])
+        np.testing.assert_array_equal(got[:, :, off], was[:, :, off])
+        for b in off:               # live=None writes the row's next slot
+            assert not np.array_equal(
+                np.asarray(c_all[name])[:, :, b, depth[b]],
+                was[:, :, b, depth[b]])
 
 
 # ---------------------------------------------- SWA ring / per-row depths --

@@ -65,6 +65,16 @@ def test_decode_kernel_compiles_at_qwen2_0_5b_shapes(one_chip,
     assert "tpu_custom_call" in text
 
 
+def _lower_chunk(sched, idle: bool, *args):
+    """The decode chunk lowered at these shapes; with `idle` its active
+    rows are a constant vector of none, so every step decodes no row
+    and the kernel sees every row at depth 0."""
+    if not idle:
+        return sched._chunk.lower(*args)
+    return jax.jit(lambda p, pool, a, key, n: sched._chunk(
+        p, pool, jnp.zeros_like(a), key, n), donate_argnums=1).lower(*args)
+
+
 def test_decode_chunk_compiles_in_place_for_the_chip(one_chip,
                                                      no_compile_cache,
                                                      monkeypatch):
@@ -74,6 +84,10 @@ def test_decode_chunk_compiles_in_place_for_the_chip(one_chip,
     row's token into the pool and reads its pages from it, in the layout
     the TPU keeps the pool in.  The backend is reported as the TPU so
     the chunk traces the kernel path."""
+    _check_qwen2_chunk(one_chip, monkeypatch, idle=False)
+
+
+def _check_qwen2_chunk(one_chip, monkeypatch, idle: bool):
     import re
 
     from repro.configs import get_config
@@ -89,8 +103,8 @@ def test_decode_chunk_compiles_in_place_for_the_chip(one_chip,
                                              sharding=one_chip)
     pool = jax.tree.map(on_chip, sched._pool)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = sched._chunk.lower(
-        jax.tree.map(on_chip, params), pool,
+    text = _lower_chunk(
+        sched, idle, jax.tree.map(on_chip, params), pool,
         on_chip(jnp.ones((4,), bool)), on_chip(jax.random.PRNGKey(1)),
         on_chip(jnp.int32(4))).compile().as_text()
     assert "tpu_custom_call" in text
@@ -157,6 +171,12 @@ def test_moonlight_decode_chunk_compiles_for_the_chip(one_chip,
     SwiGLU are there under their own names, nothing copies the latent
     pool, and its temporaries stay small beside the 4.1 GB pool and
     6.7 GB of weights.  Shapes only: nothing is allocated at this size."""
+    temp = _check_moonlight_chunk(one_chip, monkeypatch, idle=False)
+    with capsys.disabled():
+        print(f"\nmoonlight decode chunk: temporaries {temp / 2**30:.3f} GiB")
+
+
+def _check_moonlight_chunk(one_chip, monkeypatch, idle: bool) -> int:
     import re
 
     from repro.models import backbone as bb
@@ -175,8 +195,8 @@ def test_moonlight_decode_chunk_compiles_for_the_chip(one_chip,
         lambda: bb.init_cache(cfg, 32, 4096)))
     pool["buf"] = on_chip(jax.ShapeDtypeStruct((32, 4096), jnp.int32))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = sched._chunk.lower(
-        jax.tree.map(on_chip, params), pool,
+    compiled = _lower_chunk(
+        sched, idle, jax.tree.map(on_chip, params), pool,
         on_chip(jnp.ones((32,), bool)), on_chip(jax.random.PRNGKey(1)),
         on_chip(jnp.int32(8))).compile()
     text = compiled.as_text()
@@ -189,6 +209,50 @@ def test_moonlight_decode_chunk_compiles_for_the_chip(one_chip,
            "fusion"} & ops
     assert not bad, (bad, sorted(ops))
     temp = compiled.memory_analysis().temp_size_in_bytes
-    with capsys.disabled():
-        print(f"\nmoonlight decode chunk: temporaries {temp / 2**30:.3f} GiB")
     assert temp < 2 << 30
+    return temp
+
+
+@pytest.mark.parametrize("cell", ["qwen2-0.5b", "moonlight-16b-a3b"])
+def test_decode_chunk_compiles_with_every_row_idle(one_chip,
+                                                   no_compile_cache,
+                                                   monkeypatch, cell):
+    """Both cells' decode chunks still compile for the chip, in place,
+    where no row is active: each step hands the kernel every row at
+    depth 0, and the grid runs one step that writes back the page it
+    read."""
+    check = _check_qwen2_chunk if cell == "qwen2-0.5b" \
+        else _check_moonlight_chunk
+    check(one_chip, monkeypatch, idle=True)
+
+
+@pytest.mark.parametrize("form", ["gqa", "mla"])
+def test_decode_kernel_compiles_with_every_row_idle(one_chip,
+                                                    no_compile_cache, form):
+    """The kernel, writing the step's token, at the qwen2-0.5b pool
+    (8 slots of 1,024, 14/2 heads of 64) and the Moonlight latent pool
+    (32 slots of 4,096, rows of 576) with every row at depth 0, a
+    constant: the grid's length is then a constant too.  (Without the
+    write, an all-idle call is constant zeros and the compiler drops the
+    kernel.)"""
+    from repro.kernels.decode_attention.ops import mla_decode_attention_op
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    if form == "gqa":
+        B, S, Hq, Hkv, D = 8, 1024, 14, 2, 64
+        q = sds((B, 1, Hq, D), jnp.float32)
+        kv = sds((B, S, Hkv, D), jnp.float32)
+        tok = sds((B, Hkv, D), jnp.float32)
+        fn = lambda q, k, v, kn, vn, s: paged_decode_attention_op(
+            q, k, v, jnp.zeros_like(s), None, (kn, vn), s, page_size=128,
+            interpret=False)
+        args = (q, kv, kv, tok, tok, sds((B,), jnp.int32))
+    else:
+        B, S, H, Dl, L = 32, 4096, 16, 576, 27
+        fn = lambda q, c, l, r, s: mla_decode_attention_op(
+            q, c, jnp.zeros_like(s), l, r, s, page_size=128,
+            interpret=False, scale=192 ** -0.5, dv=512)
+        args = (sds((B, 1, H, Dl), jnp.bfloat16),
+                sds((L, 1, B, S, 1, Dl), jnp.bfloat16),
+                (sds((), jnp.int32), sds((), jnp.int32)),
+                sds((B, 1, Dl), jnp.bfloat16), sds((B,), jnp.int32))
+    assert "tpu_custom_call" in _compiled_text(fn, *args)

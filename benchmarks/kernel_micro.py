@@ -3,8 +3,6 @@
 timed paths are the jit'd jnp implementations the dry-run lowers)."""
 from __future__ import annotations
 
-import sys
-
 import jax
 import jax.numpy as jnp
 
@@ -79,40 +77,6 @@ def offload_hot_path_rows() -> list[tuple]:
     return rows
 
 
-def decode_attention_rows() -> list[tuple]:
-    """Serving decode attention: the seed dense einsum over the full
-    cache width vs the paged path that visits only the KV pages below
-    the pool's deepest live row (slot pools mostly sit far below
-    capacity, here depths <= S/4)."""
-    from functools import partial
-
-    from repro.kernels.decode_attention.ops import paged_decode_attention_jnp
-    from repro.kernels.decode_attention.ref import decode_attention_ref
-
-    B, S, Hq, Hkv, D = 8, 1024, 8, 2, 64
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, 1, Hq, D))
-    k = jax.random.normal(ks[1], (B, S, Hkv, D))
-    v = jax.random.normal(ks[2], (B, S, Hkv, D))
-    attend = jnp.asarray(np.linspace(32, 256, B).astype(np.int32))
-
-    dense = jax.jit(decode_attention_ref)
-    paged = jax.jit(partial(paged_decode_attention_jnp, page_size=128))
-    us_seed = timed_us(dense, q, k, v, attend, iters=50)
-    us_paged = timed_us(paged, q, k, v, attend, iters=50)
-    # NOTE: derived strings stay measurement-free — the --compare gate
-    # only judges rows whose name AND derived match the baseline, so a
-    # re-measured ratio in derived would exempt the row from gating
-    print(f"decode_attention paged speedup: {us_seed / us_paged:.2f}x",
-          file=sys.stderr)
-    return [
-        ("kernel.decode_attention_seed.us", us_seed,
-         f"B{B} S{S} Hq{Hq} dense full-width"),
-        ("kernel.decode_attention_paged.us", us_paged,
-         f"B{B} S{S} Hq{Hq} depths<=256 page128"),
-    ]
-
-
 def kernel_micro_rows() -> list[tuple]:
     rows = []
     B, T, H, D = 1, 512, 4, 64
@@ -138,6 +102,5 @@ def kernel_micro_rows() -> list[tuple]:
     p = mlstm_init(KEY, 128, 4)
     f = jax.jit(lambda p, x: mlstm_apply(p, x, n_heads=4, chunk=64))
     rows.append(("kernel.mlstm_chunked.us", timed_us(f, p, x), ""))
-    rows.extend(decode_attention_rows())
     rows.extend(offload_hot_path_rows())
     return rows

@@ -1,29 +1,23 @@
-"""Jit'd public wrappers for paged decode attention.
+"""Decode attention: the one entry the model's layers call.
 
-``paged_decode_attention_op`` drives the Pallas kernel; callers say
-whether it runs compiled (TPU) or in interpret mode (the CPU correctness
-harness), so the interpreter can never slip onto the chip by default.
-``paged_decode_attention_jnp`` is the blocked fallback: a `lax.switch`
-over page-aligned prefix widths —
-the branch for width W runs the *dense* reference math over k/v[:, :W],
-where W is the smallest page multiple covering max(attend_len).  Because
-masked tail keys feed exact zeros into every reduction, each branch is
-bit-identical to the full-width dense path while doing only W/S of its
-work, so swapping it under `nn.attention.decode_attention` cannot change
-a single greedy token.  ``paged_decode_attention`` picks per backend and
-falls back to the dense reference when the cache width doesn't page.
+``decode_attend`` alone decides how a decode step attends its cache.
+Where the cache width pages for the compiled kernel (`_tpu_page`: on the
+TPU) it runs the Pallas kernel, per shard of the ambient mesh if there is
+one (`_kernel`); the kernel writes the step's token into the pool in
+place and walks a ragged grid of the live pages only.  Everywhere else it
+runs the dense reference (`ref.decode_attention_ref`), after writing the
+token with one scatter.  Both paths give the same meaning to a row at
+depth 0, which is what `live` makes of the rows that decode nothing: it
+is neither attended nor written, and its output is zeros.  The kernel agrees with the
+reference within float32 tolerance (its online softmax reorders the
+accumulation); the tests hold it to that, not to bit-identity.
 
-``mla_decode_attention_op`` runs latent attention's absorbed decode on
-the same kernel: one cached row per token holds the keys, and its first
-`dv` entries the values.
-
-``paged_decode_append`` is the compiled kernel's write-and-attend step
-over a layer-stacked pool (*L, B, S, Hkv, D) with a ``layer`` index
-tuple, one index per leading axis: the form a decode step that updates
-its KV cache in place hands over.  The kernel writes the step's token
-and fetches that layer's pages straight from the pool; off the TPU the
-caller writes the token and slices the layer out (`layer_of`), then runs
-exactly the math above on a single cache.
+``paged_decode_attention_op`` (the GQA pools, K and V) and
+``mla_decode_attention_op`` (latent attention: one cached row per token
+holds the keys, and its first `dv` entries the values) are the kernel's
+jitted ops under the names a device trace shows.  Callers say whether
+they run compiled (TPU) or in interpret mode (the CPU correctness
+harness), so the interpreter never slips onto the chip by default.
 """
 from __future__ import annotations
 
@@ -39,7 +33,52 @@ from repro.kernels.decode_attention.kernel import paged_decode_attention_tpu
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
 
-def layer_of(pool, layer):
+def decode_attend(q, pool, attend_len, *, new=None, slot=None, layer=None,
+                  live=None, scale=None, dv: int = 0):
+    """One decode step's attention over a cache or a layer-stacked pool.
+
+    q: (B, 1, Hq, D).  pool: the cache operands, (k, v) each
+    (B, S, Hkv, D), or with `dv` (latent attention) (rows,) whose first dv
+    entries are the values; with `layer` (one index per leading axis)
+    each is a (*L, B, S, Hkv, D) pool of which that layer is attended.
+    attend_len: () or (B,) valid slots per row.  new: optional step's
+    token of each pool operand, one slot of its rows (B, 1, Hkv, D),
+    written at slot (() or (B,)) of each row before it is attended.
+    live: optional (B,) bool, the rows that decode a request.  A row
+    that is not live, or at attend_len 0, is neither attended nor
+    written: its output is zeros.  scale defaults to 1/sqrt(D).
+
+    Returns out (B, 1, Hq, dv or D), and with `new` (out, pool), the
+    pool operands updated."""
+    if live is not None:
+        attend_len = jnp.where(live, attend_len, 0)
+    page = _tpu_page(pool[0].shape[-3])
+    if page:
+        return _kernel(q, pool, attend_len, layer,
+                       None if new is None else tuple(n[:, 0] for n in new),
+                       slot, page, scale=scale, dv=dv)
+    if new is not None:
+        pool = tuple(_write(c, n, slot, attend_len, layer)
+                     for c, n in zip(pool, new))
+    rows = pool if layer is None else tuple(_layer_of(c, layer)
+                                            for c in pool)
+    out = decode_attention_ref(q, rows[0], None if dv else rows[1],
+                               attend_len, scale=scale, dv=dv)
+    return out if new is None else (out, pool)
+
+
+def _tpu_page(S: int) -> int:
+    """Page width of the compiled kernel over a cache of width S, 0 where
+    it does not run: off the TPU, or where the width does not page.  The
+    token axis is the kernel's lane axis, so a page is a multiple of 128
+    lanes or the whole width."""
+    page = auto_page_size(S)
+    if not page or jax.default_backend() != "tpu":
+        return 0
+    return page if page % 128 == 0 else S
+
+
+def _layer_of(pool, layer):
     """Layer `layer` (one index per leading axis) of a stacked pool."""
     n = len(layer)
     start = tuple(layer) + (0,) * (pool.ndim - n)
@@ -47,46 +86,16 @@ def layer_of(pool, layer):
                                  ).reshape(pool.shape[n:])
 
 
-def _width_ladder(S: int, page_size: int) -> tuple[int, ...]:
-    """Page-multiple prefix widths that *divide* S.
-
-    Only divisor widths are offered: XLA's CPU dot panelizes the
-    contraction axis, and a prefix contraction over W is bit-identical to
-    the full-width one (whose tail summands are exact zeros) only when W
-    lands on a panel boundary — empirically, when W divides S.  Non-
-    divisor widths (e.g. 768 of 1024) reassociate the accumulation and
-    drift by ~1 ULP, which the greedy bit-compat contract forbids.
-    `test_paged_jnp_bit_identical_to_dense` guards this assumption.
-    """
-    return tuple(W for W in range(page_size, S + 1, page_size)
-                 if S % W == 0)
-
-
-@partial(jax.jit, static_argnames=("page_size",))
-def paged_decode_attention_jnp(q, k_cache, v_cache, attend_len, *,
-                               page_size: int = 128):
-    """Blocked-jnp paged decode attention, bit-identical to the dense ref.
-
-    q: (B, 1, Hq, D); k/v_cache: (B, S, Hkv, D) with S a page multiple;
-    attend_len: () or (B,) valid-slot counts.  Only the pages below the
-    smallest ladder width covering max(attend_len) are touched.
-    """
-    S = k_cache.shape[1]
-    assert S % page_size == 0 and S // page_size >= 1, (S, page_size)
-    widths = _width_ladder(S, page_size)
-    attend_len = jnp.asarray(attend_len)
-    branch = jnp.clip(
-        jnp.searchsorted(jnp.asarray(widths), jnp.max(attend_len),
-                         side="left"),
-        0, len(widths) - 1)
-
-    def prefix(W, q, k, v, attend):
-        return decode_attention_ref(
-            q, jax.lax.slice_in_dim(k, 0, W, axis=1),
-            jax.lax.slice_in_dim(v, 0, W, axis=1), attend)
-
-    return jax.lax.switch(branch, [partial(prefix, W) for W in widths],
-                          q, k_cache, v_cache, attend_len)
+def _write(cache, new, slot, attend_len, layer):
+    """The reference step's token write, one scatter: new (B, 1, Hkv, D)
+    into slot of each row (of layer `layer`); a row at attend_len 0
+    keeps what it held."""
+    lead = () if layer is None else tuple(layer)
+    B = new.shape[0]
+    slot = jnp.where(attend_len > 0, slot, cache.shape[-3])  # -> dropped
+    slot = jnp.broadcast_to(slot, (B,))
+    return cache.at[(*lead, jnp.arange(B), slot)].set(
+        new[:, 0].astype(cache.dtype), mode="drop")
 
 
 def _pool_op(q, k_cache, v_cache, attend_len, layer, new, slot, *,
@@ -162,88 +171,63 @@ def mla_decode_attention_op(q, cache, attend_len, layer=None, new=None,
                     dv=dv)
 
 
-def tpu_page(S: int, page_size: int | None = None) -> int:
-    """Page width of the compiled kernel over a cache of width S, 0 where
-    it does not run: off the TPU, or where the width does not page.  The
-    token axis is the kernel's lane axis, so a page is a multiple of 128
-    lanes or the whole width."""
-    page = page_size or auto_page_size(S)
-    if not page or jax.default_backend() != "tpu":
-        return 0
-    return page if page % 128 == 0 else S
-
-
-def paged_decode_attention(q, k_cache, v_cache, attend_len, *,
-                           page_size: int | None = None):
-    """Backend dispatch for the serving decode hot path.
-
-    Caches whose width pages cleanly run the paged path (compiled Pallas
-    on TPU, bit-identical blocked jnp elsewhere); anything else takes the
-    dense reference, so callers never pay page-padding for tiny caches.
-    """
-    tpu = tpu_page(k_cache.shape[1], page_size)
-    if tpu:
-        return _kernel(q, k_cache, v_cache, attend_len, None, None, tpu)
-    page = page_size or auto_page_size(k_cache.shape[1])
-    if not page or jax.default_backend() != "cpu":
-        # the divisor-ladder bit-identity is an XLA *CPU* dot-panelization
-        # property; an unverified backend (GPU) gets the dense reference
-        # rather than a maybe-ULP-off switch branch
-        return decode_attention_ref(q, k_cache, v_cache, attend_len)
-    return paged_decode_attention_jnp(q, k_cache, v_cache, attend_len,
-                                      page_size=page)
-
-
-def paged_decode_append(q, k_cache, v_cache, k_new, v_new, slot,
-                        attend_len, *, page_size: int, layer=None):
-    """The compiled kernel's write-and-attend step, at the page size
-    `tpu_page` gives: the step's K/V token k/v_new (B, Hkv, D) goes into
-    slot (B,) of each row (of layer `layer` of a pool), in place, and
-    that layer is attended over attend_len slots.  Returns
-    (out, k_cache, v_cache)."""
-    slot = jnp.broadcast_to(jnp.asarray(slot, jnp.int32), q.shape[:1])
-    return _kernel(q, k_cache, v_cache, attend_len, layer,
-                   ((k_new, v_new), slot), page_size)
-
-
-def _kernel(q, k_cache, v_cache, attend_len, layer, append, page_size):
-    """The compiled kernel, per shard of the ambient mesh if there is one
-    (the compiler cannot partition a Mosaic kernel itself).  Rows split
-    over the data axes and kv heads over "model", the layout
+def _kernel(q, pool, attend_len, layer, new, slot, page_size, *, scale,
+            dv: int):
+    """The kernel op of the pool's form, per shard of the ambient mesh if
+    there is one (the compiler cannot partition a Mosaic kernel itself).
+    Rows split over the data axes and kv heads over "model", the layout
     `launch.partition` gives the slot pool and the attention weights; an
     axis that does not divide stays whole, and so do the pool's layer
     axes.  Query heads split with their kv heads: a contiguous block of
     Hq/m query heads uses exactly the block of Hkv/m kv heads on the same
-    device."""
-    op = partial(paged_decode_attention_op, page_size=page_size,
-                 interpret=False)
-    new, slot = append or (None, None)
+    device.  Compiled on the TPU; elsewhere, where a test steers the
+    choice onto the kernel, interpreted."""
+    interpret = jax.default_backend() != "tpu"
+    if dv:
+        op = lambda q, pool, a, lay, new, slot: mla_decode_attention_op(
+            q, *pool, a, lay, None if new is None else new[0], slot,
+            page_size=page_size, interpret=interpret, scale=scale, dv=dv)
+    else:
+        op = lambda q, pool, a, lay, new, slot: paged_decode_attention_op(
+            q, *pool, a, lay, new, slot, page_size=page_size,
+            interpret=interpret)
+    B = q.shape[0]
+    if new is not None:
+        slot = jnp.broadcast_to(jnp.asarray(slot, jnp.int32), (B,))
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
-        return op(q, k_cache, v_cache, attend_len, layer, new, slot)
-    B, Hkv = k_cache.shape[-4], k_cache.shape[-2]
-    n_lead = k_cache.ndim - 4
+        res = op(q, pool, attend_len, layer, new, slot)
+    else:
+        res = _per_shard(op, mesh, q, pool, attend_len, layer, new, slot)
+    return res if new is None else (res[0], tuple(res[1:]))
+
+
+def _per_shard(op, mesh, q, pool, attend_len, layer, new, slot):
+    """`op` run per shard of `mesh`, in the layout `_kernel` describes."""
+    B, Hkv = q.shape[0], pool[0].shape[-2]
+    n_lead = pool[0].ndim - 4
     data = tuple(a for a in mesh.axis_names if a != "model")
     dsize = math.prod(mesh.shape[a] for a in data)
     msize = mesh.shape.get("model", 1)
     rows = data if data and B % dsize == 0 else None
     heads = "model" if msize > 1 and Hkv % msize == 0 else None
     spec = P(rows, None, heads, None)
-    pool = P(*(None,) * n_lead, rows, None, heads, None)
+    pool_spec = P(*(None,) * n_lead, rows, None, heads, None)
     attend = jnp.broadcast_to(jnp.asarray(attend_len, jnp.int32), (B,))
     lay = jnp.stack(layer or (0,)).astype(jnp.int32)
-    args = [q, k_cache, v_cache, attend, lay]
-    in_specs = [spec, pool, pool, P(rows), P()]
+    n = len(pool)
+    args = [q, *pool, attend, lay]
+    in_specs = [spec, *(pool_spec,) * n, P(rows), P()]
     out_specs = spec
     if new is not None:
-        tok = P(rows, heads, None)
         args += [*new, slot]
-        in_specs += [tok, tok, P(rows)]
-        out_specs = (spec, pool, pool)
+        in_specs += [*(P(rows, heads, None),) * n, P(rows)]
+        out_specs = (spec, *(pool_spec,) * n)
 
-    def per_shard(q, k, v, a, lay, *append):
-        new = (append[:2], append[2]) if append else ()
-        return op(q, k, v, a, tuple(lay) if n_lead else None, *new)
+    def per_shard(q, *rest):
+        pool, (a, lay), append = rest[:n], rest[n:n + 2], rest[n + 2:]
+        new, slot = (append[:n], append[n]) if append else (None, None)
+        return op(q, pool, a, tuple(lay) if n_lead else None, new, slot)
 
     return jax.shard_map(per_shard, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=out_specs, check_vma=False)(*args)

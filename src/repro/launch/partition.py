@@ -147,7 +147,7 @@ def pool_shardings(pool, mesh, *, model_axes=("model",)):
     """Shardings for a decode slot pool (`serve.scheduler`): every leaf
     leads with the slot axis, which shards over the data axes, so a slot
     lives wholly on one data shard and host-side evict/inject touches
-    exactly that shard's rows.  Cache K/V leaves
+    exactly that shard's rows.  Cache K/V and latent leaves
     (n_sb, n_layer, S, W, Hkv, hd) carry the slot axis third and
     additionally go model-parallel over kv-heads when divisible, matching
     the tensor-parallel attention params.  Axes that don't divide stay
@@ -161,7 +161,7 @@ def pool_shardings(pool, mesh, *, model_axes=("model",)):
 
     def rule(path, leaf):
         shape = leaf.shape
-        if re.search(r"(^|/)(k|v)$", path):
+        if re.search(r"(^|/)(k|v|latent)$", path):
             assert len(shape) == 6, (path, shape)
             spec: list = [None] * 6
             if dval is not None and shape[2] % dsize == 0:

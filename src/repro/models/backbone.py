@@ -700,10 +700,10 @@ def decode_step_counted(cfg: ArchConfig, params, tokens, cache, cache_len,
     a (B,) vector (slot-pool decode: every row tracks its own context
     depth; attention writes/attends per row).  live: optional (B,) bool,
     the rows that decode a request: the others route no token to an
-    expert and, on the TPU kernel's path, attend and write no page
-    (their logits are computed and ignored).  Returns (logits
-    (B, vocab), new_cache, counts (2,) int32: held experts hit and
-    routings that landed on them, summed over the MoE layers).
+    expert and attend and write nothing (their logits are computed and
+    ignored).  Returns (logits (B, vocab), new_cache, counts (2,) int32:
+    held experts hit and routings that landed on them, summed over the
+    MoE layers).
 
     The layer loop carries the whole cache, indexed by superblock: each
     attention layer writes its new K/V (or latent row) with one update at
@@ -829,9 +829,11 @@ def prefill_chunk(cfg: ArchConfig, params, tokens, cache, depth, *,
     of a bucket).  Attention runs the segment's queries against the first
     `attend_width` cache slots via `flash_attention(q_offset=depth)`, so
     a row at absolute position depth+i sees exactly the keys a one-shot
-    prefill of the same padded width would show it — segment boundaries
-    cannot move a logit by one ULP.  Stale keys past depth+C are causally
-    masked (slot index == absolute position for a non-ring prefill).
+    prefill of the same padded width would show it; the segmented sums
+    run in another order, so logits and K/V agree with one-shot prefill
+    within float32 reassociation (tested against a written tolerance).
+    Stale keys past depth+C are causally masked (slot index == absolute
+    position for a non-ring prefill).
     lengths: optional (B,) prompt lengths; positions at or past them are
     pads, which route nothing to an expert.
 

@@ -18,12 +18,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode_attention.ops import (
-    layer_of,
-    paged_decode_append,
-    paged_decode_attention,
-    tpu_page,
-)
+from repro.kernels.decode_attention.ops import decode_attend
 from repro.nn import init as initializers
 from repro.nn.linear import dense_apply, dense_init
 from repro.nn.module import split_keys
@@ -168,12 +163,11 @@ def decode_attention(q, k_cache, v_cache, attend_len) -> jnp.ndarray:
     S once full; slot order does not matter because keys carry absolute
     RoPE phases.  Returns (B, 1, Hq, D).
 
-    Caches whose width splits into KV pages route through the paged
-    subsystem (`repro.kernels.decode_attention`): only the pages below
-    max(attend_len) are visited, and the fallback path is bit-identical
-    to the dense einsum this function used to inline.
+    `kernels.decode_attention.ops.decode_attend` chooses the path: the
+    Pallas kernel where the cache pages on the TPU, the dense reference
+    elsewhere; they agree within float tolerance.
     """
-    return paged_decode_attention(q, k_cache, v_cache, attend_len)
+    return decode_attend(q, (k_cache, v_cache), attend_len)
 
 
 # ----------------------------------------------------------- full layer ----
@@ -251,10 +245,9 @@ def attention_decode_apply(params, x, k_cache, v_cache, cache_len, *,
     that carries the pool through its layer loop updates it in place
     instead of slicing and restacking it per layer.
 
-    live: optional (B,) bool, the rows that decode a request.  On the TPU
-    kernel's path the others are neither attended nor written (their
-    output is zeros, and their slots keep what they held); elsewhere it
-    is not read.
+    live: optional (B,) bool, the rows that decode a request: the others
+    are neither attended nor written (their output is zeros, and their
+    slots keep what they held).
     """
     B = x.shape[0]
     S = k_cache.shape[-3]
@@ -265,33 +258,9 @@ def attention_decode_apply(params, x, k_cache, v_cache, cache_len, *,
     if rope_theta > 0:
         q = apply_rope(q, pos_b, rope_theta)
         k = apply_rope(k, pos_b, rope_theta)
-    attend_len = jnp.minimum(pos + 1, S)
-    slot = pos % S
-    page = tpu_page(S)
-    if page:       # the TPU kernel writes the token and attends, in place
-        attend = attend_len if live is None else jnp.where(live, attend_len, 0)
-        out, k_cache, v_cache = paged_decode_append(
-            q, k_cache, v_cache, k[:, 0], v[:, 0], slot, attend,
-            page_size=page, layer=layer)
-    else:
-        lead = () if layer is None else tuple(layer)
-        if pos.ndim == 0:
-            def put(cache, new):
-                new = new.astype(cache.dtype).reshape(
-                    (1,) * len(lead) + new.shape)
-                return jax.lax.dynamic_update_slice(
-                    cache, new, (*lead, 0, slot, 0, 0))
-        else:
-            idx = (*lead, jnp.arange(B), slot)    # per-row write slot
-
-            def put(cache, new):
-                return cache.at[idx].set(new[:, 0].astype(cache.dtype))
-        k_cache, v_cache = put(k_cache, k), put(v_cache, v)
-        if layer is None:
-            out = decode_attention(q, k_cache, v_cache, attend_len)
-        else:
-            out = decode_attention(q, layer_of(k_cache, layer),
-                                   layer_of(v_cache, layer), attend_len)
+    out, (k_cache, v_cache) = decode_attend(
+        q, (k_cache, v_cache), jnp.minimum(pos + 1, S),
+        new=(k, v), slot=pos % S, layer=layer, live=live)
     out = out.reshape(B, 1, n_heads * head_dim)
     return dense_apply(params["wo"], out), k_cache, v_cache
 

@@ -25,12 +25,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode_attention.ops import (
-    layer_of,
-    mla_decode_attention_op,
-    tpu_page,
-)
-from repro.nn.attention import NEG_INF, flash_attention
+from repro.kernels.decode_attention.ops import decode_attend
+from repro.nn.attention import flash_attention
 from repro.nn.linear import dense_apply, dense_init
 from repro.nn.module import split_keys
 from repro.nn.norm import rmsnorm_apply, rmsnorm_init
@@ -123,21 +119,6 @@ def mla_chunk_attend(params, x, latent_cache, positions, depth, *,
     return _out(params, o, n_heads, spec)
 
 
-def _attend_rows(q, cache, attend_len, scale: float, dv: int):
-    """Absorbed attention of q (B, 1, H, Dl) over cache rows (B, S, 1, Dl),
-    in float32: the rows are the keys, their first dv entries the values.
-    Returns (B, 1, H, dv)."""
-    S = cache.shape[1]
-    rows = cache[:, :, 0].astype(jnp.float32)                   # (B, S, Dl)
-    s = jnp.einsum("bhd,bsd->bhs", q[:, 0].astype(jnp.float32), rows) * scale
-    valid = jnp.arange(S)[None, :] < jnp.broadcast_to(
-        jnp.asarray(attend_len), (q.shape[0],))[:, None]
-    s = jnp.where(valid[:, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhs,bsr->bhr", p, rows[..., :dv])
-    return o[:, None].astype(q.dtype)
-
-
 def mla_decode_apply(params, x, cache, cache_len, *, n_heads: int, spec,
                      rope_theta: float, layer=None, live=None):
     """One-token decode in the absorbed form.  x: (B, 1, d); cache: (B, S,
@@ -158,23 +139,9 @@ def mla_decode_apply(params, x, cache, cache_len, *, n_heads: int, spec,
     q = jnp.concatenate([q_lat, q_pe[:, 0].astype(q_lat.dtype)],
                         axis=-1)[:, None]                       # (B,1,H,Dl)
     scale = 1.0 / math.sqrt(spec.qk_head_dim)
-    attend_len = jnp.minimum(pos + 1, S)
-    slot = jnp.broadcast_to(pos % S, (B,)).astype(jnp.int32)
-    page = tpu_page(S)
-    if page:       # the TPU kernel writes the row and attends, in place
-        attend = jnp.broadcast_to(attend_len, (B,))
-        if live is not None:
-            attend = jnp.where(live, attend, 0)
-        o, cache = mla_decode_attention_op(
-            q, cache, attend, layer,
-            row[:, 0, None].astype(cache.dtype), slot, page_size=page,
-            interpret=False, scale=scale, dv=r)
-    else:
-        lead = () if layer is None else tuple(layer)
-        cache = cache.at[(*lead, jnp.arange(B), slot)].set(
-            row[:, 0, None].astype(cache.dtype))
-        rows = cache if layer is None else layer_of(cache, layer)
-        o = _attend_rows(q, rows, attend_len, scale, r)
+    o, (cache,) = decode_attend(
+        q, (cache,), jnp.minimum(pos + 1, S), new=(row[:, :, None],),
+        slot=pos % S, layer=layer, live=live, scale=scale, dv=r)
     o = jnp.einsum("bhr,rhv->bhv", o[:, 0].astype(q_lat.dtype),
                    w_kvb[..., nope:])
     return _out(params, o[:, None], n_heads, spec), cache

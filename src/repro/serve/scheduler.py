@@ -26,8 +26,8 @@ runs every slot whatever its occupancy.
 
 Long prompts admit through *chunked prefill*: a prompt whose bucket
 exceeds `prefill_segment` is staged one segment at a time between decode
-chunks (`backbone.prefill_chunk`, bit-identical to one-shot prefill), so
-a long admission can never stall the decode pool for more than one
+chunks (`backbone.prefill_chunk`, which agrees with one-shot prefill
+within float32 reassociation), so a long admission can never stall the decode pool for more than one
 segment of prefill work.  One admission stages at a time; short groups
 keep admitting around it, and the staged slot joins the pool when its
 last segment lands.
@@ -285,12 +285,11 @@ class ContinuousScheduler:
         self._key = jax.random.PRNGKey(seed)
         S = self.sched.max_slots
         L = max_len
-        # the pool's KV width is a power-of-two page count so decode
-        # attention always has a paged cache with a *dense* divisor
-        # ladder to early-exit over (a raw max_len like 152 would round
-        # to 160, whose only ladder widths are 32 and 160 — one deep row
-        # would force full-width attention); <2x memory, and requests
-        # still budget against max_len
+        # the pool's KV width is a power-of-two page count so the decode
+        # kernel pages it at 128 lanes and visits only the live pages (a
+        # raw max_len like 152 would round to 160, which pages only as
+        # one whole-width page: every row would attend the full width);
+        # <2x memory, and requests still budget against max_len
         page = self.sched.page_size
         n_pages = 1 << max(1, (round_up(max_len, page) // page - 1)
                            .bit_length())
@@ -442,9 +441,7 @@ class ContinuousScheduler:
             done = pool["done"] | (run & ((t == pool["eos"])
                                           | (gen >= pool["max_new"])))
             # only running rows advance their depth: done/free slots keep
-            # cache_len frozen (and evict resets it), so the paged decode
-            # kernel's max-depth branch tracks live occupancy, not the
-            # deepest slot the pool has ever held
+            # cache_len frozen (and evict resets it)
             new = dict(pool, buf=buf, gen=gen, done=done, cache=cache,
                        tok=jnp.where(run[:, None], t[:, None], pool["tok"]),
                        cache_len=pool["cache_len"] + run.astype(jnp.int32))
@@ -626,8 +623,9 @@ class ContinuousScheduler:
         prompt (chunked prefill and prefix-page seeding apply as for any
         admission), the next token samples from the resumed prefill's
         logits, and streams/Completion carry the full token sequence.
-        Greedy rows are bit-identical to an uninterrupted run: argmax
-        sampling is RNG-free and prefill is bit-identical however the
+        Greedy rows continue as an uninterrupted run would: argmax
+        sampling is RNG-free, and the resumed prefill agrees with the
+        uninterrupted run's within float32 reassociation, however the
         prompt is segmented.  Returns the new rid."""
         req = sus.request
         gen = np.asarray(sus.generated, np.int32)
@@ -954,9 +952,11 @@ class ContinuousScheduler:
 
     def _advance_staging(self) -> None:
         """Run one prefill segment for the staged admission (if any).
-        Attention spans the bucket width at every segment, which keeps
-        the staged rows bit-identical to a one-shot bucketed prefill;
-        segments stop once the prompt tail has landed."""
+        Attention spans the bucket width at every segment (keys past the
+        segment are causally masked), one compiled program per bucket;
+        the staged rows agree with a one-shot bucketed prefill within
+        float32 reassociation.  Segments stop once the prompt tail has
+        landed."""
         if not self._staging:
             return
         st = self._staging[0]
@@ -1006,8 +1006,7 @@ class ContinuousScheduler:
     def _complete(self, fin: list[int], buf, gen, *,
                   timed_out: bool = False) -> list[int]:
         """Release finished slots and record their Completions; freed
-        slots drop to depth 0 so the paged decode kernel's max-depth
-        branch follows live occupancy."""
+        slots drop to depth 0."""
         from repro.serve.engine import Completion
         if self.tel.enabled and fin:
             self.tel.counter(

@@ -1,9 +1,10 @@
-"""Paged decode attention vs the dense oracle.
+"""Decode attention: the kernel against the dense reference.
 
-The load-bearing claim is *bit*-identity of the blocked-jnp fallback:
-`decode_attention` swapped the dense einsum for the paged path in the
-serving hot loop, and greedy decode must not move by one ULP.  The Pallas
-kernel (online softmax) is held to float tolerance in interpret mode.
+`ops.decode_attend` runs the Pallas kernel on the TPU and the dense
+reference everywhere else.  The kernel (online softmax) is held to the
+reference within float tolerance in interpret mode; both paths give a
+row that decodes nothing the same meaning (zero output, pool row kept),
+checked on each.
 """
 import jax
 import jax.numpy as jnp
@@ -12,10 +13,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.common import auto_page_size
+import repro.kernels.decode_attention.ops as ops
 from repro.kernels.decode_attention.ops import (
-    mla_decode_attention_op,
-    paged_decode_attention,
-    paged_decode_attention_jnp,
+    decode_attend,
     paged_decode_attention_op,
 )
 from repro.kernels.decode_attention.ref import decode_attention_ref
@@ -34,80 +34,6 @@ def _qkv(B, S, Hq, Hkv, D, key=KEY):
     return (jax.random.normal(kq, (B, 1, Hq, D)),
             jax.random.normal(kk, (B, S, Hkv, D)),
             jax.random.normal(kv, (B, S, Hkv, D)))
-
-
-# ------------------------------------------------------ fallback bit-exact --
-
-
-@pytest.mark.parametrize("B,S,Hq,Hkv,D,page", [
-    (4, 1024, 8, 2, 64, 128),
-    (2, 256, 4, 4, 32, 64),
-    (3, 96, 6, 3, 16, 32),
-    (1, 512, 2, 1, 128, 128),
-])
-def test_paged_jnp_bit_identical_to_dense(B, S, Hq, Hkv, D, page):
-    """Every page-prefix branch must reproduce the full-width dense path
-    bit-for-bit (masked tail keys are exact zeros in every reduction)."""
-    q, k, v = _qkv(B, S, Hq, Hkv, D)
-    rng = np.random.RandomState(0)
-    for _ in range(4):
-        attend = jnp.asarray(rng.randint(1, S + 1, size=B), jnp.int32)
-        got = paged_decode_attention_jnp(q, k, v, attend, page_size=page)
-        want = decode_attention_ref(q, k, v, attend)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_paged_jnp_scalar_attend_bit_identical():
-    q, k, v = _qkv(2, 256, 4, 2, 64)
-    for attend in (1, 77, 128, 129, 256):
-        got = paged_decode_attention_jnp(q, k, v, attend, page_size=128)
-        want = decode_attention_ref(q, k, v, attend)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_decode_attention_dispatch_bit_identical():
-    """The public decode_attention (auto page size) == dense oracle, both
-    for paging widths and for widths that fall back to dense."""
-    for S in (64, 56, 1024):
-        q, k, v = _qkv(2, S, 4, 2, 16)
-        attend = jnp.asarray([S // 2, S], jnp.int32)
-        np.testing.assert_array_equal(
-            np.asarray(decode_attention(q, k, v, attend)),
-            np.asarray(decode_attention_ref(q, k, v, attend)))
-
-
-def test_decode_loop_tokens_match_dense_path(monkeypatch):
-    """End-to-end pre-PR equivalence: a greedy decode loop through
-    bb.decode_step produces the same tokens with the paged path as with
-    the dense einsum (the verbatim seed math) forced in its place."""
-    from repro.configs import get_config
-    from repro.models import backbone as bb
-    import repro.nn.attention as attn
-
-    cfg = get_config("qwen2-0.5b").reduced()
-    params = bb.init_params(cfg, KEY)
-    rng = np.random.RandomState(0)
-    batch = {"tokens": jnp.asarray(rng.randint(0, cfg.vocab, (2, 8)),
-                                   jnp.int32)}
-    logits, cache, T = bb.prefill(cfg, params, batch, max_len=64)
-
-    def run():
-        step = jax.jit(lambda p, t, c, n: bb.decode_step(cfg, p, t, c, n))
-        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        cl = jnp.full((2,), T, jnp.int32)
-        c = cache
-        toks = []
-        for _ in range(12):
-            lg, c = step(params, tok, c, cl)
-            tok = jnp.argmax(lg, -1)[:, None].astype(jnp.int32)
-            cl = cl + 1
-            toks.append(np.asarray(tok))
-        return np.concatenate(toks, axis=1)
-
-    got = run()                                    # paged (S=64 pages at 32)
-    monkeypatch.setattr(attn, "decode_attention", decode_attention_ref)
-    want = run()                                   # seed dense path
-    np.testing.assert_array_equal(got, want)
 
 
 def test_auto_page_size():
@@ -210,57 +136,67 @@ def test_pallas_append_writes_pool_in_place(layer):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
-# ----------------------------------- idle rows (attend_len 0) visit nothing --
+# ------------------------------------- idle rows: zero output, pool kept --
 
 _IDLE = {"first": [0], "middle": [2], "last": [4], "mixed": [0, 2, 4],
          "all": [0, 1, 2, 3, 4]}
 
 
-def _idle_call(form, append, attend, slot, idx):
-    """One kernel call on a 2x2-layer pool of rows `idx` (of 5) at layer
-    (1, 0), in the GQA form (14/2 heads of 64) or the latent form (4
-    heads, rows of 80 whose first 64 are the values): a call on a subset
-    of rows sees the same rows.  Returns (out, pool operands after the
-    call, pool operands before)."""
-    S, page, layer = 512, 128, (1, 0)
+def _kernel_path(monkeypatch):
+    """Steer `decode_attend` onto the Pallas kernel (in interpret mode off
+    the TPU) at pages of 128, where the TPU backend takes it compiled."""
+    monkeypatch.setattr(ops, "_tpu_page",
+                        lambda S: 128 if S % 128 == 0 else 0)
+
+
+def _idle_call(form, append, depth, live, slot, idx):
+    """One `decode_attend` call on a 2x2-layer pool of rows `idx` (of 5)
+    at layer (1, 0), in the GQA form (14/2 heads of 64) or the latent
+    form (4 heads, rows of 80 whose first 64 are the values): a call on
+    a subset of rows sees the same rows.  Returns (out, pool operands
+    after the call, pool operands before)."""
+    S, layer = 512, (1, 0)
     ks = jax.random.split(jax.random.PRNGKey(11), 4)
     rows = lambda key, shape: jax.random.normal(key, (5, *shape))[idx]
     if form == "gqa":
         Hq, Hkv, D = 14, 2, 64
         pool = tuple(jnp.moveaxis(rows(k, (2, 2, S, Hkv, D)), 0, 2)
                      for k in ks[:2])
+        form_kw = {}
     else:
         Hq, D = 4, 80
         pool = (jnp.moveaxis(rows(ks[0], (2, 2, S, 1, D)), 0, 2),)
+        form_kw = dict(scale=0.1, dv=64)
     q = rows(ks[2], (1, Hq, D))
-    new = tuple(rows(jax.random.fold_in(ks[3], j), (pool[0].shape[-2], D))
+    new = tuple(rows(jax.random.fold_in(ks[3], j),
+                     (1, pool[0].shape[-2], D))
                 for j in range(len(pool))) if append else None
-    attend, slot = jnp.asarray(attend, jnp.int32), jnp.asarray(slot, jnp.int32)
-    if form == "gqa":
-        res = paged_decode_attention_op(q, *pool, attend, layer, new, slot,
-                                        page_size=page, interpret=True)
-    else:
-        res = mla_decode_attention_op(q, *pool, attend, layer,
-                                      new and new[0], slot, page_size=page,
-                                      interpret=True, scale=0.1, dv=64)
-    out, *after = res if append else (res,)
+    res = decode_attend(q, pool, jnp.asarray(depth, jnp.int32), new=new,
+                        slot=jnp.asarray(slot, jnp.int32), layer=layer,
+                        live=live, **form_kw)
+    out, after = res if append else (res, pool)
     return out, after, pool
 
 
 @pytest.mark.parametrize("idle", list(_IDLE))
 @pytest.mark.parametrize("append", [False, True])
 @pytest.mark.parametrize("form", ["gqa", "mla"])
-def test_pallas_idle_rows_visit_no_page(form, append, idle):
-    """Rows with attend_len 0 among live rows at several depths (one
-    page, a page and one lane, a mid page, the full width): the live
-    rows' outputs and pool pages are bit-identical to a call without the
-    idle rows, the idle rows' outputs are exactly zero, and their pool
-    rows keep every value they held, write slot included."""
+@pytest.mark.parametrize("path", ["kernel", "reference"])
+def test_idle_rows_read_zero_and_keep_their_pool_rows(path, form, append,
+                                                      idle, monkeypatch):
+    """Rows that are not live, among live rows at several depths (one
+    page, a page and one lane, a mid page, the full width), on either
+    path: the live rows' outputs and pool pages are bit-identical to the
+    same path's call without the idle rows, the idle rows' outputs are
+    exactly zero, and their pool rows keep every value they held, write
+    slot included."""
+    if path == "kernel":
+        _kernel_path(monkeypatch)
     depth = np.asarray([1, 129, 300, 512, 128])
     slot = depth - 1
     gone = _IDLE[idle]
-    attend = np.where(np.isin(np.arange(5), gone), 0, depth)
-    out, after, before = _idle_call(form, append, attend, slot,
+    live = jnp.asarray(~np.isin(np.arange(5), gone))
+    out, after, before = _idle_call(form, append, depth, live, slot,
                                     np.arange(5))
     out = np.asarray(out)
     np.testing.assert_array_equal(out[gone], 0)
@@ -270,29 +206,12 @@ def test_pallas_idle_rows_visit_no_page(form, append, idle):
     kept = np.setdiff1d(np.arange(5), gone)
     if not len(kept):
         return
-    want, want_after, _ = _idle_call(form, append, attend[kept], slot[kept],
-                                     kept)
+    want, want_after, _ = _idle_call(form, append, depth[kept], None,
+                                     slot[kept], kept)
     np.testing.assert_array_equal(out[kept], np.asarray(want))
     for a, w in zip(after, want_after):
         np.testing.assert_array_equal(np.asarray(a)[:, :, kept],
                                       np.asarray(w))
-
-
-def _kernel_path(monkeypatch):
-    """Send the decode step through the Pallas kernel in interpret mode,
-    at pages of 128, as the TPU backend would send it compiled."""
-    import repro.kernels.decode_attention.ops as ops
-    import repro.nn.attention as attn
-    import repro.nn.mla as mla
-
-    page = lambda S, page_size=None: 128 if S % 128 == 0 else 0
-    monkeypatch.setattr(attn, "tpu_page", page)
-    monkeypatch.setattr(mla, "tpu_page", page)
-    for mod, name in ((ops, "paged_decode_attention_op"),
-                      (mla, "mla_decode_attention_op")):
-        op = getattr(mod, name)
-        monkeypatch.setattr(mod, name, lambda *a, interpret, _op=op, **k:
-                            _op(*a, interpret=True, **k))
 
 
 @pytest.mark.parametrize("arch", ["qwen2-0.5b", "moonlight"])
@@ -302,6 +221,17 @@ def test_decode_step_live_mask_on_the_kernel_path(arch, monkeypatch):
     live=None, and the free slot (depth 0) and the finished row keep
     every pool value they held, their next write slot included, where
     live=None writes it."""
+    _kernel_path(monkeypatch)
+    _check_live_mask_step(arch)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "moonlight"])
+def test_decode_step_live_mask_on_the_reference_path(arch):
+    """The same claims on the path the CPU takes, the dense reference."""
+    _check_live_mask_step(arch)
+
+
+def _check_live_mask_step(arch):
     from repro.configs import get_config
     from repro.models import backbone as bb
 
@@ -311,7 +241,6 @@ def test_decode_step_live_mask_on_the_kernel_path(arch, monkeypatch):
     else:
         cfg = get_config(arch).reduced()
         params = bb.init_params(cfg, KEY)
-    _kernel_path(monkeypatch)
     B, S = 4, 256
     cache = bb.init_cache(cfg, B, S)
     cache = {n: jax.random.normal(jax.random.fold_in(KEY, i), c.shape
@@ -345,23 +274,20 @@ def test_decode_step_live_mask_on_the_kernel_path(arch, monkeypatch):
                 max_size=6),
        st.integers(min_value=0, max_value=2**31 - 1))
 def test_ring_depth_property(depths, seed):
-    """Paged and dense decode_attention agree with a per-row oracle built
-    from reference_attention across random cache_len vectors, including
-    full (ring-wrapped) caches where attend_len == S."""
+    """decode_attention agrees with a per-row oracle built from
+    reference_attention across random cache_len vectors, including full
+    (ring-wrapped) caches where attend_len == S."""
     S, Hq, Hkv, D = 64, 4, 2, 16
     B = len(depths)
     key = jax.random.PRNGKey(seed % (2**31))
     q, k, v = _qkv(B, S, Hq,Hkv, D, key=key)
     attend = jnp.asarray(depths, jnp.int32)
 
-    paged = paged_decode_attention_jnp(q, k, v, attend, page_size=32)
-    dense = decode_attention_ref(q, k, v, attend)
-    np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))
-
+    got = decode_attention(q, k, v, attend)
     for b, n in enumerate(depths):     # per-row oracle over the valid prefix
         want = reference_attention(q[b:b + 1], k[b:b + 1, :n],
                                    v[b:b + 1, :n], causal=False)
-        np.testing.assert_allclose(paged[b:b + 1], want,
+        np.testing.assert_allclose(got[b:b + 1], want,
                                    atol=2e-5, rtol=2e-5)
 
 
@@ -377,19 +303,17 @@ def test_ring_depth_oracle_fixed_seeds(seed):
     depths[rng.randint(B)] = S          # force a wrapped row
     q, k, v = _qkv(B, S, Hq, Hkv, D, key=jax.random.PRNGKey(seed))
     attend = jnp.asarray(depths, jnp.int32)
-    paged = paged_decode_attention_jnp(q, k, v, attend, page_size=32)
-    np.testing.assert_array_equal(
-        np.asarray(paged), np.asarray(decode_attention_ref(q, k, v, attend)))
+    got = decode_attention(q, k, v, attend)
     for b, n in enumerate(depths):
         want = reference_attention(q[b:b + 1], k[b:b + 1, :n],
                                    v[b:b + 1, :n], causal=False)
-        np.testing.assert_allclose(paged[b:b + 1], want,
+        np.testing.assert_allclose(got[b:b + 1], want,
                                    atol=2e-5, rtol=2e-5)
 
 
 def test_swa_ring_wrap_decode_loop():
-    """Step-by-step SWA decode through a ring-wrapped cache (paged path,
-    S=64 pages at 32) matches windowed full attention — per-row depths
+    """Step-by-step SWA decode through a ring-wrapped cache (S=64)
+    matches windowed full attention — per-row depths
     past the wrap keep attending the whole ring."""
     cfgk = dict(n_heads=4, n_kv_heads=2, head_dim=8)
     d_model, W, T = 32, 64, 80

@@ -3,8 +3,9 @@
 Three layers of claims: the channel's bounded-retry/backoff/deadline
 arithmetic (closed forms), the gateway's degradation ladder (fault-free
 runs bit-identical with an idle injector attached; a total blackout
-resolves EVERY request as a Local-NN fallback whose logits match the
-standalone local path bitwise; corruption degrades to the ERASED floor,
+resolves EVERY request as a Local-NN fallback whose logits are the
+fleet's own local pass bitwise and agree with the standalone per-image
+local path within float32 reassociation; corruption degrades to the ERASED floor,
 never crashes), and the decode scheduler's deadline eviction (a stalled
 slot pool cannot hang `run()`)."""
 import dataclasses
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from agreement import FAULT_MARGIN, miss
 from repro.compress.lzw import (
     PayloadCorruptionError,
     compress_payload,
@@ -313,26 +315,61 @@ def test_idle_injector_is_bit_identical_to_none():
     assert plain.fallback_rate == idle.fallback_rate == 0.0
 
 
-def test_total_blackout_all_fallback_bit_identical_local():
-    """Acceptance: under a run-long blackout every request completes as a
-    Local-NN fallback whose logits equal the standalone local path
-    bitwise — including with the retry-forever channel config."""
+# float32 reassociation: the fleet's batched device pass against one
+# image at a time (measured: 3.0e-8 on the local logits)
+LOCAL_RTOL = 1e-5
+LOCAL_ATOL_FRAC = 1e-5        # of max|per-image local logit|
+
+
+def _blackout_local_miss(params=PARAMS) -> float:
+    """Run a 6-request fleet under a run-long blackout, built with
+    `params`, and return how far its fallback logits land from the
+    per-image local path of PARAMS, in tolerances.  Every request must
+    complete as a Local-NN fallback whose logits are the fleet's own
+    local pass, bitwise (one program) — including with the retry-forever
+    channel config."""
     forever = dataclasses.replace(WIFI_UDP, max_attempts=0,
                                   retransmit_timeout_s=1e-3)
     specs = (ClientSpec(channel=WIFI_UDP, n_requests=3),
              ClientSpec(channel=forever, n_requests=3))
-    fleet, report = _run(specs, faults=FaultInjector((Blackout(),)))
+    fleet = Fleet(CFG, params, specs, seed=0)
+    report = OffloadGateway(CFG, params, fleet, GatewayConfig(batch_width=4),
+                            faults=FaultInjector((Blackout(),))).run()
     assert len(report.traces) == 6          # nothing hangs, nothing lost
     assert report.fallback_rate == 1.0
     assert all(t.status == "fallback" for t in report.traces)
+    got, want = [], []
     for t in report.traces:
         row = fleet.clients[t.client].row0 + t.req
         np.testing.assert_array_equal(t.logits, fleet.local_logits[row])
         image = jnp.asarray(fleet.images[row:row + 1])
         ref = np.asarray(agile_forward(
             CFG, PARAMS, image, train=False)[1]["local_logits"])[0]
-        np.testing.assert_array_equal(t.logits, ref)
-        assert t.pred == int(np.argmax(ref))
+        if params is PARAMS:
+            assert t.pred == int(np.argmax(ref))
+        got.append(t.logits)
+        want.append(ref)
+    return miss(np.stack(got), np.stack(want), rtol=LOCAL_RTOL,
+                atol_frac=LOCAL_ATOL_FRAC)
+
+
+def test_total_blackout_all_fallback_agrees_with_local():
+    """Acceptance: under a run-long blackout every request completes as a
+    Local-NN fallback whose logits agree with the standalone local path
+    within the written tolerance, with the same predictions."""
+    assert _blackout_local_miss() <= 1
+
+
+def test_total_blackout_tolerance_catches_dropped_channel():
+    """A device whose extractor drops the first local feature channel
+    (always zero) misses the standalone local logits by FAULT_MARGIN
+    tolerances or more."""
+    c = int(PARAMS["mapping"][0])
+    conv = PARAMS["extractor"]["convs"][-1]
+    dropped = dict(PARAMS, extractor=dict(PARAMS["extractor"], convs=[
+        *PARAMS["extractor"]["convs"][:-1],
+        {"w": conv["w"].at[..., c].set(0), "b": conv["b"].at[c].set(0)}]))
+    assert _blackout_local_miss(dropped) >= FAULT_MARGIN
 
 
 def test_fault_run_fixed_seed_determinism():

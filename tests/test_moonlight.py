@@ -307,9 +307,9 @@ def test_mla_decode_kernel_matches_the_dense_rows():
     """The paged kernel on a latent pool (interpret mode): it writes each
     row's new latent row in place and attends every head to the rows,
     whose first kv_lora_rank entries are the values, as the dense
-    float32 path does."""
+    float32 reference does."""
     from repro.kernels.decode_attention.ops import mla_decode_attention_op
-    from repro.nn.mla import _attend_rows
+    from repro.kernels.decode_attention.ref import decode_attention_ref
     B, S, H, Dl, r, page = 3, 256, 4, 80, 64, 128
     ks = jax.random.split(jax.random.PRNGKey(9), 3)
     pool = jax.random.normal(ks[0], (2, 1, B, S, 1, Dl))
@@ -322,6 +322,7 @@ def test_mla_decode_kernel_matches_the_dense_rows():
         interpret=True, scale=0.1, dv=r)
     want_pool = pool.at[1, 0, jnp.arange(B), depth].set(new)
     np.testing.assert_array_equal(np.asarray(pool2), np.asarray(want_pool))
-    want = _attend_rows(q, want_pool[1, 0], depth + 1, 0.1, r)
+    want = decode_attention_ref(q, want_pool[1, 0], None, depth + 1,
+                                scale=0.1, dv=r)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)

@@ -32,6 +32,16 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def mesh(topo):
+    """The serving mesh over the described 2x2 chips, Auto axes as
+    `launch.mesh.make_serving_mesh` makes it."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
 @pytest.fixture
 def no_compile_cache():
     """Compiles for a described chip are written to the persistent cache
@@ -256,3 +266,75 @@ def test_decode_kernel_compiles_with_every_row_idle(one_chip,
                 (sds((), jnp.int32), sds((), jnp.int32)),
                 sds((B, 1, Dl), jnp.bfloat16), sds((B,), jnp.int32))
     assert "tpu_custom_call" in _compiled_text(fn, *args)
+
+
+def _placed(tree, shardings):
+    return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=s), tree, shardings)
+
+
+def test_decode_chunk_compiles_under_a_mesh(mesh, no_compile_cache,
+                                            monkeypatch):
+    """The scheduler's decode chunk (qwen2-0.5b reduced, 14/2 heads of
+    64) traced under the 2x2 serving mesh, with params and pool placed
+    as `launch.partition` places them: the kernel runs per shard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.launch.partition import param_shardings, pool_shardings
+    from repro.models import backbone as bb
+    from repro.serve.scheduler import ContinuousScheduler, SchedulerConfig
+
+    cfg = get_config("qwen2-0.5b").reduced()
+    params = bb.init_params(cfg, jax.random.PRNGKey(0))
+    sched = ContinuousScheduler(
+        cfg, params, max_len=256,
+        sched=SchedulerConfig(buckets=(64,), max_slots=4, chunk=4))
+    params = jax.eval_shape(lambda: params)
+    pool = jax.eval_shape(lambda: sched._pool)
+    whole = NamedSharding(mesh, P())
+    args = (_placed(params, param_shardings(params, mesh)),
+            _placed(pool, pool_shardings(pool, mesh)),
+            *(jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=whole)
+              for x in (jnp.ones((4,), bool), jax.random.PRNGKey(1),
+                        jnp.int32(4))))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.set_mesh(mesh):
+        text = sched._chunk.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "paged_decode_attention_op" in text
+
+
+def test_mla_decode_compiles_under_a_mesh(mesh, no_compile_cache,
+                                          monkeypatch):
+    """`decode_attend` on the Moonlight cell's latent pool (27 layers, 32
+    slots of 4,096 rows of 576, bfloat16) under the 2x2 serving mesh,
+    the pool's slots split over "data" as `launch.partition` places
+    them: the latent kernel runs per shard, writing the step's row in
+    place, and no collective gathers the pool.  (The whole Moonlight
+    decode chunk does not compile under a mesh yet: the held-expert
+    kernel has no per-shard wrapper.)"""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.kernels.decode_attention.ops import decode_attend
+    from repro.launch.partition import pool_shardings
+
+    B, S, H, Dl, L = 32, 4096, 16, 576, 27
+    pool = jax.ShapeDtypeStruct((L, 1, B, S, 1, Dl), jnp.bfloat16)
+    pool = _placed(pool, pool_shardings({"latent": pool}, mesh)["latent"])
+    rows = lambda shape, dt: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(
+            mesh, P("data", *(None,) * (len(shape) - 1))))
+    whole = jax.ShapeDtypeStruct((), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn = lambda q, c, a, r, s, l: decode_attend(
+        q, (c,), a, new=(r,), slot=s, layer=l, scale=192 ** -0.5, dv=512)
+    with jax.set_mesh(mesh):
+        text = jax.jit(fn, donate_argnums=1).lower(
+            rows((B, 1, H, Dl), jnp.bfloat16), pool,
+            rows((B,), jnp.int32), rows((B, 1, 1, Dl), jnp.bfloat16),
+            rows((B,), jnp.int32), (whole, whole)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "mla_decode_attention_op" in text
+    assert "all-gather" not in text
